@@ -19,6 +19,7 @@ from benchmarks import common
 from distributed_learning_tpu.data import normalize, shard_dataset, load_cifar
 from distributed_learning_tpu.data.cifar import real_cifar_present
 from distributed_learning_tpu.parallel import Topology
+from distributed_learning_tpu.parallel.consensus import make_agent_mesh
 from distributed_learning_tpu.training import MasterNode
 
 import jax.numpy as jnp
@@ -32,9 +33,9 @@ def run(
 ):
     full = common.full_scale()
     if batch_size is None:
-        batch_size = 128 if full else (16 if common.smoke() else 64)
+        batch_size = 128 if full else 16
     if n_train is None:
-        n_train = 50_000 if full else (512 if common.smoke() else 4096)
+        n_train = 50_000 if full else 512
     (X, y), (Xt, yt) = load_cifar("cifar10")
     X, y = X[:n_train], y[:n_train]
     Xt, yt = Xt[: max(n_train // 8, 128)], yt[: max(n_train // 8, 128)]
@@ -60,7 +61,7 @@ def run(
         epoch_cons_num=1,
         batch_size=batch_size,
         mix_times=2,
-        mesh=common.agent_mesh_or_none(n_agents),
+        mesh=make_agent_mesh(n_agents),
         dropout=False,
     )
     master.initialize_nodes()

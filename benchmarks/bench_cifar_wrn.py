@@ -24,6 +24,7 @@ import numpy as np
 from benchmarks import common
 from distributed_learning_tpu.data import load_cifar, normalize, shard_dataset
 from distributed_learning_tpu.parallel import Topology
+from distributed_learning_tpu.parallel.consensus import make_agent_mesh
 from distributed_learning_tpu.training import MasterNode
 
 T4_SAMPLES_PER_SEC = 100 * 50_000 / 29_887.0  # BASELINE.md wall-clock
@@ -37,11 +38,11 @@ def run(
     epochs: int = 1,
 ):
     full = common.full_scale()
-    n_agents = n_agents or (8 if full else (2 if common.smoke() else 4))
+    n_agents = n_agents or (8 if full else 2)
     depth = depth or (28 if full else 10)
     widen = widen or (10 if full else 1)
     batch_size = batch_size or (128 if full else 8)
-    n_train = 50_000 if full else (256 if common.smoke() else 1024)
+    n_train = 50_000 if full else 256
 
     (X, y), (Xt, yt) = load_cifar("cifar10")
     X, y = X[:n_train], y[:n_train]
@@ -73,7 +74,7 @@ def run(
         epoch_cons_num=1,
         batch_size=batch_size,
         mix_times=1,
-        mesh=common.agent_mesh_or_none(n_agents),
+        mesh=make_agent_mesh(n_agents),
     )
     master.initialize_nodes()
     master.train_epoch()  # compile + warm
@@ -120,7 +121,7 @@ def run(
         epoch=2,
         epoch_cons_num=10**9,  # never mix during the epoch
         batch_size=batch_size,
-        mesh=common.agent_mesh_or_none(n_agents),
+        mesh=make_agent_mesh(n_agents),
     )
     master2.initialize_nodes()
     master2.train_epoch()

@@ -36,7 +36,7 @@ def run() -> None:
     # take the better part of an hour on the chip for zero extra insight.
     # 16k keeps the vectors WRN-block-sized; the atopk case below shows
     # the hardware-aware escape hatch at the same dim.
-    dim = 16_384 if full_scale() else (256 if smoke() else 2_048)
+    dim = 16_384 if full_scale() else 256
     W = Topology.ring(n).metropolis_weights()
     rng = np.random.default_rng(0)
     x0 = jnp.asarray(rng.normal(size=(n, dim)).astype(np.float32))

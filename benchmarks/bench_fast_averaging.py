@@ -5,8 +5,8 @@ Three measurements:
 1. Gossip throughput & convergence — N agents each hold a large random
    vector; gossip until the max deviation drops below 1e-4.  Records
    rounds-to-1e-4 (the BASELINE.json north-star residual) and gossip
-   rounds/sec on both engine paths (dense MXU matmul; sharded ppermute when
-   a big-enough device mesh exists).
+   rounds/sec on both engine paths (dense MXU matmul; sharded ppermute on
+   an ``n_agents``-device mesh — too few devices is an error).
 
 2. Fused flat-buffer consensus — a model-shaped MANY-LEAF stack (the
    WRN-like regime of ~100 leaves where per-op overhead dominates):
@@ -31,7 +31,10 @@ import numpy as np
 from benchmarks import common
 from distributed_learning_tpu.ops import mixing as mixing_ops
 from distributed_learning_tpu.parallel import Topology, solve_fastest_mixing
-from distributed_learning_tpu.parallel.consensus import ConsensusEngine
+from distributed_learning_tpu.parallel.consensus import (
+    ConsensusEngine,
+    make_agent_mesh,
+)
 
 SDP_REFERENCE_S = 0.176  # Fast Averaging.ipynb cell 4 (%time wall)
 
@@ -107,18 +110,14 @@ def run_fused_vs_perleaf(
 
 def run(n_agents: int = 8, dim: int | None = None, eps: float = 1e-4):
     if dim is None:
-        dim = 1 << 22 if common.full_scale() else (1 << 12 if common.smoke() else 1 << 16)
+        dim = 1 << 22 if common.full_scale() else 1 << 12
     topo = Topology.ring(n_agents)
     W = topo.metropolis_weights()
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(n_agents, dim)).astype(np.float32))
 
     results = {}
-    modes = [("dense", None)]
-    mesh = common.agent_mesh_or_none(n_agents)
-    if mesh is not None:
-        modes.append(("ppermute", mesh))
-    for mode, m in modes:
+    for mode, m in (("dense", None), ("ppermute", make_agent_mesh(n_agents))):
         engine = ConsensusEngine(W, mesh=m)
         xs = engine.shard(x)
         out, t_rounds, res = engine.mix_until(xs, eps=eps, max_rounds=5000)

@@ -235,7 +235,9 @@ def main():
 
         with open("BASELINE.json") as f:
             d = json.load(f, object_pairs_hook=collections.OrderedDict)
-        d["published"]["northstar_titanic_asyncio_headtohead"] = rec
+        d.setdefault("published", {})[
+            "northstar_titanic_asyncio_headtohead"
+        ] = rec
         with open("BASELINE.json", "w") as f:
             json.dump(d, f, indent=1)
             f.write("\n")

@@ -107,7 +107,7 @@ def run(n_agents: Optional[int] = None, packs: Optional[int] = None,
         points_per_pack: Optional[int] = None, n_subs: int = 10,
         out_dir: Optional[str] = None) -> dict:
     if n_agents is None:
-        n_agents = 500 if full_scale() else (64 if smoke() else 128)
+        n_agents = 500 if full_scale() else 64
     if packs is None:
         packs = 2 if smoke() else 4
     if points_per_pack is None:

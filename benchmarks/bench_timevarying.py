@@ -20,7 +20,10 @@ import numpy as np
 from benchmarks import common
 from distributed_learning_tpu.data import load_cifar, normalize, shard_dataset
 from distributed_learning_tpu.parallel import Topology
-from distributed_learning_tpu.parallel.consensus import ConsensusEngine
+from distributed_learning_tpu.parallel.consensus import (
+    ConsensusEngine,
+    make_agent_mesh,
+)
 from distributed_learning_tpu.parallel.schedule import chebyshev_omegas
 from distributed_learning_tpu.parallel.topology import gamma as exact_gamma
 from distributed_learning_tpu.training import MasterNode
@@ -37,11 +40,11 @@ def run(
     edge_p: float = 0.4,
 ):
     full = common.full_scale()
-    n_agents = n_agents or (8 if full else (2 if common.smoke() else 4))
+    n_agents = n_agents or (8 if full else 2)
     depth = depth or (28 if full else 10)
     widen = widen or (10 if full else 1)
     batch_size = batch_size or (128 if full else 8)
-    n_train = 50_000 if full else (256 if common.smoke() else 1024)
+    n_train = 50_000 if full else 256
 
     (X, y), (Xt, yt) = load_cifar("cifar100")
     X, y = X[:n_train], y[:n_train]
@@ -77,7 +80,7 @@ def run(
         mix_times=4,
         topology_schedule=schedule,
         chebyshev=True,
-        mesh=common.agent_mesh_or_none(n_agents),
+        mesh=make_agent_mesh(n_agents),
     )
     master.initialize_nodes()
     master.train_epoch()  # compile + warm

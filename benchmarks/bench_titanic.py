@@ -24,7 +24,10 @@ from distributed_learning_tpu.data import load_titanic, split_data
 from distributed_learning_tpu.models import logreg_loss
 from distributed_learning_tpu.models.logreg import accuracy as logreg_accuracy
 from distributed_learning_tpu.parallel import Topology
-from distributed_learning_tpu.parallel.consensus import ConsensusEngine
+from distributed_learning_tpu.parallel.consensus import (
+    ConsensusEngine,
+    make_agent_mesh,
+)
 
 ALPHA, TAU = 0.1, 1e-4
 REFERENCE_ACC = 0.7978  # Titanic nb cell 15 (K4 / 4-agent recorded value)
@@ -32,7 +35,7 @@ REFERENCE_ACC = 0.7978  # Titanic nb cell 15 (K4 / 4-agent recorded value)
 
 def run(n_agents: int = 4, iters: int | None = None, mix_eps: float = 1e-9):
     if iters is None:
-        iters = 4000 if common.full_scale() else (100 if common.smoke() else 1000)
+        iters = 4000 if common.full_scale() else 100
     X_tr, y_tr, X_te, y_te = load_titanic()
     shards = split_data(X_tr, y_tr, n_agents)
     m = min(len(s[0]) for s in shards.values())
@@ -42,7 +45,7 @@ def run(n_agents: int = 4, iters: int | None = None, mix_eps: float = 1e-9):
     )
     engine = ConsensusEngine(
         Topology.ring(n_agents).metropolis_weights(),
-        mesh=common.agent_mesh_or_none(n_agents),
+        mesh=make_agent_mesh(n_agents),
     )
 
     def local_step(w, X, y, lr):

@@ -37,6 +37,7 @@ from distributed_learning_tpu.data.cifar import (
     real_cifar_present,
 )
 from distributed_learning_tpu.parallel import Topology
+from distributed_learning_tpu.parallel.consensus import make_agent_mesh
 from distributed_learning_tpu.training import MasterNode
 from distributed_learning_tpu.training.config import wrn_lr_schedule
 
@@ -109,7 +110,7 @@ def run(
         mix_times=1,
         augment=True,
         augment_pad_value=normalized_pad_value(dataset),
-        mesh=common.agent_mesh_or_none(n_agents),
+        mesh=make_agent_mesh(n_agents),
     )
     master.initialize_nodes()
 

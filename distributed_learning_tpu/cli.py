@@ -16,7 +16,7 @@ Subcommands (dispatched before the trainer flag surface):
     python -m distributed_learning_tpu.cli obs-report <run.jsonl>
     python -m distributed_learning_tpu.cli obs-report --merge <a.jsonl> <b.jsonl>
     python -m distributed_learning_tpu.cli obs-report --bench BENCH_r*.json
-    python -m distributed_learning_tpu.cli obs-report --ledger PERF_LEDGER.jsonl
+    python -m distributed_learning_tpu.cli obs-report --ledger benchmarks/results/perf_ledger.jsonl
     python -m distributed_learning_tpu.cli obs-monitor <aggregate.jsonl>
 
 summarize JSONL observability event logs — single-process, merged
@@ -239,6 +239,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(f"wrote {args.dump_config}")
         return 0
 
+    from distributed_learning_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     ckpt = os.path.abspath(cfg.checkpoint_dir) if cfg.checkpoint_dir else None
     cfg_path = ckpt + ".config.json" if ckpt else None
     if (args.resume or args.testOnly) and cfg_path and os.path.exists(cfg_path):

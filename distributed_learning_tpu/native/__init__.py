@@ -4,23 +4,30 @@
 conversion, int8 quantization, crc32); ``wire.cpp`` (wrapped by
 :mod:`.wire`) is the whole-frame wire engine layered on the same
 primitives.  Each shared library is compiled with g++ on first use and
-cached beside its source; environments without a toolchain fall back to
+cached beside its source under a name keyed on that source, the build
+flags and the host's CPU (``_wire.<key>.so``), so a library copied in
+from another box or left over from an older source is rebuilt, never
+loaded; environments without a toolchain fall back to
 numpy/ml_dtypes/zlib implementations with identical semantics (the tests
 assert bit-equality).
 
 Build hardening (ISSUE 9): every library exports ``dlt_abi_version()``
-(``dlt_abi.h``), checked right after ``dlopen`` — a stale cached ``.so``
-missing new symbols triggers a rebuild, never an ``AttributeError`` at
-first use.  A failed g++ build logs ONE warning on the ``dlt.native``
-logger and bumps the ``native.build_failed`` obs counter (it used to
-return ``None`` silently), then the pure-Python fallback serves.
+(``dlt_abi.h``), checked right after ``dlopen`` against this module's
+``_ABI_VERSION``.  A failed g++ build (or an ABI disagreement) logs ONE
+warning on the ``dlt.native`` logger and bumps the
+``native.build_failed`` obs counter, then the pure-Python fallback
+serves.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 import zlib
@@ -79,49 +86,82 @@ def _cache_override(lib_path: str) -> str:
     return os.path.join(cache_dir, os.path.basename(lib_path))
 
 
-def _build_lib(src: str, lib_path: str, *, force: bool = False) -> Optional[str]:
-    """Compile ``src`` to ``lib_path`` unless a fresh cache exists.
+def _host_cpu_flags() -> str:
+    """What ``-march=native`` resolves against on this host: the first
+    ``flags``/``Features`` line of /proc/cpuinfo (empty where the file
+    is absent — the machine name below still keys the build)."""
+    try:
+        with open("/proc/cpuinfo", encoding="utf-8") as fh:
+            for line in fh:
+                if line.startswith(("flags", "Features")):
+                    return line
+    except OSError:
+        pass
+    return ""
 
-    ``force`` ignores the cache (the ABI-mismatch rebuild path).
+
+def _keyed_lib_path(src: str, lib_path: str, cflags: list) -> str:
+    """``_wire.so`` -> ``_wire.<key>.so``, the key a hash of everything
+    the binary depends on: the source and ``dlt_abi.h`` as checked in,
+    the build flags, and this host's CPU.  A library found under that
+    name was built from this checkout's sources for this host; one that
+    came from another box, or predates a source edit, has another name
+    and is never opened."""
+    h = hashlib.sha256()
+    for path in (src, os.path.join(os.path.dirname(src), "dlt_abi.h")):
+        if os.path.exists(path):
+            with open(path, "rb") as fh:
+                h.update(fh.read())
+    h.update(" ".join(cflags).encode())
+    h.update(platform.machine().encode())
+    h.update(_host_cpu_flags().encode())
+    stem, ext = os.path.splitext(lib_path)
+    return f"{stem}.{h.hexdigest()[:16]}{ext}"
+
+
+def _build_lib(src: str, lib_path: str) -> Optional[str]:
+    """Compile ``src`` unless this host already built this source; the
+    library lands beside ``lib_path`` under its keyed name
+    (:func:`_keyed_lib_path`), which is returned.
+
     ``DLT_NATIVE_EXTRA_CFLAGS`` (space-separated) appends build flags —
     the sanitizer stage's ``-fsanitize=...`` hook; combined with
     ``DLT_NATIVE_CACHE_DIR`` the instrumented build is fully separate.
     """
-    if (
-        not force
-        and os.path.exists(lib_path)
-        and os.path.getmtime(lib_path) >= os.path.getmtime(src)
-    ):
-        return lib_path
+    extra_cflags = os.environ.get("DLT_NATIVE_EXTRA_CFLAGS", "").split()
+    cflags = ["-O3", "-shared", "-fPIC", "-std=c++17", *extra_cflags]
+    keyed = _keyed_lib_path(src, lib_path, cflags)
+    if os.path.exists(keyed):
+        return keyed
     # Per-process temp name: concurrent first-use builds (multi-process
     # deployments) must not interleave g++ output on a shared path; the
-    # final os.replace is atomic either way.  -march=native is safe for
-    # a compiled-per-box-at-first-use cache (it IS this box) and lets
+    # final os.replace is atomic either way.  -march=native is safe
+    # because the host's CPU is part of the library's name, and lets
     # the wire engine's bulk loops vectorize; boxes whose toolchain
     # rejects it retry with the portable baseline.
-    tmp = f"{lib_path}.{os.getpid()}.tmp"
-    extra_cflags = os.environ.get("DLT_NATIVE_EXTRA_CFLAGS", "").split()
-    base = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-        *extra_cflags, src, "-o", tmp,
-    ]
+    tmp = f"{keyed}.{os.getpid()}.tmp"
     last_exc: Optional[BaseException] = None
-    for extra in (["-march=native"], []):
+    for march in (["-march=native"], []):
         try:
             subprocess.run(
-                base[:2] + extra + base[2:],
+                ["g++", *march, *cflags, src, "-o", tmp],
                 check=True,
                 capture_output=True,
                 timeout=120,
             )
-            os.replace(tmp, lib_path)
-            return lib_path
+            os.replace(tmp, keyed)
+            # Builds this one supersedes (an edited source, another
+            # host's copy) would otherwise pile up beside it.
+            stem, ext = os.path.splitext(lib_path)
+            for stale in glob.glob(f"{glob.escape(stem)}.*{ext}"):
+                if stale != keyed:
+                    with contextlib.suppress(OSError):
+                        os.unlink(stale)
+            return keyed
         except (OSError, subprocess.SubprocessError) as exc:
             last_exc = exc
-            try:
+            with contextlib.suppress(OSError):
                 os.unlink(tmp)
-            except OSError:
-                pass
     detail = type(last_exc).__name__
     stderr = getattr(last_exc, "stderr", None)
     if stderr:
@@ -147,10 +187,10 @@ def _load_lib(
 ) -> Optional[ctypes.CDLL]:
     """Build (if needed), dlopen, ABI-check, and configure one library.
 
-    An ABI mismatch — a cached ``.so`` from an older source whose mtime
-    beat the checkout's — forces ONE rebuild from the current source; a
-    second mismatch means the toolchain itself is stale and the Python
-    fallback serves.
+    The library is always one this host built from ``src`` as it stands
+    (see :func:`_keyed_lib_path`), so an ABI mismatch means the source
+    and this module's ``_ABI_VERSION`` disagree: the Python fallback
+    serves and the failure is counted.
     """
     path = _build_lib(src, lib_path)
     if path is None:
@@ -160,29 +200,10 @@ def _load_lib(
     except OSError:
         return None
     if not _abi_ok(lib):
-        _logger.warning(
-            "cached %s has a stale ABI (wanted v%d); rebuilding from source",
-            os.path.basename(lib_path), _ABI_VERSION,
+        _report_build_failure(
+            src, f"library reports another ABI than v{_ABI_VERSION}"
         )
-        try:
-            # dlopen caches by pathname while a handle stays open: the
-            # rebuilt library would silently resolve to the stale image
-            # unless the old handle is closed first.
-            import _ctypes
-
-            _ctypes.dlclose(lib._handle)
-        except Exception:
-            pass
-        path = _build_lib(src, lib_path, force=True)
-        if path is None:
-            return None
-        try:
-            lib = ctypes.CDLL(path)
-        except OSError:
-            return None
-        if not _abi_ok(lib):
-            _report_build_failure(src, "rebuilt library still ABI-stale")
-            return None
+        return None
     configure(lib)
     return lib
 

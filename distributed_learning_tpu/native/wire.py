@@ -138,7 +138,7 @@ def _load() -> Optional[ctypes.CDLL]:
             return None
         # DLT_NATIVE_CACHE_DIR reroutes the built .so (the sanitized-
         # build hook for graftlint --native): instrumented builds live
-        # in their own cache, never clobbering the production _wire.so.
+        # in their own cache, never clobbering the production library.
         _lib = _load_lib(_SRC, _cache_override(_LIB), _configure)
         return _lib
 

@@ -29,7 +29,7 @@ One import surface for the four pieces:
   extracted from any compiled entry point: FLOPs, bytes, peak HBM,
   donation, collective inventory; :class:`SampledDispatchTimer`
   1-in-N chunk-boundary step timing with MFU/bytes-per-sec gauges;
-  the persistent `PERF_LEDGER.jsonl` perf ledger behind
+  the persistent `benchmarks/results/perf_ledger.jsonl` ledger behind
   ``obs-report --ledger``).
 
 Library code counts into the process-wide default registry/tracer

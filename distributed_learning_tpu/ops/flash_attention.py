@@ -35,10 +35,10 @@ the kernels and sliced after — scores and softmax are unchanged by zero
 columns, and the pad/slice pair is differentiable, so the padding
 composes with the custom VJP.
 
-Context length is bounded by HBM, not VMEM.  Measured throughput comes
-from ``benchmarks/bench_attention.py`` (TFLOP/s at 8k/32k/131k with a
-block-size sweep); numbers live in ``BASELINE.json:"published"``, not
-here.  On CPU the same kernels run under ``interpret=True`` for the
+Context length is bounded by HBM, not VMEM.  Throughput is what
+``benchmarks/bench_attention.py`` measures (TFLOP/s at 8k/32k/131k with
+a block-size sweep); on the chip it is not measured yet.  On CPU the
+same kernels run under ``interpret=True`` for the
 tests; correctness bar: values and gradients match
 :func:`~distributed_learning_tpu.ops.ring_attention.attention_reference`.
 """
@@ -551,9 +551,9 @@ def flash_attention(
     this falls back to the reference einsum/softmax path (XLA fuses it
     well enough on CPU; the kernel is the TPU fast path).
 
-    Default blocks (256, 512) are the measured-best forward
-    configuration from the on-chip sweep at 8k-131k tokens
-    (``BASELINE.json: flash_attention_*``); for any T they degrade to
+    Default blocks (256, 512) fit the VMEM budget of
+    ``docs/flash_roofline.md``; their rate against other block shapes on
+    the chip is not measured.  For any T they degrade to
     the largest 8-aligned blocks that divide T, so every previously
     valid sequence length keeps working.
 

@@ -14,7 +14,7 @@ from typing import Any
 
 import jax
 
-__all__ = ["save_checkpoint", "restore_checkpoint"]
+__all__ = ["save_checkpoint", "restore_checkpoint", "saved_tree_metadata"]
 
 
 def _checkpointer():
@@ -53,3 +53,10 @@ def restore_checkpoint(path: str, template: Any) -> Any:
     """Read a pytree with the shapes/dtypes of ``template`` from ``path``."""
     ckptr = _checkpointer()
     return ckptr.restore(os.path.abspath(path), template)
+
+
+def saved_tree_metadata(path: str) -> dict:
+    """The tree a checkpoint at ``path`` holds, as orbax's per-leaf
+    metadata (``.shape``/``.dtype``) — what lets a caller build a
+    restore template for subtrees it does not own."""
+    return _checkpointer().metadata(os.path.abspath(path)).item_metadata.tree

@@ -676,8 +676,11 @@ class GossipTrainer:
                 f"{'None (isolated nodes)' if weights is None else 'given'})"
             )
 
-        # Static per-node data (truncated to a common batch grid).
-        self._Xs, self._ys = self._stack_data(train_data, batch_size)
+        # Static per-node data (truncated to a common batch grid); under
+        # a mesh each agent's shard lives on its agent's device.
+        self._Xs, self._ys = self.engine.shard(
+            self._stack_data(train_data, batch_size)
+        )
         if self.augment and self._Xs.shape[2:] != (32, 32, 3):
             raise ValueError(
                 "augment=True needs (32, 32, 3) image inputs; got per-sample "
@@ -925,10 +928,14 @@ class GossipTrainer:
         params = stack(params0)
         batch_stats = stack(bs0) if bs0 is not None else None
         opt_state = jax.vmap(self.tx.init)(params)
+        # Every stacked leaf goes where its agent lives (one device per
+        # agent under a mesh; a no-op on the dense layout) — optimizer
+        # slots and BatchNorm stats too, not only the params.
+        params, batch_stats, opt_state = self.engine.shard(
+            (params, batch_stats, opt_state)
+        )
         self._state = (
-            self.engine.shard(params)
-            if self.engine.mesh is not None
-            else params,
+            params,
             batch_stats,
             opt_state,
             jax.random.key(self.seed + 1),
@@ -2067,7 +2074,10 @@ class GossipTrainer:
         return tree
 
     def restore_checkpoint(self, path: str) -> None:
-        from distributed_learning_tpu.training.checkpoint import restore_checkpoint
+        from distributed_learning_tpu.training.checkpoint import (
+            restore_checkpoint,
+            saved_tree_metadata,
+        )
 
         if self._state is None:
             self.initialize_nodes()
@@ -2080,51 +2090,37 @@ class GossipTrainer:
             "epochs_done": 0,
             "global_step": 0,
         }
-        def _is_structure_mismatch(exc: Exception) -> bool:
-            # Orbax reports template/on-disk tree divergence as a
-            # ValueError mentioning the structures; anything else (missing
-            # path, corrupt data, dtype drift inside a leaf) must surface.
-            text = str(exc)
-            return isinstance(exc, ValueError) and (
-                "structure" in text or "MISSING" in text
+        # The tree on disk says whether it carries CHOCO state; the
+        # template is built to match it, so a trainer of either kind
+        # reads a checkpoint of either kind.
+        saved_choco = saved_tree_metadata(path).get("choco")
+        if self._choco is not None and saved_choco is not None:
+            template["choco"] = self._choco_tree()
+        elif self._choco is not None:
+            # Checkpoint saved before CHOCO state was checkpointed (or
+            # by a dense trainer): old semantics — estimates reset,
+            # error feedback re-converges.
+            warnings.warn(
+                "checkpoint has no CHOCO state (saved by an older "
+                "version or a dense trainer); estimates reset to zero "
+                "and error feedback re-converges over the next few "
+                "epochs"
             )
-
-        import warnings
-
-        restored = None
-        if self._choco is not None:
-            try:
-                restored = restore_checkpoint(
-                    path, {**template, "choco": self._choco_tree()}
-                )
-            except Exception as exc:
-                if not _is_structure_mismatch(exc):
-                    raise
-                # Checkpoint saved before CHOCO state was checkpointed (or
-                # by a dense trainer): old semantics — estimates reset,
-                # error feedback re-converges.
-                warnings.warn(
-                    "checkpoint has no CHOCO state (saved by an older "
-                    "version or a dense trainer); estimates reset to zero "
-                    "and error feedback re-converges over the next few "
-                    "epochs"
-                )
-        if restored is None:
-            try:
-                restored = restore_checkpoint(path, template)
-            except Exception as exc:
-                if self._choco is not None or not _is_structure_mismatch(exc):
-                    raise
-                # Dense trainer reading a compressed run's checkpoint:
-                # restore the training state and ignore the CHOCO subtree.
-                warnings.warn(
-                    "checkpoint contains CHOCO state but this trainer has "
-                    "no compression; the estimates are ignored"
-                )
-                restored = restore_checkpoint(
-                    path, {**template, "choco": self._choco_tree()}
-                )
-                restored.pop("choco", None)
+        elif saved_choco is not None:
+            # Dense trainer reading a compressed run's checkpoint:
+            # restore the training state and ignore the CHOCO subtree
+            # (orbax wants the whole on-disk structure in the template).
+            warnings.warn(
+                "checkpoint contains CHOCO state but this trainer has "
+                "no compression; the estimates are ignored"
+            )
+            template["choco"] = jax.tree.map(
+                lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype),
+                saved_choco,
+            )
+        restored = restore_checkpoint(path, template)
+        if self._choco is None:
+            restored.pop("choco", None)
         self._state = (
             restored["params"],
             restored["batch_stats"] if bs is not None else None,

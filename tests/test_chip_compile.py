@@ -1,0 +1,164 @@
+"""Compile the main path's kernels for a described v5e, without a chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described and not attached (``jax.experimental.topologies``), so what it
+would refuse on the machine — a slice off the tiling, too much VMEM, a
+kernel that cannot be partitioned, a program that does not fit HBM — is
+refused in tier-1, at no chip time.  Nothing runs: a compile that passes
+is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process at a time may load the TPU's library, and every xdist
+worker imports every test file), and the persistent compilation cache is
+off around the compiles (such an entry cannot be read back without a chip).
+The kernels themselves are compiled: the public wrappers ask
+``jax.devices()`` and would take their CPU branch here.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from distributed_learning_tpu.ops import flash_attention as fa
+from distributed_learning_tpu.ops import mixing as mixing_ops
+
+GiB = 2.0**30
+#: (batch*heads, T, head_dim) of the flash cases, default blocks 256/512.
+FLASH_SHAPES = [(8, 8192, 128), (16, 2048, 128)]
+BLOCK_Q, BLOCK_K = 256, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def agent_mesh(topo):
+    return Mesh(np.array(topo.devices[:4]), ("agents",))
+
+
+@pytest.fixture(scope="module")
+def cache_off():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _flash_fn(variant: str, scale: float):
+    """The custom-vjp kernels under the public wrappers, by variant."""
+    f32sum = lambda x: x.astype(jnp.float32).sum()
+
+    def flash(window):
+        return lambda q, k, v: fa._flash(
+            q, k, v, scale, True, BLOCK_Q, BLOCK_K, False, window
+        )
+
+    def flash_lse(q, k, v):
+        out, lse = fa._flash_lse(
+            q, k, v, scale, True, BLOCK_Q, BLOCK_K, False
+        )
+        return f32sum(out) + lse.sum()
+
+    if variant == "fwd":
+        return flash(None)
+    if variant == "grad":
+        return jax.grad(lambda *a: f32sum(flash(None)(*a)), argnums=(0, 1, 2))
+    if variant == "window":
+        return jax.value_and_grad(
+            lambda *a: f32sum(flash(1024)(*a)), argnums=(0, 1, 2)
+        )
+    if variant == "lse":
+        return jax.value_and_grad(flash_lse, argnums=(0, 1, 2))
+    raise ValueError(variant)
+
+
+@pytest.mark.parametrize("variant", ["fwd", "grad", "window", "lse"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_flash_kernels_compile_for_v5e(one_chip, cache_off, shape, variant):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    fn = _flash_fn(variant, float(1.0 / np.sqrt(shape[-1])))
+    compiled = _compile(fn, x, x, x)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_dense_mix_compiles_at_wrn_28_10_width(one_chip, cache_off):
+    """The dense gossip GEMM over 4 stacked WRN-28-10 replicas (4 x
+    36,489,290 parameters) on one 16 GiB chip."""
+    from distributed_learning_tpu.models import WideResNet
+
+    n = 4
+    model = WideResNet(depth=28, widen_factor=10, dropout_rate=0.3,
+                       num_classes=10)
+    variables = jax.eval_shape(
+        lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, 32, 32, 3)), train=False
+        )
+    )
+    params = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(
+            (n,) + v.shape, v.dtype, sharding=one_chip
+        ),
+        variables["params"],
+    )
+    assert sum(
+        int(np.prod(v.shape[1:])) for v in jax.tree.leaves(params)
+    ) == 36_489_290
+    W = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
+    compiled = _compile(
+        lambda p, w: mixing_ops.fused_dense_mix(
+            p, w, precision=jax.lax.Precision.HIGHEST
+        ),
+        params, W,
+    )
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 8 * GiB, total / GiB  # half the chip, by a wide margin
+
+
+def test_sharded_ring_mix_compiles_to_collective_permute(agent_mesh, cache_off):
+    """One agent per device on the 2x2: the gossip round is ppermutes."""
+    from distributed_learning_tpu.parallel.consensus import ConsensusEngine
+    from distributed_learning_tpu.parallel.topology import Topology
+
+    engine = ConsensusEngine(
+        Topology.ring(4).metropolis_weights(), mesh=agent_mesh
+    )
+    x = jax.ShapeDtypeStruct(
+        (4, 4_194_304), jnp.float32,
+        sharding=NamedSharding(agent_mesh, P("agents")),
+    )
+    compiled = _compile(lambda s: engine.mix(s, times=1), x)
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    assert "all-gather" not in text  # the state never leaves its device whole
+    # Per device: one agent's 16 MiB in, 16 MiB out.
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.02 * GiB

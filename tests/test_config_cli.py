@@ -142,9 +142,12 @@ def test_cli_dump_config(tmp_path, capsys):
     assert len(cfg.node_names) == 8 and cfg.topology == "torus2d"
 
 
-def test_cli_train_checkpoint_resume_testonly(tmp_path, capsys):
+def test_cli_train_checkpoint_resume_testonly(tmp_path, capsys, monkeypatch):
     """The reference main.py workflow: train, auto-checkpoint, --resume
     continues from the saved epoch, -t evaluates only."""
+    # The trainer branch is an entry point and places the compile cache;
+    # with the variable set it leaves this process's JAX config alone.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     ckpt = str(tmp_path / "ckpt")
     base = [
         "--net_type", "ann", "--dataset", "cifar10", "--nodes", "2",

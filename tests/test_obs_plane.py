@@ -574,7 +574,7 @@ def test_obs_report_bench_trajectory(tmp_path, capsys):
             "vs_baseline": 0.5}},
         {"n": 4, "rc": 0, "parsed": {
             "metric": "m", "value": 60.0, "unit": "samples/sec",
-            "vs_baseline": 0.6, "tunnel_wedged": True}},
+            "vs_baseline": 0.6, "provisional": True}},
     ]
     paths = []
     for row in rows:
@@ -586,20 +586,8 @@ def test_obs_report_bench_trajectory(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "no record (driver rc=2)" in out
     assert "REGRESSION -50% vs r01" in out
-    assert "cpu-sanity (tunnel wedged)" in out
+    assert "provisional" in out
     assert "best healthy headline: 100.00 (r01)" in out
-
-    # And over the repo's real trajectory files (the satellite's point:
-    # the bench history is readable TODAY).
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    real = sorted(
-        os.path.join(repo, f) for f in os.listdir(repo)
-        if f.startswith("BENCH_r") and f.endswith(".json")
-    )
-    if real:
-        assert main(["obs-report", "--bench", *real]) == 0
-        out = capsys.readouterr().out
-        assert "bench trajectory" in out
 
 
 def test_obs_monitor_once_renders_dashboard(tmp_path, capsys):

@@ -556,22 +556,32 @@ def test_abi_version_matches_loaded_libraries():
         assert int(fn()) == native._ABI_VERSION
 
 
-def test_stale_cached_so_triggers_rebuild_not_attribute_error(tmp_path):
-    """The ISSUE 9 scenario: a cached .so compiled from OLDER source but
-    with a NEWER mtime (git checkout) lacks the new symbols.  _load_lib
-    must detect the ABI mismatch and rebuild from source — the old
-    behavior was an AttributeError at first use."""
+def _mini_src(marker: int) -> str:
+    return (
+        "#include <cstdint>\n"
+        'extern "C" { uint32_t dlt_abi_version() { return %du; }\n'
+        "int dlt_mini_marker() { return %d; } }\n"
+        % (native._ABI_VERSION, marker)
+    )
+
+
+def _marker(lib) -> int:
+    lib.dlt_mini_marker.restype = ctypes.c_int
+    return lib.dlt_mini_marker()
+
+
+def test_copied_in_so_is_rebuilt_not_loaded(tmp_path):
+    """A ``.so`` this host did not build from the checked-in source (one
+    copied in with the tree, or left by an older source, whatever its
+    mtime) sits under another name than the keyed one and is never
+    opened: _load_lib builds from source instead."""
     if not _have_gxx():
         pytest.skip("no g++ in this environment")
     src = tmp_path / "mini.cpp"
     lib_path = tmp_path / "_mini.so"
-    src.write_text(
-        "#include <cstdint>\n"
-        'extern "C" { uint32_t dlt_abi_version() { return %du; }\n'
-        "int dlt_mini_marker() { return 7; } }\n" % native._ABI_VERSION
-    )
-    # Build a STALE library (no dlt_abi_version at all) and postdate it
-    # so the mtime check alone would keep serving it.
+    src.write_text(_mini_src(7))
+    # A foreign library at the un-keyed name, postdated so an mtime
+    # check would keep serving it; it lacks every symbol.
     stale_src = tmp_path / "stale.cpp"
     stale_src.write_text('extern "C" { int old_symbol() { return 1; } }\n')
     subprocess.run(
@@ -581,9 +591,29 @@ def test_stale_cached_so_triggers_rebuild_not_attribute_error(tmp_path):
     )
     os.utime(lib_path, (2**31 - 10, 2**31 - 10))
     lib = native._load_lib(str(src), str(lib_path), lambda l: None)
-    assert lib is not None, "stale cache must be rebuilt, not served"
-    lib.dlt_mini_marker.restype = ctypes.c_int
-    assert lib.dlt_mini_marker() == 7
+    assert lib is not None, "the foreign library must not be served"
+    assert _marker(lib) == 7
+    built = sorted(p.name for p in tmp_path.glob("_mini.*.so"))
+    assert len(built) == 1 and built[0] != "_mini.so"
+
+
+def test_so_name_is_keyed_on_source_and_flags(tmp_path, monkeypatch):
+    """Editing the source (or changing the flags) changes the library's
+    name, so the next load rebuilds; the superseded build is removed."""
+    if not _have_gxx():
+        pytest.skip("no g++ in this environment")
+    src = tmp_path / "mini.cpp"
+    lib_path = str(tmp_path / "_mini.so")
+    src.write_text(_mini_src(7))
+    first = native._build_lib(str(src), lib_path)
+    assert native._build_lib(str(src), lib_path) == first  # cached
+    src.write_text(_mini_src(8))
+    second = native._build_lib(str(src), lib_path)
+    assert second != first
+    assert not os.path.exists(first) and os.path.exists(second)
+    assert _marker(ctypes.CDLL(second)) == 8
+    monkeypatch.setenv("DLT_NATIVE_EXTRA_CFLAGS", "-DDLT_OTHER_FLAGS")
+    assert native._build_lib(str(src), lib_path) != second
 
 
 def test_wrong_abi_after_rebuild_falls_back_with_counter(tmp_path):
@@ -628,8 +658,8 @@ def test_so_artifacts_are_gitignored():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         ["git", "check-ignore",
-         "distributed_learning_tpu/native/_codec.so",
-         "distributed_learning_tpu/native/_wire.so"],
+         "distributed_learning_tpu/native/_codec.0123456789abcdef.so",
+         "distributed_learning_tpu/native/_wire.0123456789abcdef.so"],
         cwd=repo, capture_output=True, text=True,
     )
     assert out.returncode == 0, "native *.so must be gitignored"

@@ -41,6 +41,7 @@ DEFAULT_ROOTS = (
     "benchmarks",
     "examples",
     "bench.py",
+    "chip_smoke.py",
 )
 
 
