@@ -9,15 +9,17 @@ Chrome ``traceEvents`` JSON (load in ``chrome://tracing`` / Perfetto)
 or aggregates into the run report through the
 :class:`~distributed_learning_tpu.obs.registry.MetricsRegistry`.
 
-``profiler=True`` additionally wraps every span in
-``jax.profiler.TraceAnnotation`` (via
-:func:`distributed_learning_tpu.utils.profiling.annotate`), so the same
-span names appear inside a TensorBoard device profile when one is being
-captured — one naming scheme across both tools.
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+(:func:`distributed_learning_tpu.utils.profiling.annotate`), so the span
+names land on the host plane of any profile being captured, on the
+profiler's own clock and beside the device's operations — one naming
+scheme across both tools.  While no profiler session is open the
+annotation is a flag test.
 
 Everything is host-side: entering/leaving a span is two monotonic clock
-reads and a list append.  No device syncs, no jax import unless
-``profiler=True``.
+reads and a list append.  No device syncs.  ``jax.profiler`` is imported
+at the first span, not with this module, so importing ``obs`` stays
+jax-free.
 """
 
 from __future__ import annotations
@@ -27,7 +29,7 @@ import dataclasses
 import json
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 from distributed_learning_tpu.obs.registry import MetricsRegistry, get_registry
 
@@ -141,9 +143,6 @@ class SpanTracer:
         the JSONL event log.  A zero-arg callable is resolved per span
         (the default tracer passes ``get_registry`` so
         ``use_registry`` scoping applies to spans too).
-    profiler:
-        Also emit each span as a ``jax.profiler.TraceAnnotation`` so the
-        names land inside an active device profile.
     max_spans:
         Bound on the retained per-span detail (aggregates in the
         registry stay exact past the cap; the Chrome export covers the
@@ -159,10 +158,9 @@ class SpanTracer:
     """
 
     def __init__(self, *, registry: Optional[MetricsRegistry] = None,
-                 profiler: bool = False, max_spans: int = 1 << 16,
+                 max_spans: int = 1 << 16,
                  clock=time.perf_counter):
         self.registry = registry
-        self.profiler = bool(profiler)
         self._clock = clock
         self._max_spans = int(max_spans)
         self._lock = threading.Lock()
@@ -183,22 +181,20 @@ class SpanTracer:
         return st
 
     @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[None]:
+    def span(self, name: str, **ids) -> Iterator[None]:
         """Time the enclosed block as span ``name`` (nested spans record
-        their depth and parent)."""
+        their depth and parent).  ``ids`` go to the profiler's copy of
+        the span only."""
+        # imported at the first span: importing ``obs`` stays jax-free
+        from distributed_learning_tpu.utils.profiling import annotate
+
         stack = self._stack()
         depth = len(stack)
         parent = stack[-1] if stack else None
         stack.append(name)
-        if self.profiler:
-            from distributed_learning_tpu.utils.profiling import annotate
-
-            cm: Any = annotate(name)
-        else:
-            cm = contextlib.nullcontext()
         t0 = self._clock()
         try:
-            with cm:
+            with annotate(name, **ids):
                 yield
         finally:
             dur = self._clock() - t0
@@ -310,6 +306,6 @@ def set_tracer(tracer: SpanTracer) -> Optional[SpanTracer]:
         return prev
 
 
-def span(name: str):
+def span(name: str, **ids):
     """Convenience: a span on the default tracer."""
-    return get_tracer().span(name)
+    return get_tracer().span(name, **ids)

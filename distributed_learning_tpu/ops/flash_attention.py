@@ -326,6 +326,7 @@ def _fwd_call(qb, kb, vb, sm_scale, causal, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(qb, kb, vb)
 
 
@@ -371,6 +372,7 @@ def _bwd_call(qb, kb, vb, out, do, lse, dadj, sm_scale, causal, block_q,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qb, kb, vb, out, do, lse, *extra)
 
     dkv_kernel = functools.partial(
@@ -408,6 +410,7 @@ def _bwd_call(qb, kb, vb, out, do, lse, dadj, sm_scale, causal, block_q,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qb, kb, vb, out, do, lse, *extra)
 
     return dq, dk, dv

@@ -207,14 +207,20 @@ def flatten_stacked(
         layout = fused_layout(stacked)
     leaves = jax.tree.leaves(stacked)
     by_bucket: Dict[str, List[jax.Array]] = {}
-    for slot, leaf in zip(layout.slots, leaves):
-        by_bucket.setdefault(slot.bucket, []).append(
-            leaf.reshape(leaf.shape[0], slot.size)
-        )
-    buffers = {
-        name: (parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1))
-        for name, parts in by_bucket.items()
-    }
+    # consensus.pack / .unpack / .round / .residual: the names a profile
+    # shows for the mix's parts (docs/observability.md); metadata only.
+    with jax.named_scope("consensus.pack"):
+        for slot, leaf in zip(layout.slots, leaves):
+            by_bucket.setdefault(slot.bucket, []).append(
+                leaf.reshape(leaf.shape[0], slot.size)
+            )
+        buffers = {
+            name: (
+                parts[0] if len(parts) == 1
+                else jnp.concatenate(parts, axis=1)
+            )
+            for name, parts in by_bucket.items()
+        }
     return buffers, layout
 
 
@@ -224,12 +230,13 @@ def unflatten_stacked(
     """Inverse of :func:`flatten_stacked`: slice each leaf back out of its
     dtype bucket and restore the tree structure (one-time exit cost)."""
     leaves = []
-    for slot in layout.slots:
-        buf = buffers[slot.bucket]
-        piece = jax.lax.slice_in_dim(
-            buf, slot.offset, slot.offset + slot.size, axis=1
-        )
-        leaves.append(piece.reshape((buf.shape[0],) + slot.shape))
+    with jax.named_scope("consensus.unpack"):
+        for slot in layout.slots:
+            buf = buffers[slot.bucket]
+            piece = jax.lax.slice_in_dim(
+                buf, slot.offset, slot.offset + slot.size, axis=1
+            )
+            leaves.append(piece.reshape((buf.shape[0],) + slot.shape))
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)
 
 
@@ -281,7 +288,8 @@ def dense_mix(
         out = jnp.matmul(W.astype(jnp.float32), xf, precision=precision)
         return out.reshape(x.shape).astype(x.dtype)
 
-    return jax.tree.map(mix_leaf, stacked)
+    with jax.named_scope("consensus.round"):
+        return jax.tree.map(mix_leaf, stacked)
 
 
 # --------------------------------------------------------------------- #
@@ -647,13 +655,16 @@ def agent_deviations(stacked: Pytree) -> jax.Array:
     (``mixer.py:5-6, 57-66``) — the norm is over the agent's *entire*
     flattened parameter vector.
     """
-    return jnp.sqrt(_sq_dev_from_mean(stacked))
+    with jax.named_scope("consensus.residual"):
+        return jnp.sqrt(_sq_dev_from_mean(stacked))
 
 
 def max_deviation(stacked: Pytree) -> jax.Array:
     """Scalar: max over agents of :func:`agent_deviations` — the residual the
     eps-stopping rule compares against (``mixer.py:40-41, 51-55``)."""
-    return jnp.max(agent_deviations(stacked))
+    dev = agent_deviations(stacked)
+    with jax.named_scope("consensus.residual"):
+        return jnp.max(dev)
 
 
 def max_std(stacked: Pytree) -> jax.Array:

@@ -31,6 +31,7 @@ per-method parity notes.
 
 from __future__ import annotations
 
+import functools
 from typing import (
     Any,
     Dict,
@@ -63,6 +64,24 @@ __all__ = [
     "ring_offset_weights",
     "local_ring_mix",
 ]
+
+
+def _public_call(name: str):
+    """Open the host span ``name`` at the top of a public engine call,
+    with ``call=<n>`` (the engine's running call count) so that the
+    spans :meth:`ConsensusEngine._launch` opens inside it share its
+    identifier."""
+
+    def deco(method):
+        @functools.wraps(method)
+        def wrapper(self, *args, **kwargs):
+            self._calls += 1
+            with get_tracer().span(name, call=self._calls):
+                return method(self, *args, **kwargs)
+
+        return wrapper
+
+    return deco
 
 
 class AsyncGossipState(NamedTuple):
@@ -178,22 +197,24 @@ def local_ring_mix(
             acc = jax.tree.map(lambda a, v: a + scale(v, w), acc, nb)
         return fwd, bwd, acc
 
-    acc0 = jax.tree.map(lambda v: scale(v, self_w[0]), x)
-    _, _, acc = lax.fori_loop(0, k_hops, body, (x, x, acc0))
-    return jax.tree.map(lambda a, v: a.astype(v.dtype), acc, x)
+    with jax.named_scope("consensus.round"):
+        acc0 = jax.tree.map(lambda v: scale(v, self_w[0]), x)
+        _, _, acc = lax.fori_loop(0, k_hops, body, (x, x, acc0))
+        return jax.tree.map(lambda a, v: a.astype(v.dtype), acc, x)
 
 
 def local_sq_deviation(x: Pytree, axis_name: str) -> jax.Array:
     """This shard's squared L2 distance from the global mean vector (runs
     inside ``shard_map``; the sharded analogue of
     ``ops.agent_deviations``**2)."""
-    total = jnp.float32(0.0)
-    for leaf in jax.tree.leaves(x):
-        # graftlint: disable=raw-collective-in-shard-map -- consensus residual: the pmean over agents IS the statistic (distance from the global mean), not a TP exit
-        mean = lax.pmean(leaf.astype(jnp.float32), axis_name)
-        d = leaf.astype(jnp.float32) - mean
-        total = total + jnp.sum(d * d)
-    return total
+    with jax.named_scope("consensus.residual"):
+        total = jnp.float32(0.0)
+        for leaf in jax.tree.leaves(x):
+            # graftlint: disable=raw-collective-in-shard-map -- consensus residual: the pmean over agents IS the statistic (distance from the global mean), not a TP exit
+            mean = lax.pmean(leaf.astype(jnp.float32), axis_name)
+            d = leaf.astype(jnp.float32) - mean
+            total = total + jnp.sum(d * d)
+        return total
 
 
 class ConsensusEngine:
@@ -249,6 +270,7 @@ class ConsensusEngine:
         self._self_w = jnp.asarray(self.schedule.self_weights, dtype=jnp.float32)
         self._match_w = jnp.asarray(self.schedule.weights, dtype=jnp.float32)
         self._jit_cache: Dict[str, Any] = {}
+        self._calls = 0  # public calls so far: the spans' ``call=`` id
 
     # ------------------------------------------------------------------ #
     # Local (per-shard) building blocks                                  #
@@ -261,14 +283,15 @@ class ConsensusEngine:
         def scale(v: jax.Array, s: jax.Array) -> jax.Array:
             return (v.astype(jnp.float32) * s).astype(v.dtype)
 
-        acc = jax.tree.map(lambda v: scale(v, self_w[0]), x)
-        for r in range(self.schedule.num_rounds):
-            pairs = self.schedule.ppermute_pairs(r)
-            nb = jax.tree.map(lambda v: lax.ppermute(v, ax, pairs), x)
-            acc = jax.tree.map(
-                lambda a, b: a + scale(b, match_w[r, 0]), acc, nb
-            )
-        return acc
+        with jax.named_scope("consensus.round"):
+            acc = jax.tree.map(lambda v: scale(v, self_w[0]), x)
+            for r in range(self.schedule.num_rounds):
+                pairs = self.schedule.ppermute_pairs(r)
+                nb = jax.tree.map(lambda v: lax.ppermute(v, ax, pairs), x)
+                acc = jax.tree.map(
+                    lambda a, b: a + scale(b, match_w[r, 0]), acc, nb
+                )
+            return acc
 
     def _ring_offset_weights(
         self, W: np.ndarray
@@ -302,7 +325,8 @@ class ConsensusEngine:
             )
             return out.reshape(v.shape).astype(v.dtype)
 
-        return jax.tree.map(leaf, x)
+        with jax.named_scope("consensus.round"):
+            return jax.tree.map(leaf, x)
 
     def _local_sq_deviation(self, x: Pytree) -> jax.Array:
         return local_sq_deviation(x, self.axis_name)
@@ -317,12 +341,14 @@ class ConsensusEngine:
     def _dense_residual(x: Pytree) -> jax.Array:
         """Max agent deviation of a (possibly fused) stacked state — the
         eps-stopping residual of the dense programs."""
-        return jnp.max(ops.agent_deviations(x))
+        return ops.max_deviation(x)
 
     def _local_residual(self, x: Pytree) -> jax.Array:
         """The sharded residual: this shard's deviation, pmax'd over the
         agent axis (runs inside ``shard_map``)."""
-        return lax.pmax(jnp.sqrt(self._local_sq_deviation(x)), self.axis_name)
+        sq = self._local_sq_deviation(x)
+        with jax.named_scope("consensus.residual"):
+            return lax.pmax(jnp.sqrt(sq), self.axis_name)
 
     @staticmethod
     def _dense_global_avg(x: Pytree) -> Pytree:
@@ -409,6 +435,32 @@ class ConsensusEngine:
                 layout.bytes_per_round(self.n) * int(rounds),
             )
 
+    @staticmethod
+    def _ring_operands(decomp) -> tuple:
+        """The k-hop ring programs' leading operands, on the device."""
+        self_w, w_fwd, w_bwd, k_hops = decomp
+        return (
+            jnp.asarray(self_w),
+            jnp.asarray(w_fwd),
+            jnp.asarray(w_bwd),
+            jnp.int32(k_hops),
+        )
+
+    def _launch(self, fn, stacked: Pytree, operands=None, *, rounds=None):
+        """The host's part of one public call, each step under its own
+        span inside the call's (:func:`_public_call`):
+        ``consensus.layout`` (:meth:`_note_layout`),
+        ``consensus.operands`` (``operands()`` builds the program's other
+        arguments, the python scalars going to the device there) and
+        ``consensus.dispatch`` (``fn`` enqueued; nothing waits for it)."""
+        span, call = get_tracer().span, self._calls
+        with span("consensus.layout", call=call):
+            self._note_layout(stacked, rounds=rounds)
+        with span("consensus.operands", call=call):
+            args = operands() if operands is not None else ()
+        with span("consensus.dispatch", call=call):
+            return fn(stacked, *args)
+
     # ------------------------------------------------------------------ #
     # Public API                                                         #
     # ------------------------------------------------------------------ #
@@ -427,15 +479,17 @@ class ConsensusEngine:
         if not isinstance(times, jax.core.Tracer):
             get_registry().inc("consensus.rounds_run", int(times))
 
+    @_public_call("consensus.mix")
     def mix(self, stacked: Pytree, times: int = 1) -> Pytree:
         """Run exactly ``times`` gossip rounds (``Mixer.mix(times, eps=None)``
         semantics, ``mixer.py:18-41``)."""
         fn = self._get_jitted("mix")
         self._count_rounds(times)
-        self._note_layout(stacked, rounds=times)
-        with get_tracer().span("consensus.mix"):
-            return fn(stacked, jnp.int32(times))
+        return self._launch(
+            fn, stacked, lambda: (jnp.int32(times),), rounds=times
+        )
 
+    @_public_call("consensus.mix_until")
     def mix_until(
         self,
         stacked: Pytree,
@@ -456,15 +510,17 @@ class ConsensusEngine:
         """
         fn = self._get_jitted("mix_until")
         get_registry().inc("consensus.mix_until.calls")
-        self._note_layout(stacked)
-        with get_tracer().span("consensus.mix_until"):
-            return fn(
-                stacked,
+        return self._launch(
+            fn,
+            stacked,
+            lambda: (
                 jnp.float32(eps),
                 jnp.int32(min_times),
                 jnp.int32(max_rounds),
-            )
+            ),
+        )
 
+    @_public_call("consensus.mix_until_with")
     def mix_until_with(
         self,
         stacked: Pytree,
@@ -488,31 +544,30 @@ class ConsensusEngine:
         all-to-all; ``route="auto"`` picks whichever moves less data.
         """
         W_traced, decomp = self._traced_w_dispatch(W, route)
-        args = (
-            jnp.float32(eps),
-            jnp.int32(min_times),
-            jnp.int32(max_rounds),
-        )
-        get_registry().inc("consensus.mix_until.calls")
-        self._note_layout(stacked)
-        with get_tracer().span("consensus.mix_until_with"):
-            if W_traced is not None:
-                return self._get_jitted("mix_until_with")(
-                    stacked, W_traced, *args
-                )
-            self_w, w_fwd, w_bwd, k_hops = decomp
-            fn = self._get_ring_jitted(
-                "mix_until_with_ring", bool(w_fwd.any()), bool(w_bwd.any())
-            )
-            return fn(
-                stacked,
-                jnp.asarray(self_w),
-                jnp.asarray(w_fwd),
-                jnp.asarray(w_bwd),
-                jnp.int32(k_hops),
-                *args,
+
+        def stop():
+            return (
+                jnp.float32(eps),
+                jnp.int32(min_times),
+                jnp.int32(max_rounds),
             )
 
+        get_registry().inc("consensus.mix_until.calls")
+        if W_traced is not None:
+            return self._launch(
+                self._get_jitted("mix_until_with"),
+                stacked,
+                lambda: (W_traced,) + stop(),
+            )
+        _, w_fwd, w_bwd, _ = decomp
+        fn = self._get_ring_jitted(
+            "mix_until_with_ring", bool(w_fwd.any()), bool(w_bwd.any())
+        )
+        return self._launch(
+            fn, stacked, lambda: self._ring_operands(decomp) + stop()
+        )
+
+    @_public_call("consensus.mix_pairwise")
     def mix_pairwise(
         self,
         stacked: Pytree,
@@ -549,10 +604,8 @@ class ConsensusEngine:
         if len(edges) == 0:
             return stacked
         self._count_rounds(rounds)
-        self._note_layout(stacked, rounds=rounds)
         if self.mesh is not None:
-            with get_tracer().span("consensus.mix_pairwise"):
-                return self._mix_pairwise_sharded(stacked, key, rounds, edges)
+            return self._mix_pairwise_sharded(stacked, key, rounds, edges)
         ckey = ("pairwise", len(edges))
         if ckey not in self._jit_cache:
             edges_dev = jnp.asarray(edges, jnp.int32)
@@ -578,8 +631,12 @@ class ConsensusEngine:
                 return out
 
             self._jit_cache[ckey] = jax.jit(self._fuse_state_fn(f))
-        with get_tracer().span("consensus.mix_pairwise"):
-            return self._jit_cache[ckey](stacked, key, jnp.int32(rounds))
+        return self._launch(
+            self._jit_cache[ckey],
+            stacked,
+            lambda: (key, jnp.int32(rounds)),
+            rounds=rounds,
+        )
 
     def _random_maximal_matchings(
         self, edges: np.ndarray
@@ -675,8 +732,14 @@ class ConsensusEngine:
                     out_specs=P(ax),
                 )
             )
-        return self._jit_cache[ckey](stacked, key, jnp.int32(rounds))
+        return self._launch(
+            self._jit_cache[ckey],
+            stacked,
+            lambda: (key, jnp.int32(rounds)),
+            rounds=rounds,
+        )
 
+    @_public_call("consensus.mix_chebyshev")
     def mix_chebyshev(self, stacked: Pytree, times: int) -> Pytree:
         """``times`` rounds of Chebyshev-accelerated gossip (BASELINE
         config 5: "Chebyshev-accelerated averaging").
@@ -693,9 +756,7 @@ class ConsensusEngine:
                 lambda x: self._run_chebyshev(x, omegas)
             )
         self._count_rounds(times)
-        self._note_layout(stacked, rounds=times)
-        with get_tracer().span("consensus.mix_chebyshev"):
-            return self._jit_cache[key](stacked)
+        return self._launch(self._jit_cache[key], stacked, rounds=times)
 
     def _traced_w_dispatch(self, W, route: str):
         """Shared guard for the traced-W entry points.
@@ -744,6 +805,7 @@ class ConsensusEngine:
             route = "ring" if 2 * k_hops < self.n - 1 else "allgather"
         return route, (self_w, w_fwd, w_bwd, k_hops)
 
+    @_public_call("consensus.mix_with")
     def mix_with(
         self, stacked: Pytree, W, times: int = 1, *, route: str = "auto"
     ) -> Pytree:
@@ -765,25 +827,25 @@ class ConsensusEngine:
         """
         W_traced, decomp = self._traced_w_dispatch(W, route)
         self._count_rounds(times)
-        self._note_layout(stacked, rounds=times)
-        with get_tracer().span("consensus.mix_with"):
-            if W_traced is not None:
-                return self._get_jitted("mix_with")(
-                    stacked, W_traced, jnp.int32(times)
-                )
-            self_w, w_fwd, w_bwd, k_hops = decomp
-            fn = self._get_ring_jitted(
-                "mix_with_ring", bool(w_fwd.any()), bool(w_bwd.any())
-            )
-            return fn(
+        if W_traced is not None:
+            return self._launch(
+                self._get_jitted("mix_with"),
                 stacked,
-                jnp.asarray(self_w),
-                jnp.asarray(w_fwd),
-                jnp.asarray(w_bwd),
-                jnp.int32(k_hops),
-                jnp.int32(times),
+                lambda: (W_traced, jnp.int32(times)),
+                rounds=times,
             )
+        _, w_fwd, w_bwd, _ = decomp
+        fn = self._get_ring_jitted(
+            "mix_with_ring", bool(w_fwd.any()), bool(w_bwd.any())
+        )
+        return self._launch(
+            fn,
+            stacked,
+            lambda: self._ring_operands(decomp) + (jnp.int32(times),),
+            rounds=times,
+        )
 
+    @_public_call("consensus.mix_chebyshev_with")
     def mix_chebyshev_with(
         self, stacked: Pytree, W, omegas, *, route: str = "auto"
     ) -> Pytree:
@@ -798,26 +860,27 @@ class ConsensusEngine:
         """
         omegas = jnp.asarray(omegas, dtype=jnp.float32)
         W_traced, decomp = self._traced_w_dispatch(W, route)
-        self._count_rounds(int(omegas.shape[0]))
-        self._note_layout(stacked, rounds=int(omegas.shape[0]))
-        with get_tracer().span("consensus.mix_chebyshev_with"):
-            if W_traced is not None:
-                return self._get_jitted("mix_chebyshev_with")(
-                    stacked, W_traced, omegas
-                )
-            self_w, w_fwd, w_bwd, k_hops = decomp
-            fn = self._get_ring_jitted(
-                "mix_chebyshev_with_ring", bool(w_fwd.any()), bool(w_bwd.any())
-            )
-            return fn(
+        times = int(omegas.shape[0])
+        self._count_rounds(times)
+        if W_traced is not None:
+            return self._launch(
+                self._get_jitted("mix_chebyshev_with"),
                 stacked,
-                jnp.asarray(self_w),
-                jnp.asarray(w_fwd),
-                jnp.asarray(w_bwd),
-                jnp.int32(k_hops),
-                omegas,
+                lambda: (W_traced, omegas),
+                rounds=times,
             )
+        _, w_fwd, w_bwd, _ = decomp
+        fn = self._get_ring_jitted(
+            "mix_chebyshev_with_ring", bool(w_fwd.any()), bool(w_bwd.any())
+        )
+        return self._launch(
+            fn,
+            stacked,
+            lambda: self._ring_operands(decomp) + (omegas,),
+            rounds=times,
+        )
 
+    @_public_call("consensus.global_average")
     def global_average(self, stacked: Pytree) -> Pytree:
         """Exact averaging — the gamma=0 degenerate case (centralized DP
         all-reduce).  Dense mode is a mean over the agent axis; sharded
@@ -830,9 +893,9 @@ class ConsensusEngine:
         the accumulated consensus error at bounded extra bandwidth).
         """
         get_registry().inc("consensus.global_averages")
-        self._note_layout(stacked, rounds=1)
-        with get_tracer().span("consensus.global_average"):
-            return self._get_jitted("global_average")(stacked)
+        return self._launch(
+            self._get_jitted("global_average"), stacked, rounds=1
+        )
 
     def run_round(
         self,
@@ -1446,6 +1509,7 @@ class ConsensusEngine:
 
         return program
 
+    @_public_call("consensus.mix_async")
     def mix_async(
         self,
         stacked: Pytree,
@@ -1475,12 +1539,15 @@ class ConsensusEngine:
                     tau=tau, periods=periods, times=times
                 )
             )
-        if state is None:
-            state = self.init_async_state(stacked)
         self._count_rounds(times)
-        self._note_layout(stacked, rounds=times)
-        with get_tracer().span("consensus.mix_async"):
-            return self._jit_cache[key](stacked, state)
+        return self._launch(
+            self._jit_cache[key],
+            stacked,
+            lambda: (
+                self.init_async_state(stacked) if state is None else state,
+            ),
+            rounds=times,
+        )
 
     # ------------------------------------------------------------------ #
     # Byzantine-robust variants (parallel/robust.py)                     #
@@ -1493,6 +1560,7 @@ class ConsensusEngine:
 
         return robust.robust_mix_program(self, spec, times)
 
+    @_public_call("consensus.mix_robust")
     def mix_robust(self, stacked: Pytree, spec, times: int = 1):
         """Run ``times`` robust gossip rounds; returns ``(mixed, mass)``
         where ``mass`` is the total edge weight the defense redirected to
@@ -1507,9 +1575,9 @@ class ConsensusEngine:
                 robust.robust_mix_program(self, cfg, times)
             )
         self._count_rounds(times)
-        self._note_layout(stacked, rounds=times)
-        with get_tracer().span("consensus.mix_robust"):
-            mixed, mass = self._jit_cache[key](stacked)
+        mixed, mass = self._launch(
+            self._jit_cache[key], stacked, rounds=times
+        )
         get_registry().inc("consensus.robust.rounds", int(times))
         return mixed, mass
 
@@ -1525,6 +1593,7 @@ class ConsensusEngine:
             self, spec, tau=tau, periods=periods, times=times
         )
 
+    @_public_call("consensus.mix_async_robust")
     def mix_async_robust(
         self,
         stacked: Pytree,
@@ -1550,12 +1619,15 @@ class ConsensusEngine:
                     self, cfg, tau=tau, periods=periods, times=times
                 )
             )
-        if state is None:
-            state = self.init_async_state(stacked)
         self._count_rounds(times)
-        self._note_layout(stacked, rounds=times)
-        with get_tracer().span("consensus.mix_async_robust"):
-            return self._jit_cache[key](stacked, state)
+        return self._launch(
+            self._jit_cache[key],
+            stacked,
+            lambda: (
+                self.init_async_state(stacked) if state is None else state,
+            ),
+            rounds=times,
+        )
 
     def cost_profile(self, stacked: Pytree, *, times: int = 1,
                      name: str = "consensus.mix"):

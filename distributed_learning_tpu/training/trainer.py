@@ -53,6 +53,7 @@ from distributed_learning_tpu.parallel.consensus import (
 )
 from distributed_learning_tpu.parallel.schedule import chebyshev_omegas
 from distributed_learning_tpu.parallel.topology import Topology, gamma as mixing_gamma
+from distributed_learning_tpu.utils.profiling import annotate
 from distributed_learning_tpu.utils.telemetry import TelemetryProcessor
 
 Pytree = Any
@@ -764,6 +765,10 @@ class GossipTrainer:
         aug_pad = self.augment_pad_value
         remat = self.remat
 
+        # The jax.named_scope blocks below name the device program's
+        # parts in a profile (gather / augment / fwd_bwd / carry / opt;
+        # docs/observability.md).  They are metadata on the ops: the
+        # compiled program is the same with or without them.
         def train_step(params, batch_stats, opt_state, x, y, rng):
             if augment:
                 # Jitted RandomCrop(32, pad 4) + flip fused into the step
@@ -772,8 +777,9 @@ class GossipTrainer:
                 # crop borders that match its crop-before-normalize order).
                 from distributed_learning_tpu.data.cifar import augment_batch
 
-                rng, k_aug = jax.random.split(rng)
-                x = augment_batch(k_aug, x, pad_value=aug_pad)
+                with jax.named_scope("augment"):
+                    rng, k_aug = jax.random.split(rng)
+                    x = augment_batch(k_aug, x, pad_value=aug_pad)
 
             def lossf(p):
                 variables = {"params": p}
@@ -782,18 +788,21 @@ class GossipTrainer:
                 mutable = ["moe_stats"] + (
                     ["batch_stats"] if batch_stats is not None else []
                 )
-                logits, mut = model.apply(
-                    variables,
-                    x,
-                    train=True,
-                    rngs={"dropout": rng} if has_dropout else {},
-                    mutable=mutable,
-                )
-                loss = loss_fn(logits, y)
-                aux = collect_load_balance_loss(mut)
-                if aux is not None:
-                    loss = loss + moe_aux_coef * aux
-                acc = metric_fn(logits, y)
+                # Inside lossf, so that JAX names the forward ops
+                # jvp(fwd_bwd) and the backward transpose(jvp(fwd_bwd)).
+                with jax.named_scope("fwd_bwd"):
+                    logits, mut = model.apply(
+                        variables,
+                        x,
+                        train=True,
+                        rngs={"dropout": rng} if has_dropout else {},
+                        mutable=mutable,
+                    )
+                    loss = loss_fn(logits, y)
+                    aux = collect_load_balance_loss(mut)
+                    if aux is not None:
+                        loss = loss + moe_aux_coef * aux
+                    acc = metric_fn(logits, y)
                 return loss, (mut.get("batch_stats", None), acc)
 
             if remat:
@@ -806,9 +815,11 @@ class GossipTrainer:
             # Device-side metrics carry (obs/carry.py): the grad norm is
             # computed on device and stacked by the epoch scan; the host
             # reads it once per chunk alongside the loss trace.
-            gnorm = obs_global_norm(grads)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("carry"):
+                gnorm = obs_global_norm(grads)
+            with jax.named_scope("opt"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, new_bs, opt_state, loss, acc, gnorm
 
         vstep = jax.vmap(train_step)
@@ -828,8 +839,9 @@ class GossipTrainer:
 
             def body(carry, idx_t):
                 params, bs, opt, rng = carry
-                x = take(Xs, idx_t)
-                y = take(ys, idx_t)
+                with jax.named_scope("gather"):
+                    x = take(Xs, idx_t)
+                    y = take(ys, idx_t)
                 rng, *subs = jax.random.split(rng, n + 1)
                 subkeys = jnp.stack(subs)
                 params, bs, opt, loss, acc, gnorm = vstep(
@@ -1153,14 +1165,15 @@ class GossipTrainer:
         te = np.floor(np.float32(t) * mult + np.float32(0.5))
         return int(np.clip(te, c["min_times"], c["max_times"]))
 
-    def _span(self, name: str):
-        """Wall-clock span on the trainer's tracer (no-op when obs is
-        disabled)."""
-        import contextlib
-
+    def _span(self, name: str, **ids):
+        """One thing the host does, named.  Always a
+        ``jax.profiler.TraceAnnotation`` (a profile of any run shows the
+        trainer's spans on the host plane, ``ids`` among their stats);
+        with obs on it is also a wall-clock span on the trainer's
+        tracer."""
         if self._obs_tracer is None:
-            return contextlib.nullcontext()
-        return self._obs_tracer.span(name)
+            return annotate(name, **ids)
+        return self._obs_tracer.span(name, **ids)
 
     def cost_profile(self, k: Optional[int] = None):
         """:class:`~distributed_learning_tpu.obs.cost.CostProfile` of
@@ -1207,7 +1220,7 @@ class GossipTrainer:
 
     def train_epoch(self) -> Dict[str, Any]:
         """One epoch: local SGD on every node, then (maybe) gossip."""
-        with self._span("trainer.epoch"):
+        with self._span("trainer.epoch", epoch=self._epochs_done):
             return self._train_epoch()
 
     def _count_dispatch(self, n: int = 1) -> None:
@@ -1224,7 +1237,8 @@ class GossipTrainer:
             self.initialize_nodes()
         self._maybe_profile_costs()
         epoch_idx = self._epochs_done
-        idx = self._epoch_indices(epoch_idx)
+        with self._span("trainer.indices", epoch=epoch_idx):
+            idx = self._epoch_indices(epoch_idx)
         mixed = False
         rounds: Any = 0
         # Sampled dispatch timer (obs/cost.py): tick is two host integer
@@ -1234,31 +1248,33 @@ class GossipTrainer:
         sampled = timer.tick() if timer is not None else False
         t0 = time.perf_counter() if sampled else 0.0
         try:
-            with self._span("trainer.chunk"):
+            with self._span("trainer.dispatch", epoch=epoch_idx):
                 self._state, losses, accs, gnorms = self._jit_epoch(
                     self._state, self._Xs, self._ys, idx
                 )
+            self._count_dispatch()
+            # Consensus from epoch_cons_num onward (parity: Man_Colab
+            # cell 21 "the first epoch from which consensus begins";
+            # 1-based epochs).  Dispatched BEFORE the chunk flush so
+            # the eps path's device-side round count materializes at
+            # the same host boundary as the metric traces — one sync
+            # region per epoch, not a flush sync plus a blocking
+            # ``int(t)`` readback.
+            params, bs, opt, rng = self._state
+            if (epoch_idx + 1 >= self.epoch_cons_num
+                    and len(self.node_names) > 1):
+                with self._span("trainer.mix", epoch=epoch_idx):
+                    params, rounds = self._gossip(epoch_idx, params)
                 self._count_dispatch()
-                # Consensus from epoch_cons_num onward (parity: Man_Colab
-                # cell 21 "the first epoch from which consensus begins";
-                # 1-based epochs).  Dispatched BEFORE the chunk flush so
-                # the eps path's device-side round count materializes at
-                # the same host boundary as the metric traces — one sync
-                # region per epoch, not a flush sync plus a blocking
-                # ``int(t)`` readback.
-                params, bs, opt, rng = self._state
-                if (epoch_idx + 1 >= self.epoch_cons_num
-                        and len(self.node_names) > 1):
-                    with self._span("trainer.mix"):
-                        params, rounds = self._gossip(epoch_idx, params)
-                    self._count_dispatch()
-                    mixed = True
-                    self._state = (params, bs, opt, rng)
-                # Materialize inside the try: dispatch is async, so an
-                # execution failure (e.g. OOM) surfaces here, not at the
-                # calls above.  flush_chunk is the carry's single
-                # per-chunk host materialization; with obs enabled the
-                # same arrays also land in the registry as series.
+                mixed = True
+                self._state = (params, bs, opt, rng)
+            # Materialize inside the try: dispatch is async, so an
+            # execution failure (e.g. OOM) surfaces here, not at the
+            # calls above.  flush_chunk is the carry's single
+            # per-chunk host materialization; with obs enabled the
+            # same arrays also land in the registry as series.
+            # ``trainer.flush`` is the host waiting for the device.
+            with self._span("trainer.flush", epoch=epoch_idx):
                 arrs = flush_chunk(
                     self._obs_registry,
                     {"loss": losses, "acc": accs, "grad_norm": gnorms},
@@ -1299,26 +1315,33 @@ class GossipTrainer:
             raise
 
         # Stats every stat_step batches.
-        for s in range(0, losses.shape[0], self.stat_step):
-            chunk = slice(s, min(s + self.stat_step, losses.shape[0]))
-            for a, name in enumerate(self.node_names):
-                node = self.network[name]
-                node.stats.steps.append(self._global_step + chunk.stop)
-                node.stats.train_loss.append(float(losses[chunk, a].mean()))
-                node.stats.train_acc.append(float(accs[chunk, a].mean()))
+        with self._span("trainer.stats", epoch=epoch_idx):
+            for s in range(0, losses.shape[0], self.stat_step):
+                chunk = slice(s, min(s + self.stat_step, losses.shape[0]))
+                for a, name in enumerate(self.node_names):
+                    node = self.network[name]
+                    node.stats.steps.append(self._global_step + chunk.stop)
+                    node.stats.train_loss.append(
+                        float(losses[chunk, a].mean())
+                    )
+                    node.stats.train_acc.append(
+                        float(accs[chunk, a].mean())
+                    )
         self._global_step += losses.shape[0]
         self._epochs_done += 1
 
         test_accs = None
         if self.test_data is not None:
-            with self._span("trainer.eval"):
+            with self._span("trainer.eval", epoch=epoch_idx):
                 test_accs = self._eval_accuracy(params, bs)
             for a, name in enumerate(self.node_names):
                 node = self.network[name]
                 node.stats.test_acc.append(float(test_accs[a]))
                 node.stats.test_epochs.append(self._global_step)
 
-        self._count_dispatch()  # the deviation readout below
+        self._count_dispatch()  # the deviation readout
+        with self._span("trainer.deviation", epoch=epoch_idx):
+            deviation = float(self.engine.max_deviation(params))
         payload = {
             "epoch": epoch_idx,
             "mixed": mixed,
@@ -1327,7 +1350,7 @@ class GossipTrainer:
             "grad_norm": gnorms.mean(axis=0),
             "test_acc": test_accs,
             "mix_rounds": mix_rounds,
-            "deviation": float(self.engine.max_deviation(params)),
+            "deviation": deviation,
         }
         if self._adaptive_cfg is not None:
             # Feed the controller: next epoch's round budget is scaled
@@ -1378,7 +1401,7 @@ class GossipTrainer:
                     "mfu": self._cost_timer.last_mfu if sampled else None,
                 }
             )
-            with self._span("trainer.telemetry"):
+            with self._span("trainer.telemetry", epoch=epoch_idx):
                 for a, name in enumerate(self.node_names):
                     self.telemetry.process(
                         name,
@@ -1652,13 +1675,14 @@ class GossipTrainer:
             def mix_branch(op):
                 p, mix, sch, res = op
                 t = adapt(sch["times"], res)
-                cs = prog(
-                    ChocoState(
-                        x=p, xhat=mix["xhat"], key=mix["key"],
-                        ef=mix["ef"],
-                    ),
-                    t,
-                )
+                with jax.named_scope("compress"):
+                    cs = prog(
+                        ChocoState(
+                            x=p, xhat=mix["xhat"], key=mix["key"],
+                            ef=mix["ef"],
+                        ),
+                        t,
+                    )
                 return (
                     cs.x,
                     {"xhat": cs.xhat, "key": cs.key, "ef": cs.ef},
@@ -1738,10 +1762,11 @@ class GossipTrainer:
                     state, Xs, ys, idx_e
                 )
                 params, bs, opt, rng = state
-                params, mix, rounds, mass = jax.lax.switch(
-                    mode_e, branches,
-                    (params, gc["mix"], sched_e, gc["res"]),
-                )
+                with jax.named_scope("mix"):
+                    params, mix, rounds, mass = jax.lax.switch(
+                        mode_e, branches,
+                        (params, gc["mix"], sched_e, gc["res"]),
+                    )
                 # Post-mix residual, branch-uniform (outside the
                 # switch): the per-epoch consensus trace AND the
                 # adaptive controller's next-epoch input.
@@ -1803,7 +1828,7 @@ class GossipTrainer:
             # already compiled (and is the oracle the superstep is
             # measured against).
             return [self.train_epoch()]
-        with self._span("trainer.superstep"):
+        with self._span("trainer.superstep", epoch=self._epochs_done, k=k):
             return self._train_superstep(k)
 
     def _train_superstep(self, k: int) -> List[Dict[str, Any]]:
@@ -1811,17 +1836,20 @@ class GossipTrainer:
             self.initialize_nodes()
         self._maybe_profile_costs(k)
         epoch0 = self._epochs_done
-        idx = self._superstep_indices(epoch0, k)  # ONE host->device copy
-        modes_host = [self._epoch_mode(epoch0 + j) for j in range(k)]
-        modes = jnp.asarray(modes_host, dtype=jnp.int32)
-        sched = self._superstep_sched(epoch0, k)
-        gcarry = self._superstep_carry()
+        # The chunk's operands: shuffle indices (ONE host->device copy),
+        # per-epoch modes and gossip schedule, the gossip carry.
+        with self._span("trainer.indices", epoch=epoch0):
+            idx = self._superstep_indices(epoch0, k)
+            modes_host = [self._epoch_mode(epoch0 + j) for j in range(k)]
+            modes = jnp.asarray(modes_host, dtype=jnp.int32)
+            sched = self._superstep_sched(epoch0, k)
+            gcarry = self._superstep_carry()
         fn = self._build_superstep(k)
         timer = self._cost_timer
         sampled = timer.tick() if timer is not None else False
         t0 = time.perf_counter() if sampled else 0.0
         try:
-            with self._span("trainer.chunk"):
+            with self._span("trainer.dispatch", epoch=epoch0):
                 (
                     self._state, gcarry, losses, accs, gnorms, rounds,
                     masses, devs,
@@ -1829,11 +1857,12 @@ class GossipTrainer:
                     self._state, gcarry, self._Xs, self._ys, idx, modes,
                     sched,
                 )
-                self._count_dispatch()
-                # The superstep's single host boundary: traces, per-epoch
-                # round counts / residuals / robust mass all materialize
-                # here (flush_chunk collapses the (k, steps, n) traces to
-                # one k*steps-step chunk for the registry).
+            self._count_dispatch()
+            # The superstep's single host boundary: traces, per-epoch
+            # round counts / residuals / robust mass all materialize
+            # here (flush_chunk collapses the (k, steps, n) traces to
+            # one k*steps-step chunk for the registry).
+            with self._span("trainer.flush", epoch=epoch0):
                 arrs = flush_chunk(
                     self._obs_registry,
                     {"loss": losses, "acc": accs, "grad_norm": gnorms},
@@ -1894,58 +1923,59 @@ class GossipTrainer:
         test_accs = None
         if self.test_data is not None:
             # Evaluated once per superstep, on the boundary state.
-            with self._span("trainer.eval"):
+            with self._span("trainer.eval", epoch=epoch0):
                 test_accs = self._eval_accuracy(params, bs)
 
         payloads: List[Dict[str, Any]] = []
-        for j in range(k):
-            epoch_idx = epoch0 + j
-            final = j == k - 1
-            step_base = self._global_step
-            for s in range(0, steps, self.stat_step):
-                chunk = slice(s, min(s + self.stat_step, steps))
-                for a, name in enumerate(self.node_names):
-                    node = self.network[name]
-                    node.stats.steps.append(step_base + chunk.stop)
-                    node.stats.train_loss.append(
-                        float(losses[j, chunk, a].mean())
+        with self._span("trainer.stats", epoch=epoch0):
+            for j in range(k):
+                epoch_idx = epoch0 + j
+                final = j == k - 1
+                step_base = self._global_step
+                for s in range(0, steps, self.stat_step):
+                    chunk = slice(s, min(s + self.stat_step, steps))
+                    for a, name in enumerate(self.node_names):
+                        node = self.network[name]
+                        node.stats.steps.append(step_base + chunk.stop)
+                        node.stats.train_loss.append(
+                            float(losses[j, chunk, a].mean())
+                        )
+                        node.stats.train_acc.append(
+                            float(accs[j, chunk, a].mean())
+                        )
+                self._global_step += steps
+                self._epochs_done += 1
+                payloads.append({
+                    "epoch": epoch_idx,
+                    "mixed": modes_host[j] != 0,
+                    "train_loss": losses[j].mean(axis=0),
+                    "train_acc": accs[j].mean(axis=0),
+                    "grad_norm": gnorms[j].mean(axis=0),
+                    "test_acc": test_accs if final else None,
+                    "mix_rounds": int(rounds_host[j]),
+                    "deviation": float(devs_host[j]),
+                })
+                if self._obs_registry is not None:
+                    # Per-epoch consensus traces, as on the per-epoch path
+                    # (the adaptive controller's readout; arXiv 2105.09080
+                    # headline residual series).
+                    self._obs_registry.observe(
+                        "consensus.residual", float(devs_host[j]),
+                        step=self._global_step,
                     )
-                    node.stats.train_acc.append(
-                        float(accs[j, chunk, a].mean())
-                    )
-            self._global_step += steps
-            self._epochs_done += 1
-            payloads.append({
-                "epoch": epoch_idx,
-                "mixed": modes_host[j] != 0,
-                "train_loss": losses[j].mean(axis=0),
-                "train_acc": accs[j].mean(axis=0),
-                "grad_norm": gnorms[j].mean(axis=0),
-                "test_acc": test_accs if final else None,
-                "mix_rounds": int(rounds_host[j]),
-                "deviation": float(devs_host[j]),
-            })
-            if self._obs_registry is not None:
-                # Per-epoch consensus traces, as on the per-epoch path
-                # (the adaptive controller's readout; arXiv 2105.09080
-                # headline residual series).
-                self._obs_registry.observe(
-                    "consensus.residual", float(devs_host[j]),
-                    step=self._global_step,
-                )
-                if modes_host[j]:
-                    self._obs_registry.inc(
-                        "consensus.rounds_run", int(rounds_host[j])
-                    )
-                    if masses_host is not None:
-                        mass_j = float(masses_host[j])
+                    if modes_host[j]:
                         self._obs_registry.inc(
-                            "consensus.robust.clipped_mass", mass_j
+                            "consensus.rounds_run", int(rounds_host[j])
                         )
-                        self._obs_registry.observe(
-                            "consensus.robust.mass", mass_j,
-                            step=self._global_step,
-                        )
+                        if masses_host is not None:
+                            mass_j = float(masses_host[j])
+                            self._obs_registry.inc(
+                                "consensus.robust.clipped_mass", mass_j
+                            )
+                            self._obs_registry.observe(
+                                "consensus.robust.mass", mass_j,
+                                step=self._global_step,
+                            )
         if test_accs is not None:
             for a, name in enumerate(self.node_names):
                 node = self.network[name]
@@ -1970,7 +2000,7 @@ class GossipTrainer:
                     "mfu": self._cost_timer.last_mfu if sampled else None,
                 }
             )
-            with self._span("trainer.telemetry"):
+            with self._span("trainer.telemetry", epoch=epoch0):
                 for payload in payloads:
                     for a, name in enumerate(self.node_names):
                         self.telemetry.process(

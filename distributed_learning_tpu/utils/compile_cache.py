@@ -23,11 +23,17 @@ def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
     ``JAX_COMPILATION_CACHE_DIR`` places it from outside: JAX reads that
-    variable itself and this sets nothing.  Without it the cache sits at
+    variable itself and this sets no directory.  Without it the cache sits at
     ``<checkout>/.jax_cache`` — a fixed path, because the path is part
     of what a cache entry is keyed on and a directory that moves never
     hits.
     """
+    # JAX leaves the ops' metadata (``jax.named_scope`` paths, source
+    # lines) out of an entry's key by default, so an executable compiled
+    # before a scope was named is served after it, and a profile shows the
+    # old names.  The names are what the program's profiles are read by
+    # (docs/observability.md), so entries are keyed on them too.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -67,7 +67,13 @@ def test_cache_helper_leaves_a_placed_cache_alone(monkeypatch, tmp_path):
     placed = str(tmp_path / "elsewhere")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
     before = jax.config.jax_compilation_cache_dir
-    assert enable_compile_cache() == placed
+    try:
+        assert enable_compile_cache() == placed
+        # entries are keyed on the ops' names too (the profiles' scopes)
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", False)
     assert jax.config.jax_compilation_cache_dir == before
 
 
@@ -80,4 +86,6 @@ def test_cache_helper_default_is_one_fixed_path_in_the_checkout(monkeypatch):
         assert enable_compile_cache() == first
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", False)
     assert first == os.path.join(REPO, ".jax_cache")

@@ -384,26 +384,32 @@ def _tiny_trainer(obs_arg, seed_data=0):
     )
 
 
-def test_trainer_obs_enabled_is_bit_identical_to_disabled():
+def test_trainer_obs_enabled_is_bit_identical_to_disabled(tmp_path):
     import jax
 
     reg = MetricsRegistry()
     t_on = _tiny_trainer(reg)
     t_off = _tiny_trainer(None)
+    t_prof = _tiny_trainer(None)
     outs_on = t_on.start_consensus()
     outs_off = t_off.start_consensus()
+    # Under an open profiler session every span is a live annotation and
+    # the programs' named scopes are being recorded: still the same bits.
+    with jax.profiler.trace(str(tmp_path)):
+        outs_prof = t_prof.start_consensus()
 
     # Exact equality: final params, every epoch's loss/acc trace.
-    for a, b in zip(
-        jax.tree.leaves(t_on.state[0]), jax.tree.leaves(t_off.state[0])
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for oa, ob in zip(outs_on, outs_off):
-        np.testing.assert_array_equal(oa["train_loss"], ob["train_loss"])
-        np.testing.assert_array_equal(oa["train_acc"], ob["train_acc"])
-        np.testing.assert_array_equal(oa["grad_norm"], ob["grad_norm"])
-        assert oa["mix_rounds"] == ob["mix_rounds"] > 0
-        assert oa["deviation"] == ob["deviation"]
+    for other, outs in ((t_off, outs_off), (t_prof, outs_prof)):
+        for a, b in zip(
+            jax.tree.leaves(t_on.state[0]), jax.tree.leaves(other.state[0])
+        ):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for oa, ob in zip(outs_on, outs):
+            np.testing.assert_array_equal(oa["train_loss"], ob["train_loss"])
+            np.testing.assert_array_equal(oa["train_acc"], ob["train_acc"])
+            np.testing.assert_array_equal(oa["grad_norm"], ob["grad_norm"])
+            assert oa["mix_rounds"] == ob["mix_rounds"] > 0
+            assert oa["deviation"] == ob["deviation"]
 
     # And the enabled run actually observed things.
     rep = reg.run_report()
@@ -411,7 +417,9 @@ def test_trainer_obs_enabled_is_bit_identical_to_disabled():
     assert rep["series"]["train.loss"]["count"] == 2
     assert rep["series"]["train.grad_norm/0"]["count"] == 2
     assert rep["series"]["consensus.residual"]["count"] == 2
-    for name in ("trainer.epoch", "trainer.chunk", "trainer.mix"):
+    for name in ("trainer.epoch", "trainer.indices", "trainer.dispatch",
+                 "trainer.mix", "trainer.flush", "trainer.stats",
+                 "trainer.deviation"):
         assert rep["spans"][name]["count"] == 2, name
 
 
