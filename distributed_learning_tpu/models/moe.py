@@ -17,6 +17,12 @@ returned aux so tests and training can watch it.
 
 ``shard_moe_params`` places the stacked expert kernels over the mesh;
 everything else in the layer is replicated.
+
+:class:`HeldExpertsMLP` is the other formulation, for models with far
+more experts than a chip holds (arXiv:2101.03961's expert parallelism
+seen from ONE of its chips): the layer is told which experts it holds,
+routes over all of them, computes the part of the result its own experts
+give, and drops no token.
 """
 
 from __future__ import annotations
@@ -31,6 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "MoEMLP",
+    "HeldExpertsMLP",
     "shard_moe_params",
     "moe_param_spec",
     "collect_load_balance_loss",
@@ -304,6 +311,127 @@ class MoEMLP(nn.Module):
             "moe_stats", "dropped_fraction", jnp.zeros(()),
             reduce_fn=lambda a, b: b,
         )
+        return out.reshape(B, T, d).astype(x.dtype)
+
+
+class HeldExpertsMLP(nn.Module):
+    """One chip's share of a top-k expert layer with a shared expert
+    (the sparse block of arXiv:2101.03961 as hybrid MoE LMs configure
+    it: softmax router, renormalised top-k, SwiGLU experts of a stated
+    width, one always-on shared expert behind a sigmoid gate).
+
+    The router scores all ``num_experts`` in f32 (softmax over all of
+    them, top ``top_k``, renormalised when ``norm_topk``); this layer
+    holds experts ``[first_expert, first_expert + experts_held)`` and
+    computes their part of the sum alone: a (token, choice) pair that
+    falls on an expert held elsewhere adds nothing here, and a token
+    whose choices all lie elsewhere gets the shared expert alone.  No
+    exchange and no stand-in for the absent chips.
+
+    Sized for the worst case the routing can produce, which is every
+    token on every held expert: each held expert runs over ALL the
+    tokens and a token's result is weighted by its gate for that expert,
+    zero where the expert was not among its choices (dense dispatch over
+    the held experts: ``experts_held * tokens`` rows whatever the router
+    does, three large matrix products, no gather, no scatter, no buffer
+    that can overflow).  A sorted buffer of fewer rows was built first
+    and measured on the chip: a share's router LEARNS to prefer the
+    experts held here (the absent ones add nothing, so only these lower
+    the loss), the held pairs grew sevenfold within sixty steps and
+    overran any buffer short of the worst case, at which size the
+    buffer's gathers cost more than this (PERF.md, PR 28).
+
+    Counters sown under ``counters`` (``obs/carry.py::collect_counters``):
+    ``moe.rows_held`` (the (token, choice) pairs that fall on held
+    experts) and ``moe.load_max`` (the fullest held expert's pairs): what
+    a dispatch that gathers only the chosen rows would have to place.
+    Nothing counts cut pairs, because dense dispatch has no way to cut
+    one.  Input/output (B, T, d); parameters f32.
+    """
+
+    num_experts: int
+    experts_held: int
+    first_expert: int = 0
+    top_k: int = 1
+    expert_width: int = 512
+    shared_width: int = 512
+    norm_topk: bool = True
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, d = x.shape
+        E, Eh, K = self.num_experts, self.experts_held, self.top_k
+        if not (1 <= K <= E and 1 <= Eh and 0 <= self.first_expert
+                and self.first_expert + Eh <= E):
+            raise ValueError(
+                f"top_k {K}, experts [{self.first_expert}, "
+                f"{self.first_expert + Eh}) do not fit {E} experts"
+            )
+        S, h = B * T, self.expert_width
+        tokens = x.reshape(S, d)
+
+        with jax.named_scope("moe_route"):
+            router = self.param("router", nn.initializers.lecun_normal(),
+                                (d, E), jnp.float32)
+            # The router reads the activations rounded to their dtype, and
+            # that value is sown as "router_input".  Written out because XLA
+            # may hand a consumer the producer's unrounded f32 where a bf16
+            # tensor is read back as f32 (xla_allow_excess_precision), fusion
+            # by fusion: on the chip the block's norm read by this layer and
+            # the same norm read back by a caller differed by a bf16 rounding
+            # in half their elements, and one token in twenty has its k-th
+            # and (k+1)-th expert closer than that.  Whoever checks the
+            # routing (the chip benchmark's reference) needs the input the
+            # router really had.  Sown values are read back only by a caller
+            # that makes "intermediates" mutable.
+            info = jnp.finfo(tokens.dtype)
+            seen = jax.lax.reduce_precision(
+                tokens.astype(jnp.float32), info.nexp, info.nmant)
+            self.sow("intermediates", "router_input", seen)
+            logits = jnp.dot(seen, router, precision="highest")
+            gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
+            self.sow("intermediates", "chosen", chosen)
+            if self.norm_topk:
+                gates = gates / jnp.sum(gates, -1, keepdims=True)
+            local = chosen - self.first_expert               # (S, K)
+            on = local[..., None] == jnp.arange(Eh)          # (S, K, Eh)
+            # weights[s, e]: token s's gate for held expert e, else 0
+            weights = jnp.sum(jnp.where(on, gates[..., None], 0.0), axis=1)
+        counts = jnp.sum(on, axis=(0, 1))                    # (Eh,) pairs
+        for name, value in (
+            ("moe.rows_held", jnp.sum(counts)),
+            ("moe.load_max", jnp.max(counts)),
+        ):
+            self.sow("counters", name, value.astype(jnp.int32),
+                     reduce_fn=lambda a, b: b)
+
+        with jax.named_scope("moe_experts"):
+            init = nn.initializers.lecun_normal(batch_axis=(0,))
+            w_gate = self.param("w_gate", init, (Eh, d, h), jnp.float32)
+            w_up = self.param("w_up", init, (Eh, d, h), jnp.float32)
+            w_down = self.param("w_down", init, (Eh, h, d), jnp.float32)
+            xc, w_gate, w_up, w_down = nn.dtypes.promote_dtype(
+                tokens, w_gate, w_up, w_down, dtype=self.dtype)
+            mid = nn.silu(jnp.einsum("sd,edh->seh", xc, w_gate)) * jnp.einsum(
+                "sd,edh->seh", xc, w_up)
+            # the gate goes in before the down-projection, in f32, so that
+            # the sum over experts is one product over (expert, width)
+            mid = (mid.astype(jnp.float32) * weights[..., None]).astype(
+                self.dtype)
+            out = jnp.einsum("seh,ehd->sd", mid, w_down,
+                             preferred_element_type=jnp.float32)
+
+        with jax.named_scope("moe_shared"):
+            dense = lambda f, name: nn.Dense(
+                f, use_bias=False, dtype=self.dtype, name=name)
+            shared = dense(d, "shared_down")(
+                nn.silu(dense(self.shared_width, "shared_gate_proj")(tokens))
+                * dense(self.shared_width, "shared_up")(tokens)
+            )
+            out = out + shared.astype(jnp.float32) * jax.nn.sigmoid(
+                dense(1, "shared_gate")(tokens).astype(jnp.float32)
+            )
         return out.reshape(B, T, d).astype(x.dtype)
 
 

@@ -19,12 +19,19 @@ Knobs:
   MoE feed-forward;
 * ``dropout_rate`` — residual-branch dropout under ``train=True`` (the
   trainer already threads dropout rngs);
-* ``decode`` + :func:`generate` — KV-cache autoregressive generation.
+* ``decode`` + :func:`generate` — KV-cache autoregressive generation;
+* ``full_attention_interval`` / ``norm`` / ``attn_gate`` /
+  ``mlp="held_experts"`` — hybrid blocks (training path only): Gated
+  DeltaNet layers (``models/gated_delta.py``) with every n-th layer
+  gated softmax attention, zero-centred RMSNorm, partial rotary, and one
+  chip's share of a many-expert layer (``models/moe.py::HeldExpertsMLP``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -41,7 +48,8 @@ from distributed_learning_tpu.ops.ring_attention import (
 __all__ = ["TransformerLM", "generate", "sample_fn", "validate_sampling"]
 
 
-def _rope(x, positions, *, base: float = 10000.0):
+def _rope(x, positions, *, base: float = 10000.0,
+          rotary_dim: int | None = None):
     """Rotary position embedding (arXiv:2104.09864) over the head dim,
     in the half-split (GPT-NeoX) layout: dimension ``j`` pairs with
     ``j + Dh/2`` and the pair rotates by ``pos / base^(2j/Dh)``.  (The
@@ -55,7 +63,14 @@ def _rope(x, positions, *, base: float = 10000.0):
     rotation is applied no matter how the sequence is split.  Applied to
     Q and K before attention; relative-position structure then lives in
     the dot products and no learned position table is needed.
+
+    ``rotary_dim`` turns only the first ``rotary_dim`` dimensions of the
+    head (partial rotary, the GPT-NeoX ``rotary_pct``); the rest pass
+    through untouched.
     """
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = _rope(x[..., :rotary_dim], positions, base=base)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     B, T, H, Dh = x.shape
     if Dh % 2:
         raise ValueError(f"rope needs an even head_dim, got {Dh}")
@@ -70,6 +85,31 @@ def _rope(x, positions, *, base: float = 10000.0):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
     )
     return out.astype(x.dtype)
+
+
+class RMSNorm(nn.Module):
+    """Zero-centred RMSNorm (arXiv:1910.07467 with the weight stored as
+    its offset from one): ``x * rsqrt(mean x^2 + eps) * (1 + w)``, ``w``
+    initialised to zero, statistics in f32."""
+
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                       jnp.float32)
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + self.eps)
+        return (y * (1.0 + w)).astype(self.dtype)
+
+
+def _norm(kind: str, eps: float, dtype):
+    if kind == "layernorm":
+        return nn.LayerNorm(epsilon=eps, dtype=dtype)
+    if kind == "rmsnorm":
+        return RMSNorm(eps=eps, dtype=dtype)
+    raise ValueError(f"unknown norm {kind!r} (want layernorm|rmsnorm)")
 
 
 class _Attention(nn.Module):
@@ -91,6 +131,14 @@ class _Attention(nn.Module):
     # through one raw lax.psum — the shard_map transpose rules supply
     # the Megatron f/g pair (training/tp.py's NOTE).
     tp_axis: str | None = None
+    # Gated attention (the softmax layers of Gated-DeltaNet hybrids):
+    # q_proj gives the query AND a per-head output gate, q and k get a
+    # per-head zero-centred RMSNorm, and the output is multiplied by
+    # sigmoid(gate) before the out-projection.
+    gated: bool = False
+    rotary_dim: int | None = None  # partial rotary (None: the whole head)
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6
 
     def _tp_shard(self, n_global: int, what: str) -> int:
         if self.tp_axis is None:
@@ -117,10 +165,31 @@ class _Attention(nn.Module):
                 "manual tp_axis is a training-stage mode; decode uses "
                 "the GSPMD path (training/tp.py::make_tp_generate)"
             )
+        if self.gated and (self.tp_axis is not None or self.decode):
+            raise ValueError(
+                "gated attention is a training-path layer: no tp_axis, "
+                "no decode"
+            )
         H = self._tp_shard(self.num_heads, "num_heads")
         Hkv = (self._tp_shard(self.num_kv_heads, "num_kv_heads")
                if self.num_kv_heads is not None else H)
-        if Hkv == H:
+        gate = None
+        if self.gated:
+            qg = nn.DenseGeneral(
+                features=(2, H, self.head_dim), use_bias=False,
+                dtype=self.dtype, name="q_proj",
+            )(x)  # (B, T, 2, H, Dh): query, gate
+            kv = nn.DenseGeneral(
+                features=(2, Hkv, self.head_dim), use_bias=False,
+                dtype=self.dtype, name="kv_proj",
+            )(x)
+            with jax.named_scope("attn_gate"):
+                q, gate = qg[:, :, 0], qg[:, :, 1]
+                q = RMSNorm(self.norm_eps, self.dtype, name="q_norm")(q)
+                k = RMSNorm(self.norm_eps, self.dtype, name="k_norm")(
+                    kv[:, :, 0])
+                v = kv[:, :, 1]
+        elif Hkv == H:
             qkv = nn.DenseGeneral(
                 features=(3, H, self.head_dim),
                 use_bias=False, dtype=self.dtype,
@@ -149,7 +218,13 @@ class _Attention(nn.Module):
             # One rope application for BOTH modes: the caller always
             # passes global positions (decode mode derives them from the
             # top-level position counter), so no per-layer recompute.
-            q, k = _rope(q, positions), _rope(k, positions)
+            turn = functools.partial(
+                _rope, positions=positions, base=self.rope_base,
+                rotary_dim=self.rotary_dim,
+            )
+            with (jax.named_scope("attn_gate") if self.gated
+                  else contextlib.nullcontext()):
+                q, k = turn(q), turn(k)
         if self.window is not None and self.attn_impl not in ("full", "flash"):
             raise ValueError(
                 f"window is only supported for full/flash attention, "
@@ -175,6 +250,10 @@ class _Attention(nn.Module):
             out = ulysses_attention(q, k, v, axis_name=self.seq_axis, causal=True)
         else:
             raise ValueError(f"unknown attn_impl {self.attn_impl!r}")
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    out.dtype)
         # Out-projection contracts (H, Dh) directly — kernel (H, Dh, d),
         # head-sharded under TP with one psum placed by the partitioner.
         return self._out_proj(out, x.shape[-1])
@@ -324,6 +403,15 @@ class _Block(nn.Module):
     moe_expert_axis: str | None = None  # manual ep (models/moe.py)
     tp_axis: str | None = None          # manual megatron tp (_Attention)
     moe_capacity_factor: float = 1.25   # GShard slots per expert
+    # Hybrid blocks (TransformerLM builds these; training path only):
+    norm: str = "layernorm"             # | "rmsnorm" (zero-centred)
+    norm_eps: float = 1e-6
+    attn_gate: bool = False             # gated attention (_Attention.gated)
+    rotary_dim: int | None = None
+    rope_base: float = 10000.0
+    # GatedDeltaNet's arguments: this block's mixer is linear attention
+    linear_attn: Any = None
+    held_experts: Any = None            # HeldExpertsMLP's (mlp="held_experts")
 
     @nn.compact
     def __call__(self, x, positions=None, train: bool = False):
@@ -341,13 +429,37 @@ class _Block(nn.Module):
                 "manual tp_axis with mlp='moe' is not supported: shard "
                 "experts over an expert axis instead (moe_expert_axis)"
             )
-        h = nn.LayerNorm(dtype=self.dtype)(x)
-        x = x + drop(_Attention(
-            self.num_heads, self.head_dim, self.attn_impl, self.seq_axis,
-            self.dtype, self.attn_window, self.decode, self.cache_len,
-            self.rope, self.num_kv_heads, tp_axis=self.tp_axis,
-        )(h, positions))
-        h = nn.LayerNorm(dtype=self.dtype)(x)
+        hybrid = self.linear_attn is not None or self.mlp == "held_experts"
+        if hybrid and (self.decode or self.tp_axis is not None
+                       or self.moe_expert_axis is not None):
+            raise ValueError(
+                "linear-attention and held-experts blocks are "
+                "training-path layers: no decode, tp_axis or expert axis"
+            )
+        h = _norm(self.norm, self.norm_eps, self.dtype)(x)
+        if self.linear_attn is not None:
+            from distributed_learning_tpu.models.gated_delta import (
+                GatedDeltaNet,
+            )
+
+            x = x + drop(GatedDeltaNet(
+                dtype=self.dtype, eps=self.norm_eps, **self.linear_attn
+            )(h))
+        else:
+            x = x + drop(_Attention(
+                self.num_heads, self.head_dim, self.attn_impl, self.seq_axis,
+                self.dtype, self.attn_window, self.decode, self.cache_len,
+                self.rope, self.num_kv_heads, tp_axis=self.tp_axis,
+                gated=self.attn_gate, rotary_dim=self.rotary_dim,
+                rope_base=self.rope_base, norm_eps=self.norm_eps,
+            )(h, positions))
+        h = _norm(self.norm, self.norm_eps, self.dtype)(x)
+        if self.mlp == "held_experts":
+            from distributed_learning_tpu.models.moe import HeldExpertsMLP
+
+            return x + drop(HeldExpertsMLP(
+                dtype=self.dtype, **self.held_experts
+            )(h))
         if self.mlp == "moe":
             # Expert-parallel feed-forward (models/moe.py): params become
             # stacked (E, ...) kernels shardable over an expert mesh axis.
@@ -359,7 +471,9 @@ class _Block(nn.Module):
                 expert_axis=self.moe_expert_axis,
             )(h))
         if self.mlp != "dense":
-            raise ValueError(f"unknown mlp {self.mlp!r} (want dense|moe)")
+            raise ValueError(
+                f"unknown mlp {self.mlp!r} (want dense|moe|held_experts)"
+            )
         d = x.shape[-1]
         if self.tp_axis is not None:
             # Megatron column-then-row MLP: the up-projection declares
@@ -420,6 +534,62 @@ class TransformerLM(nn.Module):
                              # Direct decode users must keep prompt+steps
                              # <= max_len; past it the dynamic cache write
                              # clamps (generate() enforces the bound).
+    # --- hybrid linear-attention / many-expert models (training path
+    # only; decode, tp and the pipeline builders refuse them).  The
+    # defaults leave the model above exactly as it was.
+    hidden_size: int | None = None  # d_model, when not heads x head_dim
+    norm: str = "layernorm"         # | "rmsnorm": zero-centred RMSNorm
+    norm_eps: float = 1e-6
+    head_bias: bool = True          # the output head's bias
+    rope_base: float = 10000.0
+    rope_fraction: float = 1.0      # share of the head that rotary turns
+    attn_gate: bool = False         # gated attention with q/k RMSNorm
+    # Every n-th layer is full attention, the others Gated DeltaNet
+    # (models/gated_delta.py); None: attention everywhere.
+    full_attention_interval: int | None = None
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel: int = 4
+    linear_chunk: int = 64
+    # mlp="held_experts" (models/moe.py::HeldExpertsMLP): num_experts is
+    # the router's width, moe_top_k its choices per token, and this chip
+    # holds experts [first_expert, first_expert + experts_held).
+    experts_held: int | None = None
+    first_expert: int = 0
+    expert_width: int = 512
+    shared_expert_width: int = 512
+    remat_blocks: bool = False      # rematerialise each block in backward
+
+    @property
+    def layer_types(self) -> tuple:
+        """Each layer's token mixer, ``"full_attention"`` or
+        ``"linear_attention"``."""
+        n = self.full_attention_interval
+        return tuple(
+            "linear_attention" if n and (i + 1) % n else "full_attention"
+            for i in range(self.num_layers)
+        )
+
+    @property
+    def uniform(self) -> bool:
+        """Every block is the plain attention + dense/MoE block that
+        decode, tensor parallelism and the pipeline builders know."""
+        return (
+            self.full_attention_interval is None
+            and self.mlp != "held_experts" and not self.attn_gate
+            and self.norm == "layernorm" and self.hidden_size is None
+            and self.rope_fraction == 1.0
+        )
+
+    def require_uniform(self, what: str) -> None:
+        if not self.uniform:
+            raise ValueError(
+                f"{what} supports only uniform attention blocks; hybrid "
+                "layer kinds (linear attention, gated attention, held "
+                "experts, rmsnorm) run on the training path alone"
+            )
 
     @nn.compact
     def __call__(self, tokens, train: bool = False):
@@ -431,7 +601,9 @@ class TransformerLM(nn.Module):
                 f"attn_window is only supported for full/flash attention, "
                 f"not {self.attn_impl!r}"
             )
-        d_model = self.num_heads * self.head_dim
+        if self.decode:
+            self.require_uniform("decode")
+        d_model = self.hidden_size or self.num_heads * self.head_dim
         T = tokens.shape[1]
         x = nn.Embed(self.vocab_size, d_model, dtype=self.dtype)(tokens)
         # Positions must be GLOBAL: under shard_map (ring/ulysses) each
@@ -472,17 +644,48 @@ class TransformerLM(nn.Module):
             raise ValueError(
                 f"unknown pos_emb {self.pos_emb!r} (want learned|rope)"
             )
-        for _ in range(self.num_layers):
-            x = _Block(
+        # Every block takes the norm, rotary and gate choices: their
+        # defaults build the plain block, parameter names and program as
+        # they always were.  Only the naming by depth is the hybrids'.
+        hybrid = dict(
+            norm=self.norm, norm_eps=self.norm_eps,
+            attn_gate=self.attn_gate, rope_base=self.rope_base,
+            rotary_dim=int(self.head_dim * self.rope_fraction),
+        )
+        if self.mlp == "held_experts":
+            hybrid["held_experts"] = dict(
+                num_experts=self.num_experts, top_k=self.moe_top_k,
+                experts_held=self.experts_held or self.num_experts,
+                first_expert=self.first_expert,
+                expert_width=self.expert_width,
+                shared_width=self.shared_expert_width,
+            )
+        linear_attn = dict(
+            num_key_heads=self.linear_num_key_heads,
+            num_value_heads=self.linear_num_value_heads,
+            key_head_dim=self.linear_key_head_dim,
+            value_head_dim=self.linear_value_head_dim,
+            conv_kernel=self.linear_conv_kernel, chunk=self.linear_chunk,
+        )
+        # (self, x, positions, train): train is a Python bool
+        block_cls = (nn.remat(_Block, static_argnums=(3,))
+                     if self.remat_blocks else _Block)
+        for i, kind in enumerate(self.layer_types):
+            x = block_cls(
                 self.num_heads, self.head_dim, self.mlp_ratio,
                 self.attn_impl, self.seq_axis, self.dtype,
                 self.mlp, self.num_experts, self.moe_top_k,
                 self.attn_window, self.decode, self.max_len,
                 use_rope, self.num_kv_heads, self.dropout_rate,
-                moe_capacity_factor=self.moe_capacity_factor,
+                moe_capacity_factor=self.moe_capacity_factor, **hybrid,
+                linear_attn=(linear_attn if kind == "linear_attention"
+                             else None),
+                # hybrid layers are named by depth, remat or not
+                name=None if self.uniform else f"layer_{i}",
             )(x, positions if use_rope else None, train)
-        x = nn.LayerNorm(dtype=self.dtype)(x)
-        logits = nn.Dense(self.vocab_size, dtype=self.dtype)(x)
+        x = _norm(self.norm, self.norm_eps, self.dtype)(x)
+        logits = nn.Dense(self.vocab_size, use_bias=self.head_bias,
+                          dtype=self.dtype)(x)
         return logits.astype(jnp.float32)
 
 

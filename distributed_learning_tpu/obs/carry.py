@@ -26,7 +26,32 @@ from typing import Any, Dict, Mapping, Optional, Sequence
 
 from distributed_learning_tpu.obs.registry import MetricsRegistry
 
-__all__ = ["global_norm", "flush_chunk"]
+__all__ = ["global_norm", "collect_counters", "flush_chunk"]
+
+
+def collect_counters(state: Any) -> Dict[str, Any]:
+    """One traced scalar per counter name from the ``counters``
+    collection a model sowed (``model.apply(..., mutable=["counters"])``):
+    layers that sow the same name are summed, or their maximum taken
+    where the name ends in ``_max``.  ``{}`` for a model that sows none
+    (every model but the held-experts LM): no leaf, so the step program
+    is the one it was."""
+    import jax
+    import jax.numpy as jnp
+
+    out: Dict[str, Any] = {}
+    col = state.get("counters") if isinstance(state, Mapping) else None
+    for path, leaf in jax.tree_util.tree_flatten_with_path(col or {})[0]:
+        name = next(
+            str(k.key) for k in reversed(path) if hasattr(k, "key")
+        )
+        if name not in out:
+            out[name] = leaf
+        elif name.endswith("_max"):
+            out[name] = jnp.maximum(out[name], leaf)
+        else:
+            out[name] = out[name] + leaf
+    return out
 
 
 def global_norm(tree: Any):

@@ -161,6 +161,8 @@ class _LMParts:
                  expert_axis: str | None = None,
                  tp_axis: str | None = None):
         reject_dropout_model(model)
+        # stages stack ONE block's parameters: no per-layer mixer choice
+        model.require_uniform("the LM pipeline")
         if model.attn_impl not in (
             "full", "flash", "ring", "ring_flash", "ulysses"
         ):
@@ -238,6 +240,7 @@ class _LMParts:
             self.use_rope, model.num_kv_heads, 0.0,
             moe_expert_axis=expert_axis, tp_axis=tp_axis,
             moe_capacity_factor=model.moe_capacity_factor,
+            norm_eps=model.norm_eps, rope_base=model.rope_base,
         )
         use_rope = self.use_rope
         sp, seq_axis, moe = self.sp, self.seq_axis, self.moe
@@ -277,7 +280,8 @@ class _LMParts:
                                   dtype=model.dtype)
         self.pos_embed = nn.Embed(model.max_len, d_model,
                                   dtype=model.dtype)
-        self.final_ln = nn.LayerNorm(dtype=model.dtype)
+        self.final_ln = nn.LayerNorm(epsilon=model.norm_eps,
+                                     dtype=model.dtype)
         self.head = nn.Dense(model.vocab_size, dtype=model.dtype)
 
     @property

@@ -143,6 +143,9 @@ def make_tp_train_step(
     models are unaffected.
     """
 
+    if hasattr(model, "require_uniform"):
+        # the sharding rules know the plain attention block's kernels only
+        model.require_uniform("tensor parallelism")
     from distributed_learning_tpu.models.moe import (
         apply_collecting_moe_aux,
     )

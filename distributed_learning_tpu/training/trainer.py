@@ -46,6 +46,7 @@ from distributed_learning_tpu.obs import (
     flush_chunk,
     global_norm as obs_global_norm,
 )
+from distributed_learning_tpu.obs.carry import collect_counters
 from distributed_learning_tpu.ops import mixing as ops
 from distributed_learning_tpu.parallel.consensus import (
     AsyncGossipState,
@@ -789,7 +790,7 @@ class GossipTrainer:
                 variables = {"params": p}
                 if batch_stats is not None:
                     variables["batch_stats"] = batch_stats
-                mutable = ["moe_stats"] + (
+                mutable = ["moe_stats", "counters"] + (
                     ["batch_stats"] if batch_stats is not None else []
                 )
                 # Inside lossf, so that JAX names the forward ops
@@ -807,13 +808,16 @@ class GossipTrainer:
                     if aux is not None:
                         loss = loss + moe_aux_coef * aux
                     acc = metric_fn(logits, y)
-                return loss, (mut.get("batch_stats", None), acc)
+                    # integer scalars the model counted (obs/carry.py);
+                    # {} for every model that sows none
+                    counters = collect_counters(mut)
+                return loss, (mut.get("batch_stats", None), acc, counters)
 
             if remat:
                 # Rematerialize activations in the backward pass: trades
                 # FLOPs for HBM, buying batch/model headroom at WRN scale.
                 lossf = jax.checkpoint(lossf)
-            (loss, (new_bs, acc)), grads = jax.value_and_grad(
+            (loss, (new_bs, acc, counters)), grads = jax.value_and_grad(
                 lossf, has_aux=True
             )(params)
             # Device-side metrics carry (obs/carry.py): the grad norm is
@@ -824,7 +828,7 @@ class GossipTrainer:
             with jax.named_scope("opt"):
                 updates, opt_state = tx.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
-            return params, new_bs, opt_state, loss, acc, gnorm
+            return params, new_bs, opt_state, loss, acc, gnorm, counters
 
         vstep = jax.vmap(train_step)
 
@@ -837,7 +841,8 @@ class GossipTrainer:
             permuted epoch tensor is never materialized and the only
             per-epoch host->device transfer is the index array.
             Returns state plus (steps, n) loss/acc/grad-norm traces (the
-            device-side metrics carry).
+            device-side metrics carry) and ``{name: (steps, n)}`` of the
+            model's own counters (``{}`` where it counts nothing).
             """
             take = jax.vmap(lambda X, i: jnp.take(X, i, axis=0))
 
@@ -848,15 +853,15 @@ class GossipTrainer:
                     y = take(ys, idx_t)
                 rng, *subs = jax.random.split(rng, n + 1)
                 subkeys = jnp.stack(subs)
-                params, bs, opt, loss, acc, gnorm = vstep(
+                params, bs, opt, loss, acc, gnorm, counters = vstep(
                     params, bs, opt, x, y, subkeys
                 )
-                return (params, bs, opt, rng), (loss, acc, gnorm)
+                return (params, bs, opt, rng), (loss, acc, gnorm, counters)
 
-            (params, bs, opt, rng), (losses, accs, gnorms) = jax.lax.scan(
-                body, state, idx
+            (params, bs, opt, rng), (losses, accs, gnorms, counters) = (
+                jax.lax.scan(body, state, idx)
             )
-            return (params, bs, opt, rng), losses, accs, gnorms
+            return (params, bs, opt, rng), losses, accs, gnorms, counters
 
         # Donating the carried state lets XLA reuse its buffers in place —
         # at WRN scale the stacked params/opt slots dominate HBM, so the
@@ -1254,8 +1259,8 @@ class GossipTrainer:
         t0 = time.perf_counter() if sampled else 0.0
         try:
             with self._span("trainer.dispatch", epoch=epoch_idx):
-                self._state, losses, accs, gnorms = self._jit_epoch(
-                    self._state, self._Xs, self._ys, idx
+                self._state, losses, accs, gnorms, counters = (
+                    self._jit_epoch(self._state, self._Xs, self._ys, idx)
                 )
             self._count_dispatch()
             # Consensus from epoch_cons_num onward (parity: Man_Colab
@@ -1282,13 +1287,15 @@ class GossipTrainer:
             with self._span("trainer.flush", epoch=epoch_idx):
                 arrs = flush_chunk(
                     self._obs_registry,
-                    {"loss": losses, "acc": accs, "grad_norm": gnorms},
+                    {"loss": losses, "acc": accs, "grad_norm": gnorms,
+                     **counters},
                     step0=self._global_step,
                     node_names=self.node_names,
                 )
                 losses = arrs["loss"]  # (steps, n)
                 accs = arrs["acc"]
                 gnorms = arrs["grad_norm"]
+                counters = {name: arrs[name] for name in counters}
                 mix_rounds = int(np.asarray(rounds))
                 # Robust gossip's redirected-mass scalar shares the same
                 # single per-epoch sync region (see _gossip docstring).
@@ -1357,6 +1364,9 @@ class GossipTrainer:
             "mix_rounds": mix_rounds,
             "deviation": deviation,
         }
+        if counters:
+            # the model's own integer counters, (steps, n) each
+            payload["counters"] = counters
         if self._adaptive_cfg is not None:
             # Feed the controller: next epoch's round budget is scaled
             # by this epoch's post-mix residual (float -> float32 is
@@ -1763,7 +1773,7 @@ class GossipTrainer:
             def body(carry, inp):
                 state, gc = carry
                 idx_e, mode_e, sched_e = inp
-                state, losses, accs, gnorms = epoch_fn(
+                state, losses, accs, gnorms, counters = epoch_fn(
                     state, Xs, ys, idx_e
                 )
                 params, bs, opt, rng = state
@@ -1778,16 +1788,16 @@ class GossipTrainer:
                 res = max_dev(params)
                 return (
                     ((params, bs, opt, rng), {"mix": mix, "res": res}),
-                    (losses, accs, gnorms, rounds, mass, res),
+                    (losses, accs, gnorms, rounds, mass, res, counters),
                 )
 
             (state, gcarry), ys_out = jax.lax.scan(
                 body, (state, gcarry), (idx, modes, sched)
             )
-            losses, accs, gnorms, rounds, masses, devs = ys_out
+            losses, accs, gnorms, rounds, masses, devs, counters = ys_out
             return (
                 state, gcarry, losses, accs, gnorms, rounds, masses,
-                devs,
+                devs, counters,
             )
 
         return superstep_fn
@@ -1857,7 +1867,7 @@ class GossipTrainer:
             with self._span("trainer.dispatch", epoch=epoch0):
                 (
                     self._state, gcarry, losses, accs, gnorms, rounds,
-                    masses, devs,
+                    masses, devs, counters,
                 ) = fn(
                     self._state, gcarry, self._Xs, self._ys, idx, modes,
                     sched,
@@ -1870,13 +1880,15 @@ class GossipTrainer:
             with self._span("trainer.flush", epoch=epoch0):
                 arrs = flush_chunk(
                     self._obs_registry,
-                    {"loss": losses, "acc": accs, "grad_norm": gnorms},
+                    {"loss": losses, "acc": accs, "grad_norm": gnorms,
+                     **counters},
                     step0=self._global_step,
                     node_names=self.node_names,
                 )
                 losses = arrs["loss"]  # (k, steps, n)
                 accs = arrs["acc"]
                 gnorms = arrs["grad_norm"]
+                counters = {name: arrs[name] for name in counters}
                 rounds_host = np.asarray(rounds)  # (k,)
                 devs_host = np.asarray(devs)  # (k,)
                 masses_host = (
@@ -1959,6 +1971,8 @@ class GossipTrainer:
                     "test_acc": test_accs if final else None,
                     "mix_rounds": int(rounds_host[j]),
                     "deviation": float(devs_host[j]),
+                    **({"counters": {c: v[j] for c, v in counters.items()}}
+                       if counters else {}),
                 })
                 if self._obs_registry is not None:
                     # Per-epoch consensus traces, as on the per-epoch path

@@ -29,7 +29,9 @@ from distributed_learning_tpu.ops import mixing as mixing_ops
 
 GiB = 2.0**30
 #: (batch*heads, T, head_dim) of the flash cases, default blocks 256/512.
-FLASH_SHAPES = [(8, 8192, 128), (16, 2048, 128)]
+#: The last is the Qwen3-Next cell's gated-attention layer: 2 agents x 16
+#: heads of 256 at T 4,096 (the GPT-2 cell runs head size 64).
+FLASH_SHAPES = [(8, 8192, 128), (16, 2048, 128), (32, 4096, 256)]
 BLOCK_Q, BLOCK_K = 256, 512
 
 
