@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from distributed_learning_tpu.ops import flash_attention as fa
+from distributed_learning_tpu.ops import gated_delta as gd
 from distributed_learning_tpu.ops import mixing as mixing_ops
 
 GiB = 2.0**30
@@ -109,6 +110,29 @@ def test_flash_kernels_compile_for_v5e(one_chip, cache_off, shape, variant):
     fn = _flash_fn(variant, float(1.0 / np.sqrt(shape[-1])))
     compiled = _compile(fn, x, x, x)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("variant", ["fwd", "grad"])
+@pytest.mark.parametrize("precision, D", [
+    (None, 128), ("highest", 128), (None, 256)],
+    ids=["default", "highest", "head256"])
+def test_gdn_scan_kernels_compile_for_v5e(one_chip, cache_off, precision, D,
+                                          variant):
+    """The delta rule's chunk-scan kernel pair at the Qwen3-Next cell's
+    shape: 2 agents vmapped over 64 chunks of 64 tokens, 32 heads of 128
+    (the rule itself asks ``jax.devices()`` and would take its scan); and
+    at a head size of 256, where a grid step takes fewer heads."""
+    agents, N, B, H, C = 2, 64, 1, 32, 64
+    sds = lambda *s: jax.ShapeDtypeStruct(
+        (agents, N, B, H) + s, jnp.float32, sharding=one_chip)
+    shapes = (sds(C, D), sds(C, D), sds(C, C), sds(C, D), sds(C, D), sds())
+    scan = jax.vmap(lambda *a: gd._chunk_scan(*a, precision, False))
+    fn = scan if variant == "fwd" else jax.grad(
+        lambda *a: jnp.sin(scan(*a)).sum(), argnums=(0, 1, 2, 3, 4, 5))
+    text = _compile(fn, *shapes).as_text()
+    assert text.count("tpu_custom_call") >= (1 if variant == "fwd" else 2)
+    assert "gdn_scan_fwd" in text and ("gdn_scan_bwd" in text) == (
+        variant == "grad")
 
 
 def test_fused_dense_mix_compiles_at_wrn_28_10_width(one_chip, cache_off):
