@@ -307,11 +307,11 @@ def flash(device, *, seed: int = 0, lm_T: int = 4096) -> dict:
         for g, r in zip(_run_compiled(flash_grad, q, k, v, w), ref_grads)
     )
 
-    # benchmarks/bench_lm.py's widths (vocab 8192, 8 heads x 128, bf16),
-    # depth cut from 8 layers to 2; two steps of one epoch.
+    # A small LM (vocab 8192, 8 heads x 128, bf16, 2 layers); two steps
+    # of one epoch.
     lm = dict(vocab_size=8192, num_layers=2, num_heads=8, head_dim=128)
-    say(f"flash: LM cut — depth 2 of bench_lm's 8 layers, T {lm_T}; "
-        f"widths kept: {lm}")
+    say(f"flash: LM through GossipTrainer — T {lm_T}; "
+        f"widths: {lm}")
     rng = np.random.default_rng(seed)
     batch, steps = 2, 2
     tokens = rng.integers(

@@ -15,14 +15,10 @@ Subcommands (dispatched before the trainer flag surface):
 
     python -m distributed_learning_tpu.cli obs-report <run.jsonl>
     python -m distributed_learning_tpu.cli obs-report --merge <a.jsonl> <b.jsonl>
-    python -m distributed_learning_tpu.cli obs-report --bench BENCH_r*.json
-    python -m distributed_learning_tpu.cli obs-report --ledger benchmarks/results/perf_ledger.jsonl
     python -m distributed_learning_tpu.cli obs-monitor <aggregate.jsonl>
 
-summarize JSONL observability event logs — single-process, merged
-run-wide (per-agent labels + straggler profile), the driver's bench
-trajectory, or the persistent perf ledger (compiled-program cost
-profiles + measured MFU per run, regression-flagged) — and tail the
+summarize JSONL observability event logs — single-process or merged
+run-wide (per-agent labels + straggler profile) — and tail the
 run-wide aggregate live (``docs/observability.md``), all without
 importing jax or touching any device.
 """

@@ -265,8 +265,8 @@ class ConsensusAgent:
         # recv/decode/mix at the receiver — so the merged Perfetto trace
         # arrow-links each frame's causal chain across process tracks.
         # Off (the default) the trace trailer is absent on the wire and
-        # no flow events are emitted: the <=5% rounds/sec overhead gate
-        # (benchmarks/bench_async_gossip.py) measures exactly this flag.
+        # no flow events are emitted (tests/test_trace_plane.py:
+        # test_untraced_run_emits_no_flow_events).
         self.trace = bool(trace)
         self._trace_run_id = int(trace_run_id)
         # Consistent flow sampling (docs/observability.md §Fleet-scale

@@ -93,7 +93,7 @@ def _wire_engine():
     disabled (``DLT_NO_NATIVE=1``, honored per call so the fallback can
     be forced without restarting).  Records the serving path on the
     ``comm.wire.native`` gauge — one dict write per FRAME, so run
-    reports (and bench records) can say which engine ran."""
+    reports can say which engine ran."""
     eng = native_wire if native_wire.available() else None
     try:  # lazy: obs is optional at this layer and must not cycle imports
         from distributed_learning_tpu.obs import get_registry

@@ -28,9 +28,7 @@ One import surface for the four pieces:
 * the **device-cost observatory** — `cost.py` (:class:`CostProfile`
   extracted from any compiled entry point: FLOPs, bytes, peak HBM,
   donation, collective inventory; :class:`SampledDispatchTimer`
-  1-in-N chunk-boundary step timing with MFU/bytes-per-sec gauges;
-  the persistent `benchmarks/results/perf_ledger.jsonl` ledger behind
-  ``obs-report --ledger``).
+  1-in-N chunk-boundary step timing with MFU/bytes-per-sec gauges).
 
 Library code counts into the process-wide default registry/tracer
 (`get_registry()` / `get_tracer()`); tests and multi-run drivers scope
@@ -45,9 +43,7 @@ from distributed_learning_tpu.obs.cost import (
     clear_profiles,
     device_peak_flops,
     get_profile,
-    ledger_append,
     profile_fn,
-    read_ledger,
     register_profile,
 )
 from distributed_learning_tpu.obs.instrument import InstrumentedStep, instrument_step
@@ -125,8 +121,6 @@ __all__ = [
     "all_profiles",
     "clear_profiles",
     "device_peak_flops",
-    "ledger_append",
-    "read_ledger",
     "format_run_report",
     "obs_report_main",
     "OBS_PAYLOAD_KIND",

@@ -10,7 +10,7 @@ throughput claims decompose into compute vs. communication vs. idle
 (the decomposition adaptive-synchronization schedules are built on:
 arxiv.org/pdf/2002.01119, arxiv.org/pdf/1910.13598).
 
-Three pieces, all host-side, none touching a compiled program:
+Two pieces, both host-side, neither touching a compiled program:
 
 * :class:`CostProfile` — extracted from any jitted entry point via the
   AOT ``.lower(...).compile()`` surface (``InstrumentedStep`` delegates
@@ -28,12 +28,6 @@ Three pieces, all host-side, none touching a compiled program:
   gauges.  Unsampled dispatches pay two integer ops on the host —
   nothing on the device, no program change (the obs on/off bit-identity
   oracle covers the timer).
-* the **perf ledger** — ``benchmarks/results/perf_ledger.jsonl``:
-  every ``bench.py`` / ``benchmarks/`` run appends one ``{profile,
-  measured, env}`` record (:func:`ledger_append`), and ``obs-report
-  --ledger`` renders the trend with healthy-best regression flagging
-  (:func:`format_ledger_trend`).  It is this program's own file; the
-  repo-root ``PERF_LEDGER.jsonl`` belongs to the driver.
 
 MFU definition: ``achieved FLOP/s / peak FLOP/s`` where achieved is the
 compiled program's XLA-counted FLOPs per dispatch times dispatches over
@@ -42,18 +36,17 @@ bf16/fp16 per-chip table keyed on ``jax.Device.device_kind``,
 overridable with ``DLT_PEAK_FLOPS`` (unknown chips and CPU return None:
 no peak, no MFU, never a made-up number).
 
-Everything importable here without jax (``obs-report --ledger`` is
-jax-free); jax is imported lazily inside the extraction paths only.
+Importable without jax; jax is imported lazily inside the extraction
+paths only.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, Optional
 
 __all__ = [
     "CostProfile",
@@ -65,22 +58,8 @@ __all__ = [
     "clear_profiles",
     "device_peak_flops",
     "mfu",
-    "ledger_path",
-    "ledger_append",
-    "read_ledger",
-    "format_ledger_trend",
-    "LEDGER_ENV",
-    "DEFAULT_LEDGER",
     "PEAK_FLOPS_ENV",
 ]
-
-#: env override for the perf-ledger path; the default sits inside the
-#: checkout, under the benchmarks' (git-ignored) results.
-LEDGER_ENV = "DLT_PERF_LEDGER"
-DEFAULT_LEDGER = os.path.normpath(os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "..", "..",
-    "benchmarks", "results", "perf_ledger.jsonl",
-))
 
 #: env override for the chip's peak dense FLOP/s (a float, e.g. 197e12).
 PEAK_FLOPS_ENV = "DLT_PEAK_FLOPS"
@@ -187,8 +166,8 @@ class CostProfile:
     Loop caveat (load-bearing for MFU): XLA's cost analysis counts a
     ``while``/``scan`` BODY once — trip counts are not folded in — so
     ``flops`` for a scanned program is per loop body, not per dispatch.
-    Callers that know the trip count (the trainer knows ``epoch_len``,
-    bench knows ``steps x superstep``) pass it as ``loop_steps`` to
+    Callers that know the trip count (the trainer knows ``epoch_len``
+    and its superstep) pass it as ``loop_steps`` to
     :meth:`mfu` / :meth:`bytes_per_sec`; without it the derived rates
     are lower bounds.  (Pinned by
     ``tests/test_obs_cost.py::test_cost_profile_counts_loop_body_once``.)
@@ -486,122 +465,3 @@ class SampledDispatchTimer:
                 f"cost.bytes_per_sec{suffix}", self.last_bytes_per_sec
             )
         return dt
-
-
-# ---------------------------------------------------------------------- #
-# Perf ledger                                                            #
-# ---------------------------------------------------------------------- #
-def ledger_path(path: Optional[str] = None) -> str:
-    """Resolve the ledger path: explicit arg > $DLT_PERF_LEDGER >
-    ``benchmarks/results/perf_ledger.jsonl`` of this checkout."""
-    return path or os.environ.get(LEDGER_ENV) or DEFAULT_LEDGER
-
-
-def ledger_append(record: dict, path: Optional[str] = None) -> bool:
-    """Append one perf record as a JSONL line; best-effort (a full disk
-    or read-only checkout must never fail the measurement that produced
-    the record).  Returns whether the line landed."""
-    record = dict(record)
-    record.setdefault("ts", time.time())
-    record.setdefault("kind", "perf")
-    try:
-        with open(ledger_path(path), "a", encoding="utf-8") as fh:
-            fh.write(json.dumps(record, sort_keys=True) + "\n")
-        return True
-    except OSError:
-        return False
-
-
-def read_ledger(path: Optional[str] = None) -> List[dict]:
-    """Parse the ledger, skipping blank/torn lines (a run may be
-    appending while a report reads), ordered as appended."""
-    out: List[dict] = []
-    with open(ledger_path(path), "r", encoding="utf-8") as fh:
-        for line in fh:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(rec, dict):
-                out.append(rec)
-    return out
-
-
-#: A record regresses when its value drops below this fraction of the
-#: best healthy value previously recorded for the same metric (the
-#: ``obs-report --bench`` convention, shared on purpose).
-LEDGER_REGRESSION_FRACTION = 0.9
-
-
-def _fmt_opt(value: Any, fmt: str, width: int) -> str:
-    if value is None:
-        return f"{'—':>{width}}"
-    return f"{value:{fmt}}"
-
-
-def format_ledger_trend(
-    records: Sequence[dict],
-    *, regression_fraction: float = LEDGER_REGRESSION_FRACTION,
-) -> str:
-    """The perf-ledger trend: one row per record in append order —
-    wall date, metric, value, MFU, per-dispatch GFLOPs and peak-HBM GiB
-    from the attached profile — with healthy-best regression flagging
-    per metric.  Provisional records are labeled and excluded from the
-    baseline (they measure a different configuration), exactly like the
-    ``--bench`` trajectory."""
-    lines = [
-        f"perf ledger — {len(records)} records",
-        f"  {'when':16} {'metric':44} {'value':>10} {'unit':>12} "
-        f"{'mfu%':>6} {'gflops':>9} {'peak GiB':>9}  status",
-    ]
-    best: Dict[str, float] = {}
-    best_when: Dict[str, str] = {}
-    for rec in records:
-        ts = rec.get("ts")
-        when = (
-            time.strftime("%Y-%m-%d %H:%M", time.gmtime(ts))
-            if isinstance(ts, (int, float)) else "—"
-        )
-        metric = str(rec.get("metric", "?"))
-        value = rec.get("value")
-        cost = rec.get("cost") or {}
-        m = cost.get("mfu")
-        flops = cost.get("flops")
-        peak = cost.get("peak_bytes") or cost.get("peak_hbm_bytes")
-        healthy = not rec.get("provisional")
-        status = "ok"
-        if not healthy:
-            status = "provisional"
-        elif (
-            isinstance(value, (int, float))
-            and metric in best
-            and value < regression_fraction * best[metric]
-        ):
-            status = (
-                f"REGRESSION -{(1 - value / best[metric]) * 100:.0f}% "
-                f"vs {best_when[metric]}"
-            )
-        lines.append(
-            f"  {when:16} {metric[:44]:44} "
-            f"{_fmt_opt(value, '10.2f', 10)} "
-            f"{str(rec.get('unit', '—'))[:12]:>12} "
-            f"{_fmt_opt(None if m is None else m * 100, '6.2f', 6)} "
-            f"{_fmt_opt(None if flops is None else flops / 1e9, '9.2f', 9)} "
-            f"{_fmt_opt(None if peak is None else peak / 2**30, '9.3f', 9)}"
-            f"  {status}"
-        )
-        if healthy and isinstance(value, (int, float)):
-            if metric not in best or value > best[metric]:
-                best[metric] = float(value)
-                best_when[metric] = when
-    for metric in sorted(best):
-        lines.append(
-            f"  best healthy {metric}: {best[metric]:.2f} "
-            f"({best_when[metric]})"
-        )
-    if not best:
-        lines.append("  no healthy record yet")
-    return "\n".join(lines)

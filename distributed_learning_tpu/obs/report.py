@@ -7,7 +7,7 @@ aggregated run summary: counter totals, last gauges, time-series stats,
 and span timings — "where did this run's time and bandwidth go" without
 TensorBoard.
 
-The run-wide plane adds three modes (all jax-free):
+The run-wide plane adds two modes (both jax-free):
 
 * ``obs-report --merge a.jsonl b.jsonl ...`` — merge per-agent event
   logs into ONE run report with per-agent labels plus the straggler
@@ -15,13 +15,6 @@ The run-wide plane adds three modes (all jax-free):
   expands to its sorted ``*.jsonl`` members, so a fleet harness's
   output dir is one argument; ``--trace out.json`` additionally writes
   the merged Perfetto trace);
-* ``obs-report --bench BENCH_r*.json`` — the driver's benchmark
-  trajectory as one table of headline samples/sec per round with
-  regression flagging;
-* ``obs-report --ledger benchmarks/results/perf_ledger.jsonl`` — the
-  persistent perf ledger (every ``bench.py`` / ``benchmarks/`` run
-  appends a ``{profile, measured, env}`` record; ``obs/cost.py``) as a
-  trend table with per-metric healthy-best regression flagging;
 * ``obs-monitor <aggregate.jsonl>`` — live text dashboard over the
   aggregate stream a master-side ``RunAggregator`` + ``JsonlSink``
   writes (round rate, per-agent latency bars, consensus residual, wire
@@ -47,7 +40,6 @@ __all__ = [
     "format_run_report",
     "format_straggler_profile",
     "format_edge_profile",
-    "format_bench_trajectory",
     "obs_report_main",
     "obs_monitor_main",
 ]
@@ -306,79 +298,6 @@ def merge_agent_logs(paths: Sequence[str]) -> RunAggregator:
 
 
 # ---------------------------------------------------------------------- #
-# Bench trajectory (obs-report --bench)                                  #
-# ---------------------------------------------------------------------- #
-#: A round counts as a regression when its headline drops below this
-#: fraction of the best healthy value seen in earlier rounds.
-BENCH_REGRESSION_FRACTION = 0.9
-
-
-def read_bench_records(paths: Sequence[str]) -> List[dict]:
-    """Parse the driver's ``BENCH_r*.json`` round files, sorted by
-    round number.  Each row: round ``n``, ``rc``, and the parsed record
-    (or None when the round produced no measurement)."""
-    rows = []
-    for path in paths:
-        with open(path, "r", encoding="utf-8") as fh:
-            rec = json.load(fh)
-        rows.append({
-            "path": path,
-            "n": int(rec.get("n", 0)),
-            "rc": rec.get("rc"),
-            "parsed": rec.get("parsed"),
-        })
-    rows.sort(key=lambda r: r["n"])
-    return rows
-
-
-def format_bench_trajectory(rows: List[dict]) -> str:
-    """One table of headline samples/sec per round, regressions
-    flagged.  Provisional records are labeled and excluded from the
-    regression baseline (they measure a different configuration)."""
-    lines = [
-        f"bench trajectory — {len(rows)} rounds",
-        f"  {'round':>5} {'rc':>3} {'value':>10} {'unit':>12} "
-        f"{'vs_base':>8}  status",
-    ]
-    best: Optional[float] = None
-    best_round: Optional[int] = None
-    for row in rows:
-        parsed = row["parsed"]
-        if not parsed:
-            lines.append(
-                f"  r{row['n']:04d} {row['rc']!s:>3} {'—':>10} {'—':>12} "
-                f"{'—':>8}  no record (driver rc={row['rc']})"
-            )
-            continue
-        value = float(parsed.get("value", 0.0))
-        unit = parsed.get("unit", "")
-        vs = parsed.get("vs_baseline")
-        healthy = not parsed.get("provisional")
-        status = "ok"
-        if not healthy:
-            status = "provisional"
-        elif best is not None and value < BENCH_REGRESSION_FRACTION * best:
-            status = (
-                f"REGRESSION -{(1 - value / best) * 100:.0f}% "
-                f"vs r{best_round:02d}"
-            )
-        lines.append(
-            f"  r{row['n']:04d} {row['rc']!s:>3} {value:10.2f} {unit:>12} "
-            f"{('%.3f' % vs) if vs is not None else '—':>8}  {status}"
-        )
-        if healthy and (best is None or value > best):
-            best, best_round = value, row["n"]
-    if best is not None:
-        lines.append(f"  best healthy headline: {best:.2f} (r{best_round:02d})")
-    else:
-        lines.append(
-            "  no healthy headline yet — every round missed its "
-            "measurement window"
-        )
-    return "\n".join(lines)
-
-
-# ---------------------------------------------------------------------- #
 # obs-report CLI                                                         #
 # ---------------------------------------------------------------------- #
 def obs_report_main(argv: Optional[Sequence[str]] = None) -> int:
@@ -388,8 +307,7 @@ def obs_report_main(argv: Optional[Sequence[str]] = None) -> int:
         description="summarize JSONL observability event logs",
     )
     ap.add_argument("paths", nargs="+",
-                    help="JSONL event log(s) (dump_jsonl/JsonlSink), or "
-                         "BENCH_r*.json files with --bench")
+                    help="JSONL event log(s) (dump_jsonl/JsonlSink)")
     ap.add_argument("--json", action="store_true",
                     help="emit the raw report dict as JSON")
     ap.add_argument("--merge", action="store_true",
@@ -400,36 +318,9 @@ def obs_report_main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="with --merge: also write the merged "
                          "Chrome/Perfetto trace here")
-    ap.add_argument("--bench", action="store_true",
-                    help="read BENCH_r*.json driver round files: "
-                         "headline samples/sec per round with "
-                         "regression flagging")
-    ap.add_argument("--ledger", action="store_true",
-                    help="read perf-ledger file(s) (obs/cost.py): "
-                         "the {profile, measured, env} trend "
-                         "with healthy-best regression flagging")
     args = ap.parse_args(argv)
     try:
-        if args.ledger:
-            from distributed_learning_tpu.obs.cost import (
-                format_ledger_trend,
-                read_ledger,
-            )
-
-            records: List[dict] = []
-            for path in args.paths:
-                records.extend(read_ledger(path))
-            text = (
-                json.dumps(records, indent=2, sort_keys=True)
-                if args.json else format_ledger_trend(records)
-            )
-        elif args.bench:
-            rows = read_bench_records(args.paths)
-            text = (
-                json.dumps(rows, indent=2, sort_keys=True)
-                if args.json else format_bench_trajectory(rows)
-            )
-        elif args.merge:
+        if args.merge:
             agg = merge_agent_logs(args.paths)
             if args.trace:
                 agg.export_chrome_trace(args.trace)
@@ -453,8 +344,8 @@ def obs_report_main(argv: Optional[Sequence[str]] = None) -> int:
         else:
             if len(args.paths) != 1:
                 # graftlint: disable=no-print-in-library -- CLI error reporting to stderr (argparse convention)
-                print("obs-report: pass one log, or --merge/--bench/"
-                      "--ledger for several", file=sys.stderr)
+                print("obs-report: pass one log, or --merge for several",
+                      file=sys.stderr)
                 return 2
             report = MetricsRegistry.from_jsonl(args.paths[0]).run_report()
             text = (

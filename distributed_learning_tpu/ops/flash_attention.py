@@ -35,9 +35,9 @@ the kernels and sliced after — scores and softmax are unchanged by zero
 columns, and the pad/slice pair is differentiable, so the padding
 composes with the custom VJP.
 
-Context length is bounded by HBM, not VMEM.  Throughput is what
-``benchmarks/bench_attention.py`` measures (TFLOP/s at 8k/32k/131k with
-a block-size sweep); on the chip it is not measured yet.  On CPU the
+Context length is bounded by HBM, not VMEM.  On the chip the kernels'
+time is the chip benchmark's ``flash_ms.tok`` / ``flash_ms.hyb``
+(PERF.md section 5).  On CPU the
 same kernels run under ``interpret=True`` for the
 tests; correctness bar: values and gradients match
 :func:`~distributed_learning_tpu.ops.ring_attention.attention_reference`.

@@ -38,7 +38,6 @@ __all__ = [
     "fused_layout",
     "flatten_stacked",
     "unflatten_stacked",
-    "fused_dense_mix",
     "stale_weight_matrix",
     "presence_weight_matrix",
     "stale_weighted_mix",
@@ -243,22 +242,6 @@ def unflatten_stacked(
             )
             leaves.append(piece.reshape((buf.shape[0],) + slot.shape))
     return jax.tree_util.tree_unflatten(layout.treedef, leaves)
-
-
-def fused_dense_mix(
-    stacked: Pytree,
-    W: jax.Array,
-    *,
-    times: int = 1,
-    precision: jax.lax.Precision = jax.lax.Precision.HIGHEST,
-) -> Pytree:
-    """Traceable fused gossip for embedding in a caller's own compiled
-    program (``bench.py``'s epoch): flatten once, ``times`` (static) dense
-    rounds on the fused buffers, unflatten once."""
-    buffers, layout = flatten_stacked(stacked)
-    for _ in range(int(times)):
-        buffers = dense_mix(buffers, W, precision=precision)
-    return unflatten_stacked(buffers, layout)
 
 
 def dense_mix(

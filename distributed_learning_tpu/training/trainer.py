@@ -1238,7 +1238,7 @@ class GossipTrainer:
         superstep, gossip, deviation readout — eval and checkpoint IO are
         reporting, not the train path).  The superstep's headline claim —
         host dispatches per epoch drop from >=3 to 1/K — is asserted off
-        this counter (``benchmarks/bench_superstep.py``)."""
+        this counter (``tests/test_trainer.py``)."""
         if self._obs_registry is not None:
             self._obs_registry.inc("trainer.dispatches", n)
 

@@ -1,7 +1,7 @@
 """Where XLA's persistent compilation cache lives.
 
 Called by the entry points only (``cli.main``'s trainer branch,
-``bench.py``, ``benchmarks/common.py``, ``chip_smoke.py``) before their
+``chip_smoke.py``, ``examples/wrn_accuracy.py``) before their
 first compile — never at library import and never by the tests, so
 importing the package changes no JAX configuration.
 """

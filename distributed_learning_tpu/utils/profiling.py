@@ -25,7 +25,6 @@ from typing import Iterator, Optional
 __all__ = [
     "trace",
     "annotate",
-    "maybe_trace",
     "DebugLogger",
     "enable_debug_logging",
     "summarize_trace",
@@ -68,18 +67,6 @@ def trace(log_dir: str, *, host_profile: bool = True) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def maybe_trace(log_dir: Optional[str]):
-    """:func:`trace` when ``log_dir`` is set, a no-op otherwise — the
-    programmatic capture hook measurement loops wrap their measure
-    phase in unconditionally (``bench.py`` honors ``BENCH_TRACE_DIR``
-    through this, ``benchmarks/profile_wrn.py`` passes ``--trace``'s
-    dir), so "profile this run" is an environment decision, not a code
-    path."""
-    if not log_dir:
-        return contextlib.nullcontext()
-    return trace(log_dir)
 
 
 def annotate(name: str, **ids):

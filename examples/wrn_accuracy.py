@@ -15,22 +15,24 @@ lr schedule, gossip consensus, eval) rather than the CIFAR number itself.
 The emitted JSON marks which source was used; ``vs_baseline`` is only
 reported for real CIFAR.
 
+Progress is one JSON line an epoch and the summary record one more, all on
+stdout; ``--out`` also writes summary and curve to a file.
+
 Usage:
-    python -m benchmarks.train_wrn_accuracy             # full (TPU) scale
-    python -m benchmarks.train_wrn_accuracy --proxy     # reduced CPU scale
+    python -m examples.wrn_accuracy             # full (TPU) scale
+    python -m examples.wrn_accuracy --proxy     # reduced CPU scale
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks import common
 from distributed_learning_tpu.data import load_cifar, normalize, shard_dataset
 from distributed_learning_tpu.data.cifar import (
     normalized_pad_value,
@@ -40,6 +42,7 @@ from distributed_learning_tpu.parallel import Topology
 from distributed_learning_tpu.parallel.consensus import make_agent_mesh
 from distributed_learning_tpu.training import MasterNode
 from distributed_learning_tpu.training.config import wrn_lr_schedule
+from distributed_learning_tpu.utils.compile_cache import enable_compile_cache
 
 # Reference anchors: CIFAR_10_Baseline.ipynb cell 9 (WRN-28-10, T4) and
 # CIFAR_100_Baseline.ipynb cell 9 (WRN-28-10, P100).
@@ -58,7 +61,9 @@ def run(
 ):
     if dataset not in REFERENCE_ACC:
         raise ValueError(f"dataset {dataset!r} (want cifar10|cifar100)")
-    full = common.full_scale() and not proxy
+    full = not proxy
+    if full:
+        enable_compile_cache()
     real = real_cifar_present(dataset)
     ref_acc = REFERENCE_ACC[dataset]
     n_classes = 10 if dataset == "cifar10" else 100
@@ -132,35 +137,30 @@ def run(
         print(json.dumps({"progress": rec}), flush=True)
 
     final = curve[-1]
-    record = common.emit(
-        {
-            "metric": f"wrn{depth}x{widen}_{dataset}_gossip_final_test_acc",
-            "value": round(final["test_acc_mean"], 4),
-            "unit": "accuracy",
-            "vs_baseline": round(final["test_acc_mean"] / ref_acc, 4)
-            if (real and (depth, widen) == (28, 10))
-            else None,
-            "config": (
-                f"{n_agents}-agent ring, batch {batch}/agent, {epochs} epochs, "
-                "wrn_step lr, dropout 0.3, RandomCrop+Flip, mix 1/epoch"
-            ),
-            "data_source": "real-cifar" if real else "synthetic-stand-in",
-            "reference_anchor": ref_acc if real else None,
-            "per_agent_spread": round(
-                final["test_acc_max"] - final["test_acc_min"], 5
-            ),
-            "wall_clock_s": final["elapsed_s"],
-        }
-    )
-    out_path = out_path or os.path.join(
-        os.path.dirname(__file__), "results",
-        f"wrn_accuracy_{'real' if real else 'synthetic'}_"
-        f"{dataset}_{depth}x{widen}.json",
-    )
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    with open(out_path, "w") as f:
-        json.dump({"summary": record, "curve": curve}, f, indent=2)
-    print(f"# curve written to {out_path}", flush=True)
+    record = {
+        "metric": f"wrn{depth}x{widen}_{dataset}_gossip_final_test_acc",
+        "value": round(final["test_acc_mean"], 4),
+        "unit": "accuracy",
+        "vs_baseline": round(final["test_acc_mean"] / ref_acc, 4)
+        if (real and (depth, widen) == (28, 10))
+        else None,
+        "config": (
+            f"{n_agents}-agent ring, batch {batch}/agent, {epochs} epochs, "
+            "wrn_step lr, dropout 0.3, RandomCrop+Flip, mix 1/epoch"
+        ),
+        "data_source": "real-cifar" if real else "synthetic-stand-in",
+        "reference_anchor": ref_acc if real else None,
+        "per_agent_spread": round(
+            final["test_acc_max"] - final["test_acc_min"], 5
+        ),
+        "wall_clock_s": final["elapsed_s"],
+        "platform": jax.devices()[0].platform,
+    }
+    print(json.dumps(record), flush=True)
+    if out_path:
+        with open(out_path, "w") as f:
+            json.dump({"summary": record, "curve": curve}, f, indent=2)
+        print(f"# curve written to {out_path}", flush=True)
     return record
 
 

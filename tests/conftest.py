@@ -10,20 +10,9 @@ and pinned in its config after.
 """
 
 import os
-import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 
-# The program's perf ledger (obs/cost.py) defaults to a file under
-# benchmarks/results/ so real runs accumulate history; tests must not
-# grow it — point it at a throwaway dir unless the environment already
-# pinned it.
-os.environ.setdefault(
-    "DLT_PERF_LEDGER",
-    os.path.join(
-        tempfile.mkdtemp(prefix="dlt_test_ledgers_"), "perf_ledger.jsonl"
-    ),
-)
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

@@ -26,7 +26,6 @@ from jax.sharding import SingleDeviceSharding
 
 from distributed_learning_tpu.ops import flash_attention as fa
 from distributed_learning_tpu.ops import gated_delta as gd
-from distributed_learning_tpu.ops import mixing as mixing_ops
 
 GiB = 2.0**30
 #: (batch*heads, T, head_dim) of the flash cases, default blocks 256/512.
@@ -135,62 +134,42 @@ def test_gdn_scan_kernels_compile_for_v5e(one_chip, cache_off, precision, D,
         variant == "grad")
 
 
-def test_fused_dense_mix_compiles_at_wrn_28_10_width(one_chip, cache_off):
-    """The dense gossip GEMM over 4 stacked WRN-28-10 replicas (4 x
-    36,489,290 parameters) on one 16 GiB chip."""
-    from distributed_learning_tpu.models import WideResNet
-
-    n = 4
-    model = WideResNet(depth=28, widen_factor=10, dropout_rate=0.3,
-                       num_classes=10)
-    variables = jax.eval_shape(
-        lambda: model.init(
-            jax.random.key(0), jnp.zeros((1, 32, 32, 3)), train=False
-        )
-    )
-    params = jax.tree.map(
-        lambda v: jax.ShapeDtypeStruct(
-            (n,) + v.shape, v.dtype, sharding=one_chip
-        ),
-        variables["params"],
-    )
-    assert sum(
-        int(np.prod(v.shape[1:])) for v in jax.tree.leaves(params)
-    ) == 36_489_290
-    W = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
-    compiled = _compile(
-        lambda p, w: mixing_ops.fused_dense_mix(
-            p, w, precision=jax.lax.Precision.HIGHEST
-        ),
-        params, W,
-    )
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes)
-    assert total < 8 * GiB, total / GiB  # half the chip, by a wide margin
-
-
-def test_dense_mix_until_compiles_leafwise_at_gpt2_small_width(
-    one_chip, cache_off
-):
-    """The engine's dense ``mix_until`` on 4 stacked GPT-2 small replicas
-    (4 x 163,050,577 f32 parameters, the tree of the chip benchmark's
-    consensus-only cell): the leaves are mixed where they lie, so the
-    program joins nothing and its temporaries stay under two states."""
+def _gpt2_small():
     from distributed_learning_tpu.models import TransformerLM
-    from distributed_learning_tpu.parallel.consensus import ConsensusEngine
-    from distributed_learning_tpu.parallel.topology import Topology
 
-    n = 4
     model = TransformerLM(
         vocab_size=50257, num_layers=12, num_heads=12, head_dim=64,
         max_len=1024, mlp_ratio=4, pos_emb="learned", attn_impl="flash",
         dtype=jnp.bfloat16,
     )
+    return model, jnp.zeros((1, 1024), jnp.int32), 163_050_577
+
+
+def _wrn_28_10():
+    from distributed_learning_tpu.models import WideResNet
+
+    model = WideResNet(depth=28, widen_factor=10, dropout_rate=0.3,
+                       num_classes=10)
+    return model, jnp.zeros((1, 32, 32, 3)), 36_489_290
+
+
+@pytest.mark.parametrize("build", [_gpt2_small, _wrn_28_10],
+                         ids=["gpt2-small", "wrn-28-10"])
+def test_dense_mix_until_compiles_leafwise_at_cell_widths(
+    one_chip, cache_off, build
+):
+    """The engine's dense ``mix_until`` on 4 stacked f32 replicas of the
+    chip benchmark's two trees (GPT-2 small, 4 x 163,050,577 parameters,
+    the consensus-only cell's; WRN-28-10, 4 x 36,489,290): the leaves
+    are mixed where they lie, so the program joins nothing and its
+    temporaries stay under two states."""
+    from distributed_learning_tpu.parallel.consensus import ConsensusEngine
+    from distributed_learning_tpu.parallel.topology import Topology
+
+    n = 4
+    model, sample, n_params = build()
     shapes = jax.eval_shape(
-        lambda: model.init(
-            jax.random.key(0), jnp.zeros((1, 1024), jnp.int32), train=False
-        )["params"]
+        lambda: model.init(jax.random.key(0), sample, train=False)["params"]
     )
     params = jax.tree.map(
         lambda v: jax.ShapeDtypeStruct(
@@ -199,7 +178,7 @@ def test_dense_mix_until_compiles_leafwise_at_gpt2_small_width(
         shapes,
     )
     state = sum(4 * int(np.prod(v.shape)) for v in jax.tree.leaves(params))
-    assert state == 4 * n * 163_050_577
+    assert state == 4 * n * n_params
 
     def scalar(dtype):
         return jax.ShapeDtypeStruct((), dtype, sharding=one_chip)

@@ -41,6 +41,18 @@ def test_titanic_real_csv_layout():
     assert len(X_te) == 89
 
 
+def test_titanic_source_reports_real_or_synthetic(tmp_path):
+    from distributed_learning_tpu.data import titanic_source
+
+    # Explicit missing dir -> synthetic fallback is disclosed.
+    assert titanic_source(str(tmp_path / "nope")) == "synthetic"
+    # A dir with train.csv -> real, naming the dir.
+    d = tmp_path / "titanic"
+    d.mkdir()
+    (d / "train.csv").write_text("PassengerId,Survived\n")
+    assert titanic_source(str(d)) == f"real:{d}"
+
+
 def test_split_data_contiguous_near_equal():
     # Parity: notebook cell 12 — remainder rows land on the later shards.
     X = np.arange(802 * 2, dtype=np.float32).reshape(802, 2)

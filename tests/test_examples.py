@@ -154,6 +154,29 @@ def test_cifar_gossip_masternode_example():
     assert 0.05 <= acc <= 1.0, out
 
 
+def test_wrn_accuracy_cifar100_proxy_smoke(tmp_path):
+    """The cifar100 shape of the accuracy run (the reference's second
+    anchor, CIFAR_100_Baseline.ipynb cell 9): 100-class model wiring,
+    synthetic-label path, and record naming.  Called in process, at a
+    size its command line does not offer, so that regressions surface
+    here and not in a paid TPU session."""
+    from examples import wrn_accuracy
+
+    out = str(tmp_path / "wrn100.json")
+    rec = wrn_accuracy.run(
+        proxy=True, epochs=1, n_agents=2, dataset="cifar100",
+        n_train=128, n_test=64, out_path=out,
+    )
+    assert "cifar100" in rec["metric"]
+    assert rec["data_source"] == "synthetic-stand-in"
+    assert rec["platform"] == "cpu"
+    assert 0.0 <= rec["value"] <= 1.0
+    with open(out) as f:
+        saved = json.load(f)
+    assert saved["summary"]["metric"] == rec["metric"]
+    assert len(saved["curve"]) == 1
+
+
 def test_tcp_consensus_example_pair(tmp_path):
     """The master/agent scripts agree on the weighted mean: agents 1..3
     feed 10*e_{i-1} with weights 1, 2, 3 over the path 1-2, 2-3, so every

@@ -239,30 +239,6 @@ def test_host_sync_covers_async_runtime_dispatch_loop(tmp_path):
 
 
 # --------------------------------------------------------------------- #
-# stdout-contract                                                       #
-# --------------------------------------------------------------------- #
-def test_stdout_contract_fires_on_bare_print(tmp_path):
-    code = """
-    import json, sys
-    print("starting up")
-    print(json.dumps({"metric": 1}))
-    print("diag", file=sys.stderr)
-    sys.stdout.write("x")
-    """
-    fs = _lint(tmp_path, code, relname="bench.py", rules=["stdout-contract"])
-    assert len(fs) == 2, fs
-    assert {f.line for f in fs} == {3, 6}  # the bare print + the write
-
-
-def test_stdout_contract_scoped_to_bench(tmp_path):
-    fs = _lint(
-        tmp_path, 'print("hello")\n', relname="other.py",
-        rules=["stdout-contract"],
-    )
-    assert fs == []
-
-
-# --------------------------------------------------------------------- #
 # no-print-in-library                                                   #
 # --------------------------------------------------------------------- #
 def test_no_print_fires_in_library_code(tmp_path):
@@ -281,10 +257,9 @@ def test_no_print_fires_in_library_code(tmp_path):
     assert "logging" in fs[0].message
 
 
-def test_no_print_exempts_bench_examples_tools(tmp_path):
+def test_no_print_exempts_examples_tools(tmp_path):
     for relname in (
-        "bench.py",
-        "benchmarks/bench_x.py",
+        "chip_smoke.py",
         "examples/demo.py",
         "tools/helper.py",
     ):
@@ -575,7 +550,7 @@ def _cli(*args, cwd=REPO_ROOT):
 def test_cli_list_rules():
     out = _cli("--list-rules")
     assert out.returncode == 0, out.stderr
-    for rule in ("no-pickle", "stdout-contract", "reference-citation"):
+    for rule in ("no-pickle", "no-print-in-library", "reference-citation"):
         assert rule in out.stdout
 
 
@@ -626,7 +601,6 @@ GOLDEN_RULES = [
     "sched-model-pin",
     "schedule-deadlock",
     "schedule-nondeterminism",
-    "stdout-contract",
     "suppression-claim",
     "task-shared-mutation",
     "turn-discipline-claim",
@@ -684,9 +658,9 @@ def test_changed_files_partitions_deleted_paths(tmp_path):
     from tools.graftlint.__main__ import _changed_files
 
     repo = tmp_path / "repo"
-    (repo / "benchmarks").mkdir(parents=True)
-    keep = repo / "benchmarks" / "keep.py"
-    gone = repo / "benchmarks" / "gone.py"
+    (repo / "examples").mkdir(parents=True)
+    keep = repo / "examples" / "keep.py"
+    gone = repo / "examples" / "gone.py"
     keep.write_text("x = 1\n")
     gone.write_text("y = 2\n")
     env = {
@@ -705,8 +679,8 @@ def test_changed_files_partitions_deleted_paths(tmp_path):
     keep.write_text("x = 3\n")
     scoped, missing, changed = _changed_files(repo_root=str(repo))
     assert scoped == [str(keep)]
-    assert missing == ["benchmarks/gone.py"]
-    assert "benchmarks/gone.py" in changed
+    assert missing == ["examples/gone.py"]
+    assert "examples/gone.py" in changed
 
 
 def test_cli_changed_notices_deleted_paths(monkeypatch, capsys):
@@ -716,13 +690,13 @@ def test_cli_changed_notices_deleted_paths(monkeypatch, capsys):
 
     monkeypatch.setattr(
         cli, "_changed_files",
-        lambda repo_root=None: ([], ["benchmarks/gone.py"],
-                                ["benchmarks/gone.py"]),
+        lambda repo_root=None: ([], ["examples/gone.py"],
+                                ["examples/gone.py"]),
     )
     rc = cli.main(["--changed"])
     err = capsys.readouterr().err
     assert rc == 0
-    assert "skipping deleted/renamed path(s): benchmarks/gone.py" in err
+    assert "skipping deleted/renamed path(s): examples/gone.py" in err
 
 
 def test_cli_explicit_missing_path_notices_and_continues(tmp_path):
@@ -764,7 +738,7 @@ def test_precommit_clean_tree_exits_zero():
 
 
 def test_precommit_fails_on_seeded_violation():
-    seed = os.path.join(REPO_ROOT, "benchmarks", "_precommit_seed_tmp.py")
+    seed = os.path.join(REPO_ROOT, "examples", "_precommit_seed_tmp.py")
     try:
         with open(seed, "w") as fh:
             fh.write("import cvxpy\n")

@@ -149,8 +149,8 @@ def test_unawaited_allows_await_create_task_and_bindings(tmp_path):
 
 def test_unawaited_skips_names_shadowed_by_sync_defs(tmp_path):
     """A name bound by BOTH an async def and a plain def (the nested
-    'async def main' next to a module-level 'def main' shape of
-    benchmarks/bench_northstar.py) is ambiguous and must not fire."""
+    'async def main' next to a module-level 'def main') is
+    ambiguous and must not fire."""
     code = """
     import asyncio
 

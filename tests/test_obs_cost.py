@@ -1,12 +1,10 @@
 """Device-cost observatory (obs/cost.py): CostProfile extraction on a
 known-FLOPs program, MFU arithmetic and its peak source, the sampled
-dispatch timer's sync accounting, the perf ledger round-trip with
-regression flagging (golden-pinned through ``obs-report --ledger``),
+dispatch timer's sync accounting,
 the trainer/engine integration (bit-identity preserved), and the
 graftlint audit's cost columns."""
 
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -21,11 +19,6 @@ from distributed_learning_tpu.obs import (
     use_registry,
 )
 from distributed_learning_tpu.obs import cost as cost_mod
-
-GOLDEN = os.path.join(
-    os.path.dirname(__file__), "data", "ledger_trend_golden.txt"
-)
-
 
 @pytest.fixture(autouse=True)
 def _fresh_profiles():
@@ -188,68 +181,6 @@ def test_sampled_timer_sync_accounting():
     assert 0 < reg.gauges["cost.mfu/prog"] < 1e6
     assert reg.gauges["cost.bytes_per_sec/prog"] > 0
     assert timer.last_step_time_s > 0
-
-
-# ---------------------------------------------------------------------- #
-# Perf ledger                                                            #
-# ---------------------------------------------------------------------- #
-def _ledger_fixture(tmp_path):
-    path = str(tmp_path / "perf_ledger.jsonl")
-    records = [
-        {"ts": 1754000000.0, "metric": "wrn_throughput", "value": 100.0,
-         "unit": "samples/sec",
-         "cost": {"mfu": 0.35, "flops": 2.5e9, "peak_bytes": 2 * 2**30},
-         "env": {"platform": "tpu"}},
-        {"ts": 1754172800.0, "metric": "wrn_throughput", "value": 50.0,
-         "unit": "samples/sec", "provisional": True},
-        {"ts": 1754259200.0, "metric": "wrn_throughput", "value": 80.0,
-         "unit": "samples/sec",
-         "cost": {"mfu": 0.28, "flops": 2.5e9, "peak_bytes": 2 * 2**30}},
-    ]
-    for rec in records:
-        assert cost_mod.ledger_append(rec, path)
-    return path, records
-
-
-def test_ledger_append_roundtrip(tmp_path):
-    path, records = _ledger_fixture(tmp_path)
-    back = cost_mod.read_ledger(path)
-    assert len(back) == 3
-    for orig, rec in zip(records, back):
-        assert rec["kind"] == "perf"  # stamped on append
-        for key, val in orig.items():
-            assert rec[key] == val
-    # A torn tail (mid-write crash) is skipped, not fatal.
-    with open(path, "a", encoding="utf-8") as fh:
-        fh.write('{"truncated": ')
-    assert len(cost_mod.read_ledger(path)) == 3
-
-
-def test_ledger_trend_golden_with_regression(tmp_path):
-    """The rendered trend over >=2 records: provisional rows are
-    labeled and excluded from the baseline, and the synthetic 100->80
-    drop is flagged as a regression (golden-pinned)."""
-    path, _ = _ledger_fixture(tmp_path)
-    text = cost_mod.format_ledger_trend(cost_mod.read_ledger(path))
-    assert "REGRESSION -20%" in text
-    assert "provisional" in text
-    with open(GOLDEN, "r", encoding="utf-8") as fh:
-        assert text == fh.read().rstrip("\n")
-
-
-def test_obs_report_ledger_cli(tmp_path, capsys):
-    """``obs-report --ledger`` renders the same golden table (and the
-    --json variant emits the raw records) without importing jax."""
-    from distributed_learning_tpu.obs.report import obs_report_main
-
-    path, _ = _ledger_fixture(tmp_path)
-    assert obs_report_main(["--ledger", path]) == 0
-    out = capsys.readouterr().out
-    with open(GOLDEN, "r", encoding="utf-8") as fh:
-        assert out.rstrip("\n") == fh.read().rstrip("\n")
-    assert obs_report_main(["--ledger", "--json", path]) == 0
-    rows = json.loads(capsys.readouterr().out)
-    assert [r["value"] for r in rows] == [100.0, 50.0, 80.0]
 
 
 # ---------------------------------------------------------------------- #

@@ -561,33 +561,18 @@ def test_obs_report_merge_matches_golden(tmp_path, capsys):
     assert rep["report"]["counters"]["comm.agent.rounds_run"] == 10
 
 
-def test_obs_report_bench_trajectory(tmp_path, capsys):
+def test_obs_report_merge_takes_a_directory_of_logs(tmp_path, capsys):
+    """A fleet harness's output directory is ONE argument: it expands to
+    its sorted ``*.jsonl`` members (other files are left alone) and
+    renders what naming the files renders."""
     from distributed_learning_tpu.cli import main
 
-    rows = [
-        {"n": 1, "rc": 0, "parsed": {
-            "metric": "m", "value": 100.0, "unit": "samples/sec",
-            "vs_baseline": 1.0}},
-        {"n": 2, "rc": 2, "parsed": None},
-        {"n": 3, "rc": 0, "parsed": {
-            "metric": "m", "value": 50.0, "unit": "samples/sec",
-            "vs_baseline": 0.5}},
-        {"n": 4, "rc": 0, "parsed": {
-            "metric": "m", "value": 60.0, "unit": "samples/sec",
-            "vs_baseline": 0.6, "provisional": True}},
-    ]
-    paths = []
-    for row in rows:
-        p = str(tmp_path / f"BENCH_r{row['n']:02d}.json")
-        with open(p, "w") as fh:
-            json.dump(row, fh)
-        paths.append(p)
-    assert main(["obs-report", "--bench", *paths]) == 0
-    out = capsys.readouterr().out
-    assert "no record (driver rc=2)" in out
-    assert "REGRESSION -50% vs r01" in out
-    assert "provisional" in out
-    assert "best healthy headline: 100.00 (r01)" in out
+    paths = _write_agent_logs(tmp_path)
+    (tmp_path / "notes.txt").write_text("not a log\n")
+    assert main(["obs-report", "--merge", *paths]) == 0
+    by_file = capsys.readouterr().out
+    assert main(["obs-report", "--merge", str(tmp_path)]) == 0
+    assert capsys.readouterr().out == by_file
 
 
 def test_obs_monitor_once_renders_dashboard(tmp_path, capsys):
@@ -731,8 +716,7 @@ def test_v1_payload_without_sketch_section_still_sketches():
 
 
 def test_two_tier_aggregation_matches_flat_merge():
-    """Aggregate-of-aggregates oracle at unit scale (the 500-agent
-    version is gated in benchmarks/bench_obs_plane.py): pods forward
+    """Aggregate-of-aggregates oracle at unit scale: pods forward
     merged sketch deltas upstream and the root renders exactly the
     flat merge's per-agent quantiles."""
     from distributed_learning_tpu.obs import SubAggregator
@@ -807,6 +791,53 @@ def test_fleet_mode_suppression_is_disclosed_not_silent():
     assert agg.registry.counters["obs.series_sketched"] == 30
     # The profile still has the full picture — from the sketch.
     assert agg.straggler_profile()["per_agent"]["a"]["count"] == 30
+
+
+def test_fleet_mode_delta_bytes_grow_with_buckets_not_samples():
+    """Bounded delta bytes: with sketched series kept off the raw
+    stream, ten times the samples from one distribution stay under three
+    times the payload (raw points would be ten times)."""
+    from distributed_learning_tpu.obs.aggregate import ObsDeltaSource
+
+    def pack_bytes(points):
+        reg = MetricsRegistry(clock=lambda: 0.0)
+        src = ObsDeltaSource(reg, raw_series=False)
+        rng = np.random.default_rng(7)
+        for v in rng.lognormal(mean=-3.0, sigma=1.0, size=points):
+            reg.observe("comm.agent.round_s", float(v))
+        payload = src.pack()
+        src.close()
+        return len(json.dumps(payload).encode())
+
+    assert pack_bytes(2000) <= 3 * pack_bytes(200)
+
+
+def test_pod_export_rolls_up_the_per_agent_counter_rows():
+    """Bounded upstream bytes: a pod that rolls labels up
+    (``rollup_labels=``) folds the per-agent counter dimension, so four
+    times the agents at the same volume each stay under four times the
+    exported delta (the per-agent sketches, kept for the straggler
+    profile, are the part that still grows with agents)."""
+    from distributed_learning_tpu.obs import SubAggregator
+    from distributed_learning_tpu.obs.aggregate import ObsDeltaSource
+
+    def export_bytes(agents):
+        sub = SubAggregator(
+            registry=MetricsRegistry(clock=lambda: 0.0),
+            forward_raw_series=False, rollup_labels=16,
+        )
+        for _pack in range(2):
+            for i in range(agents):
+                reg = MetricsRegistry(clock=lambda: 0.0)
+                src = ObsDeltaSource(reg, raw_series=False)
+                for v in np.random.default_rng(i).lognormal(size=20):
+                    reg.observe("comm.agent.round_s", float(v))
+                reg.inc("comm.agent.rounds_run", 20)
+                sub.process(f"b{i:04d}", src.pack())
+                src.close()
+        return len(json.dumps(sub.export_delta()).encode())
+
+    assert export_bytes(64) <= 4 * export_bytes(16)
 
 
 def test_flight_recorder_global_cap_sheds_proportionally():

@@ -159,6 +159,20 @@ def test_key_clamp_bounds_footprint_under_hostile_stream():
     assert len(sk) <= len(hostile)
 
 
+def test_bucket_footprint_saturates_on_a_stationary_stream():
+    """The O(metrics)-not-O(samples) memory contract: ten times the
+    samples of one distribution fill nearly the same log-buckets (only
+    the counts in them rise)."""
+    rng = np.random.default_rng(42)
+    sk = QuantileSketch()
+    for v in rng.lognormal(mean=-3.0, sigma=1.0, size=1000):
+        sk.add(float(v))
+    at_1k = len(sk)
+    for v in rng.lognormal(mean=-3.0, sigma=1.0, size=9000):
+        sk.add(float(v))
+    assert sk.n == 10_000 and len(sk) <= 1.75 * at_1k
+
+
 def test_degenerate_inputs_are_ignored():
     sk = QuantileSketch()
     sk.add(float("nan"))

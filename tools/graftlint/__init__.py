@@ -4,8 +4,8 @@ concurrency, and dependency invariants.
 Seven stages (full reference: ``docs/static_analysis.md``):
 
 * AST (``rules.py`` + ``concurrency.py``): pluggable source rules over
-  ``distributed_learning_tpu/``, ``benchmarks/``, ``examples/`` and
-  ``bench.py``, with ``# graftlint: disable=<rule>[ -- reason]`` inline
+  ``distributed_learning_tpu/``, ``examples/`` and
+  ``chip_smoke.py``, with ``# graftlint: disable=<rule>[ -- reason]`` inline
   suppressions.  Imports no jax — safe and fast anywhere.
 * Wire contract (``wire_contract.py``): the Python<->C++ drift checker
   for the native wire engine's hand-maintained constants, pinned next

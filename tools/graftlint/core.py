@@ -1,9 +1,8 @@
 """graftlint core: findings, suppressions, file contexts, rule registry.
 
 The AST stage walks every python file under the scanned roots
-(``distributed_learning_tpu/``, ``benchmarks/``, ``examples/``,
-``bench.py``) and runs each registered :class:`Rule` over it.  A finding
-is silenced by an inline suppression comment:
+(``distributed_learning_tpu/``, ``examples/``, ``chip_smoke.py``) and
+runs each registered :class:`Rule` over it.  A finding is silenced by an inline suppression comment:
 
     x = lax.psum(h, "model")  # graftlint: disable=raw-collective-in-shard-map -- megatron exit
 
@@ -38,9 +37,7 @@ REPO_ROOT = os.path.dirname(
 #: The trees/files the AST stage audits by default (repo-relative).
 DEFAULT_ROOTS = (
     "distributed_learning_tpu",
-    "benchmarks",
     "examples",
-    "bench.py",
     "chip_smoke.py",
 )
 

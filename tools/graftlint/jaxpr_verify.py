@@ -298,7 +298,9 @@ class Analysis:
         self.saw_vma = False
 
 
-def _pred_invariant(eqn, scope: frozenset) -> Optional[bool]:
+def _pred_invariant(
+    eqn, scope: frozenset, vma_tracked: bool
+) -> Optional[bool]:
     if not scope:
         return True
     invars = getattr(eqn, "invars", ())
@@ -307,7 +309,7 @@ def _pred_invariant(eqn, scope: frozenset) -> Optional[bool]:
     pred = invars[0]
     if _is_literal(pred):
         return True
-    vma = _vma_of(pred)
+    vma = _vma_of(pred) if vma_tracked else None
     if vma is None:
         return None
     return not (set(vma) & set(scope))
@@ -328,7 +330,16 @@ def analyze_jaxpr(jaxpr, repo_root: str = REPO_ROOT) -> Analysis:
     return an
 
 
-def _walk(j, path, scope, an, repo_root) -> List[str]:
+def _tracks_vma(eqn) -> bool:
+    """False for a ``shard_map`` traced with ``check_vma=False`` (what
+    ``jax.pmap`` traces to on the installed jax, 0.9): every aval inside
+    carries an empty ``vma`` that records nothing, so an empty set there
+    is "not tracked", never "provably invariant"."""
+    params = eqn.params
+    return params.get("check_vma", params.get("check_rep")) is not False
+
+
+def _walk(j, path, scope, an, repo_root, vma_tracked=True) -> List[str]:
     seq: List[str] = []
     counters: Counter = Counter()
     local_collectives = []
@@ -359,7 +370,8 @@ def _walk(j, path, scope, an, repo_root) -> List[str]:
             for k, br in enumerate(eqn.params.get("branches", ())):
                 sub = _sub(br)
                 branch_seqs.append(
-                    _walk(sub, f"{lab}.b{k}", scope, an, repo_root)
+                    _walk(sub, f"{lab}.b{k}", scope, an, repo_root,
+                          vma_tracked)
                     if sub is not None
                     else []
                 )
@@ -369,7 +381,7 @@ def _walk(j, path, scope, an, repo_root) -> List[str]:
                 uniform=uniform,
                 sequences=branch_seqs,
                 axis_scope=tuple(sorted(scope)),
-                pred_invariant=_pred_invariant(eqn, scope),
+                pred_invariant=_pred_invariant(eqn, scope, vma_tracked),
                 source=_source_site(eqn, repo_root),
             )
             if branch_seqs and uniform:
@@ -381,7 +393,7 @@ def _walk(j, path, scope, an, repo_root) -> List[str]:
             lab = label("scan")
             sub = _sub(eqn.params.get("jaxpr"))
             body = (
-                _walk(sub, lab, scope, an, repo_root)
+                _walk(sub, lab, scope, an, repo_root, vma_tracked)
                 if sub is not None
                 else []
             )
@@ -395,12 +407,12 @@ def _walk(j, path, scope, an, repo_root) -> List[str]:
             csub = _sub(eqn.params.get("cond_jaxpr"))
             bsub = _sub(eqn.params.get("body_jaxpr"))
             cseq = (
-                _walk(csub, f"{lab}.cond", scope, an, repo_root)
+                _walk(csub, f"{lab}.cond", scope, an, repo_root, vma_tracked)
                 if csub is not None
                 else []
             )
             bseq = (
-                _walk(bsub, f"{lab}.body", scope, an, repo_root)
+                _walk(bsub, f"{lab}.body", scope, an, repo_root, vma_tracked)
                 if bsub is not None
                 else []
             )
@@ -413,10 +425,12 @@ def _walk(j, path, scope, an, repo_root) -> List[str]:
         if subs:
             sub_scope = scope | _axes_introduced(eqn)
             an.axes_seen.update(sub_scope)
+            sub_tracked = vma_tracked and _tracks_vma(eqn)
             lab = label(name)
             for i, sub in enumerate(subs):
                 sublab = lab if len(subs) == 1 else f"{lab}.{i}"
-                seq.extend(_walk(sub, sublab, sub_scope, an, repo_root))
+                seq.extend(_walk(sub, sublab, sub_scope, an, repo_root,
+                                 sub_tracked))
 
     for eqn, op, axes in local_collectives:
         an.collectives.append(
@@ -429,7 +443,8 @@ def _walk(j, path, scope, an, repo_root) -> List[str]:
                 source=_source_site(eqn, repo_root),
             )
         )
-    _vma_pass(j, path, scope, an, repo_root)
+    if vma_tracked:
+        _vma_pass(j, path, scope, an, repo_root)
     return seq
 
 

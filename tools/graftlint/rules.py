@@ -18,13 +18,10 @@ invariants (CLAUDE.md "Conventions that bite", SURVEY.md §2):
 * ``host-sync-in-hot-path`` — ``.item()`` / ``float()`` /
   ``np.asarray()`` inside jit-decorated or scanned step functions force
   a device->host sync per call.
-* ``stdout-contract`` — ``bench.py`` must print exactly one JSON record
-  line on stdout; every stdout ``print`` must be a ``json.dumps`` emit,
-  everything else goes to stderr.
 * ``no-print-in-library`` — library code (``distributed_learning_tpu/``)
   reports through the obs layer and named ``logging`` loggers, never
-  bare ``print``; stdout belongs to the CLI/bench emit paths and
-  benchmarks/examples (exempt trees).  A legitimate library print (a
+  bare ``print``; stdout belongs to the CLI's emit paths and the
+  examples (an exempt tree).  A legitimate library print (a
   CLI subcommand's output, a matplotlib-free fallback) carries a
   reasoned suppression.
 * ``wallclock-duration`` — durations/latencies must be measured on a
@@ -381,63 +378,6 @@ class HostSyncInHotPath(Rule):
 
 
 @register
-class StdoutContract(Rule):
-    """bench.py: stdout is exactly the one-JSON-record channel."""
-
-    name = "stdout-contract"
-    files = frozenset({"bench.py"})
-
-    def _is_json_dumps(self, node: ast.AST) -> bool:
-        if isinstance(node, ast.Call):
-            name = dotted_name(node.func) or ""
-            return name.endswith("json.dumps") or name == "dumps"
-        return False
-
-    def check(self, ctx: FileContext) -> List[Finding]:
-        if ctx.relpath not in self.files:
-            return []
-        out = []
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            name = dotted_name(node.func) or ""
-            if name == "sys.stdout.write":
-                out.append(
-                    Finding(
-                        self.name,
-                        ctx.relpath,
-                        node.lineno,
-                        "sys.stdout.write bypasses the one-JSON-line "
-                        "emit path; route records through the single "
-                        "json.dumps print and diagnostics to stderr",
-                    )
-                )
-                continue
-            if name != "print":
-                continue
-            file_kw = next(
-                (kw for kw in node.keywords if kw.arg == "file"), None
-            )
-            if file_kw is not None and dotted_name(file_kw.value) != (
-                "sys.stdout"
-            ):
-                continue  # stderr (or another explicit sink)
-            if node.args and self._is_json_dumps(node.args[0]):
-                continue
-            out.append(
-                Finding(
-                    self.name,
-                    ctx.relpath,
-                    node.lineno,
-                    "print to stdout that is not a json.dumps record: "
-                    "the driver parses stdout as exactly one JSON line "
-                    "— send diagnostics to stderr (file=sys.stderr)",
-                )
-            )
-        return out
-
-
-@register
 class NoPrintInLibrary(Rule):
     """Bare ``print`` in library code must carry a reasoned suppression.
 
@@ -445,9 +385,8 @@ class NoPrintInLibrary(Rule):
     (``dlt.comm.*``) are the library's reporting channels — the
     reference's debug-flag prints are exactly the observability this
     repo replaced, and a stray ``print`` in the comm layer would also
-    corrupt any driver parsing stdout.  Benchmarks, examples, tools,
-    and ``bench.py`` own their stdout (bench.py's is separately held to
-    the ``stdout-contract``); everything else needs
+    corrupt any driver parsing stdout.  Examples, tools and
+    ``chip_smoke.py`` own their stdout; everything else needs
     ``# graftlint: disable=no-print-in-library -- <why this print is
     the interface>``.
     """
@@ -455,8 +394,8 @@ class NoPrintInLibrary(Rule):
     name = "no-print-in-library"
     requires_reason = True
     #: trees/files whose stdout IS their interface.
-    exempt_prefixes = ("benchmarks/", "examples/", "tools/", "tests/")
-    exempt_files = frozenset({"bench.py", "chip_smoke.py"})
+    exempt_prefixes = ("examples/", "tools/", "tests/")
+    exempt_files = frozenset({"chip_smoke.py"})
 
     def check(self, ctx: FileContext) -> List[Finding]:
         rel = ctx.relpath
