@@ -1,26 +1,47 @@
 """Fused flash attention as Pallas TPU kernels — forward AND backward.
 
 The single-device hot op behind the transformer path: O(T^2) attention
-computed blockwise with the online-softmax recurrence, so neither the
-(T, T) score matrix nor the full K/V ever sits in VMEM.  Grid =
-(batch*heads, q-blocks, k-blocks): the innermost k dimension iterates
-sequentially on a TPU core, so the (block_q, D) accumulator and the
-running max/denominator live in VMEM scratch across k steps — initialized
-at k==0, finalized into the output block at the last k.  K/V blocks
-stream HBM->VMEM via the grid's implicit double-buffered DMA, matmuls hit
-the MXU with f32 accumulation, and the causal path skips the compute for
-fully-masked blocks.
+computed blockwise with the online-softmax recurrence, so the (T, T)
+score matrix never exists.  One algorithm, two block schedules, chosen
+at trace time from the shape alone (:func:`_resident_plan`):
 
-Training works through the kernel: a ``jax.custom_vjp`` supplies the
+* **resident** — a head's whole K and V (and, backward, their f32
+  gradient accumulators) fit a VMEM budget, as at GPT-2's T 1,024 x
+  head size 64.  Grid (batch, lane blocks, q-blocks): K/V are fetched
+  once a head, the walk over key sub-blocks is a loop inside the kernel
+  with the causal (or window) trip count, the mask runs only on the
+  sub-blocks the diagonal or the window's edge crosses, and the
+  backward is ONE kernel.  Operands are the free view (B, T, H*D); a
+  128-lane block holds 128 // D heads side by side, each head's
+  products masked by lane, so nothing is padded or transposed in HBM.
+  Kernel names ``flash_fwd_resident``, ``flash_bwd_dq_dkv_resident``
+  (the section further down).
+* **streaming** — every other shape (long sequences, the Qwen3-Next
+  layer's T 4,096 x head size 256, heads that do not tile 128 lanes),
+  and ``flash_attention_with_lse`` (ring flash attention) always.  Grid
+  = (batch*heads, q-blocks, k-blocks): the innermost k dimension
+  iterates sequentially on a TPU core, so the (block_q, D) accumulator
+  and the running max/denominator live in VMEM scratch across k steps —
+  initialized at k==0, finalized into the output block at the last k.
+  K/V blocks stream HBM->VMEM via the grid's implicit double-buffered
+  DMA and the causal path skips the compute for fully-masked blocks.
+  Kernel names ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``.
+
+Both run their matmuls on the MXU in the operands' own dtype with f32
+accumulation and keep f32 softmax statistics; the kernel bodies share
+:func:`_masked_scores`, :func:`_online_softmax` and
+:func:`_softmax_grad`.
+
+Training works through the kernels: a ``jax.custom_vjp`` supplies the
 standard recompute-based flash backward.  The forward additionally saves
-the per-row logsumexp of the scaled scores — lane-replicated to shape
-``(BH, T, 128)``, the layout the TPU Pallas lowering requires (the last
-two block dims must tile to (8, 128); a ``(1, block_q)`` block does not
-lower, as the real compiler taught this module the hard way).  The
-backward recomputes each score block from (Q, K) on the MXU instead of
-materializing the (T, T) probability matrix, and splits into two kernels
-so every accumulator is a sequential reduction over its innermost grid
-axis:
+the per-row logsumexp of the scaled scores — in the streaming schedule
+lane-replicated to shape ``(BH, T, 128)`` (the last two block dims must
+tile to (8, 128); a ``(1, block_q)`` block does not lower), in the
+resident one as rows along lanes, one f32 a row.  The backward
+recomputes each score block from (Q, K) on the MXU instead of
+materializing the (T, T) probability matrix.  Streaming, it splits into
+two kernels so every accumulator is a sequential reduction over its
+innermost grid axis:
 
 * dQ kernel  — grid (BH, q-blocks, k-blocks): for one Q block, walk K/V
   blocks accumulating dQ += scale * dS @ K with dS = P * (dP - delta),
@@ -30,16 +51,17 @@ axis:
 * dK/dV kernel — grid (BH, k-blocks, q-blocks): for one K/V block, walk
   Q blocks accumulating dV += P^T @ dO and dK += scale * dS^T @ Q.
 
-Head dims that do not fill a 128-lane tile are zero-padded to 128 before
-the kernels and sliced after — scores and softmax are unchanged by zero
-columns, and the pad/slice pair is differentiable, so the padding
-composes with the custom VJP.
+Streaming, head dims that do not fill a 128-lane tile are zero-padded to
+128 before the kernels and sliced after — scores and softmax are
+unchanged by zero columns, and the pad/slice pair is differentiable, so
+the padding composes with the custom VJP.
 
 Context length is bounded by HBM, not VMEM.  On the chip the kernels'
 time is the chip benchmark's ``flash_ms.tok`` / ``flash_ms.hyb``
-(PERF.md section 5).  On CPU the
-same kernels run under ``interpret=True`` for the
-tests; correctness bar: values and gradients match
+(PERF.md section 5; the byte counts and what was measured:
+``docs/flash_roofline.md``).  On CPU the same kernels run under
+``interpret=True`` for the tests; correctness bar: values and gradients
+match
 :func:`~distributed_learning_tpu.ops.ring_attention.attention_reference`.
 """
 
@@ -115,6 +137,48 @@ def _masked_scores(q, k_blk, qi, kj, block_q, block_k, sm_scale, causal,
     return s
 
 
+def _online_softmax(s, m_prev, l_prev, acc_prev, v_blk):
+    """One key block of the online-softmax recurrence: the running max
+    ``m``, denominator ``l`` (both (block_q, 1) f32) and the (block_q, D)
+    f32 accumulator after the scores ``s`` of this block."""
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_next = jnp.maximum(m_prev, m_cur)
+    corr = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next)
+    l_next = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    # l is summed from the f32 probabilities above; only the matmul
+    # operand drops to V's dtype, so the normalizer stays exact while
+    # P@V hits the MXU at native-dtype rate (identity cast for f32 V).
+    pv = jax.lax.dot_general(
+        p.astype(v_blk.dtype), v_blk,
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return m_next, l_next, acc_prev * corr + pv
+
+
+def _softmax_grad(s, lse, do, v_blk, delta, adj=0.0):
+    """``(P, dS)`` of one score block from the saved logsumexp:
+    ``P = exp(S - lse)``, ``dP = dO @ V^T``, ``dS = P * (dP - delta +
+    adj)``; ``lse``, ``delta`` and ``adj`` are (block_q, 1) columns.
+    Matmuls run on native-dtype operands with f32 accumulation (see
+    :func:`_masked_scores`)."""
+    p = jnp.exp(s - lse)  # (bq, bk); masked entries -> 0
+    dp = jax.lax.dot_general(  # dO @ V^T -> (bq, bk)
+        do, v_blk,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta + adj)
+
+
+def _row_delta(do, o):
+    """``rowsum(dO * O)`` in f32 on the VPU, noise next to the MXU
+    matmuls: (block_q, D) -> (block_q, 1)."""
+    return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     *, sm_scale, causal, window=None,
@@ -142,22 +206,10 @@ def _flash_kernel(
             q_ref[0], k_ref[0], qi, kj, block_q, block_k, sm_scale, causal,
             window,
         )
-        m_prev = m_ref[:, :1]  # lane-replicated; any lane is the value
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        corr = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        l_next = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        # l is summed from the f32 probabilities above; only the matmul
-        # operand drops to V's dtype, so the normalizer stays exact while
-        # P@V hits the MXU at native-dtype rate (identity cast for f32 V).
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        # m and l are lane-replicated; any lane is the value
+        m_next, l_next, acc_ref[...] = _online_softmax(
+            s, m_ref[:, :1], l_ref[:, :1], acc_ref[...], v_ref[0]
         )
-        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
 
@@ -205,21 +257,11 @@ def _flash_dq_kernel(
             q_ref[0], k_ref[0], qi, kj, block_q, block_k, sm_scale, causal,
             window,
         )
-        p = jnp.exp(s - lse_ref[0][:, :1])  # (bq, bk); masked entries -> 0
-        # Matmuls run on native-dtype operands with f32 accumulation (see
-        # _masked_scores); delta's (bq, D) multiply-reduce stays f32 on
-        # the VPU — noise next to the two MXU matmuls.
-        delta = jnp.sum(
-            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )
-        dp = jax.lax.dot_general(  # dO @ V^T -> (bq, bk)
-            do_ref[0], v_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
         adj = 0.0 if dadj_ref is None else dadj_ref[0][:, :1]
-        ds = p * (dp - delta + adj)
+        _, ds = _softmax_grad(
+            s, lse_ref[0][:, :1], do_ref[0], v_ref[0],
+            _row_delta(do_ref[0], o_ref[0]), adj,
+        )
         dq_acc[...] += sm_scale * jax.lax.dot_general(  # dS @ K -> (bq, D)
             ds.astype(k_ref.dtype), k_ref[0],
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -257,23 +299,16 @@ def _flash_dkv_kernel(
             q_blk, k_ref[0], qi, kj, block_q, block_k, sm_scale, causal,
             window,
         )
-        p = jnp.exp(s - lse_ref[0][:, :1])  # (bq, bk)
-        delta = jnp.sum(
-            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-            axis=-1, keepdims=True,
+        adj = 0.0 if dadj_ref is None else dadj_ref[0][:, :1]
+        p, ds = _softmax_grad(
+            s, lse_ref[0][:, :1], do_ref[0], v_ref[0],
+            _row_delta(do_ref[0], o_ref[0]), adj,
         )
         dv_acc[...] += jax.lax.dot_general(  # P^T @ dO -> (bk, D)
             p.astype(do_ref.dtype), do_ref[0],
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dp = jax.lax.dot_general(  # dO @ V^T -> (bq, bk)
-            do_ref[0], v_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        adj = 0.0 if dadj_ref is None else dadj_ref[0][:, :1]
-        ds = p * (dp - delta + adj)
         dk_acc[...] += sm_scale * jax.lax.dot_general(  # dS^T @ Q -> (bk, D)
             ds.astype(q_blk.dtype), q_blk,
             dimension_numbers=(((0,), (0,)), ((), ())),
@@ -477,6 +512,317 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 
+# ---------------------------------------------------------------------- #
+# the resident schedule: a short sequence's K/V stay in VMEM             #
+# ---------------------------------------------------------------------- #
+# The module docstring has what this schedule is; the kernels keep the
+# logsumexp as (B, H*D // W, heads a lane block, T) f32, rows along lanes.
+
+#: Rows of queries a grid step takes and keys an inner step takes (the
+#: largest multiple of 128 up to this that divides T).  Measured on a v5e
+#: at GPT-2's shape (T 1,024, D 64, 4 agents x 24 heads, forward +
+#: backward kernels, ms a layer): 128: 3.52, 256: 1.40, 512: 1.21.  An
+#: inner step costs 0.2-0.4 us whatever its size beside 5.6 (forward) +
+#: 8.4 (backward) ps a score, so the fewer, larger steps of 512 win
+#: though 75% of the causal square is then live where 256 has 62.5%
+#: (docs/flash_roofline.md); 1,024 would put 16 MiB of f32 score tiles
+#: in VMEM.
+_RESIDENT_BLOCK = 512
+#: What a resident grid step may hold in VMEM, of the 16 MiB Mosaic
+#: scopes a kernel to by default on a v5e (128 MiB physical): half, the
+#: other half left to the f32 score tiles (S, P, dP, dS) and whatever
+#: the compiler spills.  Counted for the backward, the larger kernel:
+#: K, V, dK, dV whole and the q, o, dO, dq blocks, each double-buffered
+#: by the pipeline, plus the two f32 accumulators.  GPT-2's head pair
+#: (T 1,024 x 128 lanes, bf16) needs 4 MiB; the Qwen3-Next layer
+#: (T 4,096 x 256) 26 MiB and streams.
+_RESIDENT_VMEM_BUDGET = 8 * 2**20
+
+
+def _resident_plan(T, H, D, dtype):
+    """``(lane width W, sub-block)`` if attention over (B, T, H, D) takes
+    the resident schedule, else ``None`` (it streams).  Decided from the
+    shape alone: the heads must tile 128 lanes (D a multiple of 128, or a
+    divisor of it with H*D on the lane grid), the sub-block must be a
+    multiple of 128 that divides T (the logsumexp's rows lie along
+    lanes), and the backward's working set must fit the budget."""
+    if D % _LANES == 0:
+        W = D
+    elif _LANES % D == 0 and (H * D) % _LANES == 0:
+        W = _LANES
+    else:
+        return None
+    block = next(
+        (b for b in range(_RESIDENT_BLOCK, 0, -_LANES) if T % b == 0), None
+    )
+    if block is None:
+        return None
+    item = jnp.dtype(dtype).itemsize
+    held = 2 * (4 * T + 4 * block) * W * item + 2 * T * W * 4
+    return (W, block) if held <= _RESIDENT_VMEM_BUDGET else None
+
+
+def _key_ranges(qi, block, T, causal, window):
+    """Key sub-blocks a query block walks, as ``(lo, a, b, hi)``: live
+    are ``[lo, hi)``; ``[a, b)`` lie wholly inside the mask's support and
+    need no mask, ``[lo, a)`` (the window's edge) and ``[b, hi)`` (the
+    diagonal) are crossed by it."""
+    nk = T // block
+    if not causal:
+        return 0, 0, nk, nk
+    # query and key sub-blocks are the same size: block qi's diagonal
+    # block is key block qi, everything before it is wholly visible.
+    if window is None:
+        return 0, 0, qi, qi + 1
+    r0 = qi * block
+    lo = jnp.maximum(r0 - (window - 1), 0) // block
+    # wholly inside the band: first key >= last query - (window - 1)
+    a = jnp.maximum(r0 + block - window + block - 1, 0) // block
+    a = jnp.clip(a, lo, qi)
+    return lo, a, qi, qi + 1
+
+
+def _lane_heads(W, head_dim):
+    """Which head of a packed block each lane belongs to, or ``None``
+    where the block is one head (nothing to mask)."""
+    if W == head_dim:
+        return None
+    return jax.lax.broadcasted_iota(jnp.int32, (1, W), 1) // head_dim
+
+
+def _col_to_row(col):
+    """(n, 1) column -> (1, n) row, through a lane-replicated transpose
+    (the aligned 2-D transposes Mosaic has)."""
+    n = col.shape[0]
+    return jnp.transpose(jnp.broadcast_to(col, (n, _LANES)))[:1]
+
+
+def _row_to_col(row):
+    n = row.shape[1]
+    return jnp.transpose(jnp.broadcast_to(row, (_LANES, n)))[:, :1]
+
+
+def _walk(ranges, step, carry):
+    """Run ``step(kj, carry, masked)`` over the three stretches of
+    :func:`_key_ranges`; a stretch that is statically empty is not
+    traced."""
+    lo, a, b, hi = ranges
+    for start, stop, masked in ((lo, a, True), (a, b, False), (b, hi, True)):
+        if isinstance(start, int) and isinstance(stop, int) and start == stop:
+            continue
+        carry = jax.lax.fori_loop(
+            start, stop, functools.partial(step, masked=masked), carry
+        )
+    return carry
+
+
+def _head_slices(x, lane_head, heads):
+    """``x`` once a head of a packed block with the other heads' lanes
+    zeroed: the 128-lane contraction is then that head's alone (what a
+    zero-padded copy would compute).  A block of one head passes."""
+    if lane_head is None:
+        return [x]
+    return [jnp.where(lane_head == h, x, jnp.zeros_like(x))
+            for h in range(heads)]
+
+
+def _merge_heads(per_head, lane_head):
+    """Each head's own lanes out of its (rows, W) result."""
+    out = per_head[0]
+    for h, x in enumerate(per_head[1:], 1):
+        out = jnp.where(lane_head == h, x, out)
+    return out
+
+
+def _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
+                         causal, window, head_dim):
+    """One block of queries of one lane block (one head, or several side
+    by side) against all of its live keys.  The heads of a block walk
+    the keys together: their recurrences are independent, so one head's
+    products overlap the other's softmax."""
+    qi = pl.program_id(2)
+    block, W = q_ref.shape[1], q_ref.shape[2]
+    heads = W // head_dim
+    lane_head = _lane_heads(W, head_dim)
+    q_heads = _head_slices(q_ref[0], lane_head, heads)
+
+    def step(kj, carry, masked):
+        rows = pl.ds(pl.multiple_of(kj * block, block), block)
+        k_blk, v_blk = k_ref[0, rows, :], v_ref[0, rows, :]
+        return tuple(
+            _online_softmax(
+                _masked_scores(q_h, k_blk, qi, kj, block, block, sm_scale,
+                               masked, window),
+                *state, v_blk)
+            for q_h, state in zip(q_heads, carry)
+        )
+
+    carry = _walk(
+        _key_ranges(qi, block, k_ref.shape[1], causal, window), step,
+        tuple((jnp.full((block, 1), _NEG_INF, jnp.float32),
+               jnp.zeros((block, 1), jnp.float32),
+               jnp.zeros((block, W), jnp.float32)) for _ in range(heads)),
+    )
+    outs = []
+    for h, (m, l, acc) in enumerate(carry):
+        l = jnp.maximum(l, 1e-30)
+        outs.append(acc / l)  # P @ V filled every lane; a head's are its own
+        if lse_ref is not None:
+            lse_ref[0, 0, h:h + 1, :] = _col_to_row(m + jnp.log(l))
+    o_ref[0] = _merge_heads(outs, lane_head).astype(o_ref.dtype)
+
+
+def _resident_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+                         dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
+                         window, head_dim):
+    """dQ of one block of queries and its share of dK, dV: S, P and dP
+    are computed once a block pair and feed all three."""
+    qi = pl.program_id(2)
+    block, W = q_ref.shape[1], q_ref.shape[2]
+    heads = W // head_dim
+    lane_head = _lane_heads(W, head_dim)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    do = do_ref[0]
+    q_heads = _head_slices(q_ref[0], lane_head, heads)
+    do_heads = _head_slices(do, lane_head, heads)
+    deltas = [
+        jnp.sum(x, axis=-1, keepdims=True) for x in _head_slices(
+            do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+            lane_head, heads)
+    ]
+    lses = [_row_to_col(lse_ref[0, 0, h:h + 1, :]) for h in range(heads)]
+    contract_rows = (((0,), (0,)), ((), ()))
+
+    def step(kj, dq_accs, masked):
+        rows = pl.ds(pl.multiple_of(kj * block, block), block)
+        k_blk, v_blk = k_ref[0, rows, :], v_ref[0, rows, :]
+        dqs, dks, dvs = [], [], []
+        for q_h, do_h, delta, lse, dq_acc in zip(
+                q_heads, do_heads, deltas, lses, dq_accs):
+            s = _masked_scores(q_h, k_blk, qi, kj, block, block, sm_scale,
+                               masked, window)
+            p, ds = _softmax_grad(s, lse, do_h, v_blk, delta)
+            ds = ds.astype(q_h.dtype)
+            # q_h and do_h are zero off their head's lanes, so are these
+            # products: the heads of a block share the accumulators.
+            dvs.append(jax.lax.dot_general(  # P^T @ dO -> (block, W)
+                p.astype(do_h.dtype), do_h, dimension_numbers=contract_rows,
+                preferred_element_type=jnp.float32,
+            ))
+            dks.append(jax.lax.dot_general(  # dS^T @ Q -> (block, W)
+                ds, q_h, dimension_numbers=contract_rows,
+                preferred_element_type=jnp.float32,
+            ))
+            dqs.append(dq_acc + jax.lax.dot_general(  # dS @ K -> (block, W)
+                ds, k_blk, dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ))
+        dv_acc[rows, :] += sum(dvs[1:], dvs[0])
+        dk_acc[rows, :] += sum(dks[1:], dks[0])
+        return tuple(dqs)
+
+    dqs = _walk(
+        _key_ranges(qi, block, k_ref.shape[1], causal, window), step,
+        tuple(jnp.zeros((block, W), jnp.float32) for _ in range(heads)),
+    )
+    # dS @ K filled every lane; a head's are its own
+    dq_ref[0] = (sm_scale * _merge_heads(dqs, lane_head)).astype(dq_ref.dtype)
+
+    @pl.when(qi == pl.num_programs(2) - 1)
+    def _finalize():
+        dk_ref[0] = (sm_scale * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _resident_layout(q, W, head_dim, block):
+    """``(grid, rows, whole, lse_spec, lse_shape)`` on the (B, T, H*D)
+    view, grid (B, lane blocks, query blocks): ``rows`` a block of
+    queries; ``whole`` all of K or V, whose index does not move with the
+    query block, so the pipeline fetches it once a head."""
+    B, T, HD = q.shape
+    heads = W // head_dim
+    rows = pl.BlockSpec((1, block, W), lambda b, j, qi: (b, qi, j))
+    whole = pl.BlockSpec((1, T, W), lambda b, j, qi: (b, 0, j))
+    lse_spec = pl.BlockSpec((1, 1, heads, block),
+                            lambda b, j, qi: (b, j, 0, qi))
+    lse_shape = _sds((B, HD // W, heads, T), jnp.float32, q)
+    return (B, HD // W, T // block), rows, whole, lse_spec, lse_shape
+
+
+_RESIDENT_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+)
+
+
+def _resident_fwd_call(q, k, v, head_dim, W, block, sm_scale, causal, window,
+                       interpret, *, with_lse):
+    grid, rows, whole, lse_spec, lse_shape = _resident_layout(
+        q, W, head_dim, block)
+    kwargs = dict(sm_scale=sm_scale, causal=causal, window=window,
+                  head_dim=head_dim)
+    if with_lse:
+        kernel = functools.partial(_resident_fwd_kernel, **kwargs)
+    else:
+        def kernel(q_ref, k_ref, v_ref, o_ref):
+            _resident_fwd_kernel(q_ref, k_ref, v_ref, o_ref, None, **kwargs)
+    o_shape = _sds(q.shape, q.dtype, q)
+    return pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[rows, whole, whole],
+        out_specs=[rows, lse_spec] if with_lse else rows,
+        out_shape=[o_shape, lse_shape] if with_lse else o_shape,
+        compiler_params=_RESIDENT_COMPILER_PARAMS,
+        interpret=interpret,
+        name="flash_fwd_resident",
+    )(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_resident(q, k, v, head_dim, W, block, sm_scale, causal, window,
+                    interpret):
+    """Attention on the (B, T, H*D) view in the resident schedule."""
+    return _resident_fwd_call(q, k, v, head_dim, W, block, sm_scale, causal,
+                              window, interpret, with_lse=False)
+
+
+def _flash_resident_fwd(q, k, v, head_dim, W, block, sm_scale, causal, window,
+                        interpret):
+    out, lse = _resident_fwd_call(q, k, v, head_dim, W, block, sm_scale,
+                                  causal, window, interpret, with_lse=True)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_resident_bwd(head_dim, W, block, sm_scale, causal, window,
+                        interpret, res, do):
+    q, k, v, out, lse = res
+    grid, rows, whole, lse_spec, _ = _resident_layout(q, W, head_dim, block)
+    T = q.shape[1]
+    return tuple(pl.pallas_call(
+        functools.partial(_resident_bwd_kernel, sm_scale=sm_scale,
+                          causal=causal, window=window, head_dim=head_dim),
+        grid=grid,
+        in_specs=[rows, whole, whole, rows, rows, lse_spec],
+        out_specs=[rows, whole, whole],
+        out_shape=[_sds(q.shape, x.dtype, q) for x in (q, k, v)],
+        scratch_shapes=[
+            pltpu.VMEM((T, W), jnp.float32),
+            pltpu.VMEM((T, W), jnp.float32),
+        ],
+        compiler_params=_RESIDENT_COMPILER_PARAMS,
+        interpret=interpret,
+        name="flash_bwd_dq_dkv_resident",
+    )(q, k, v, out, do, lse))
+
+
+_flash_resident.defvjp(_flash_resident_fwd, _flash_resident_bwd)
+
+
 def _prep_blocks(q, k, v, block_q, block_k):
     """Shared wrapper preprocessing: clamp block sizes to T (callers
     must forward the returned sizes to the kernel), validate
@@ -527,6 +873,25 @@ def _prep_blocks(q, k, v, block_q, block_k):
     return to_bh(q), to_bh(k), to_bh(v), block_q, block_k, unpack
 
 
+def _attend(q, k, v, scale, causal, block_q, block_k, interpret, window):
+    """The kernels under :func:`flash_attention` on (B, T, H, D), in the
+    schedule the shape takes (:func:`_resident_plan`)."""
+    B, T, H, D = q.shape
+    plan = _resident_plan(T, H, D, q.dtype)
+    if plan is not None:
+        view = lambda x: x.reshape(B, T, H * D)  # free: no transpose, no pad
+        out = _flash_resident(view(q), view(k), view(v), D, *plan, scale,
+                              causal, window, interpret)
+        return out.reshape(B, T, H, D)
+    qb, kb, vb, block_q, block_k, unpack = _prep_blocks(
+        q, k, v, block_q, block_k
+    )
+    return unpack(
+        _flash(qb, kb, vb, scale, causal, block_q, block_k, interpret,
+               window)
+    )
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -549,21 +914,24 @@ def flash_attention(
 
     Differentiable: gradients run through the Pallas backward kernels
     (``jax.custom_vjp``), so the transformer's ``attention="flash"`` mode
-    trains on TPU.  Head dims off the 128-lane grid are zero-padded
-    through the kernels and sliced back.  Off-TPU without ``interpret``
-    this falls back to the reference einsum/softmax path (XLA fuses it
-    well enough on CPU; the kernel is the TPU fast path).
+    trains on TPU.  Off-TPU without ``interpret`` this falls back to the
+    reference einsum/softmax path (XLA fuses it well enough on CPU; the
+    kernel is the TPU fast path).
 
-    Default blocks (256, 512) fit the VMEM budget of
-    ``docs/flash_roofline.md``; their rate against other block shapes on
-    the chip is not measured.  For any T they degrade to
-    the largest 8-aligned blocks that divide T, so every previously
-    valid sequence length keeps working.
+    The shape picks the schedule (module docstring): where K/V fit the
+    VMEM budget the resident kernels run on the operands as they lie,
+    with their own sub-block; everything else streams, head dims off the
+    128-lane grid zero-padded through the kernels and sliced back.
+    ``block_q`` / ``block_k`` are the streaming schedule's blocks: the
+    defaults (256, 512) fit the VMEM budget of
+    ``docs/flash_roofline.md``, which has what was measured on the chip;
+    for any T they degrade to the largest 8-aligned blocks that divide
+    T, so every previously valid sequence length keeps working.
 
     ``window`` (requires ``causal``) is sliding-window attention: row
     ``r`` attends to keys ``[r - window + 1, r]``.  Blocks entirely
-    outside the band are skipped in the forward AND both backward
-    kernels, so cost scales O(T * window) instead of O(T^2) — the
+    outside the band are skipped in the forward AND the backward
+    kernels of either schedule, so cost scales O(T * window) instead of O(T^2) — the
     standard long-context local-attention trade (Mistral-style).
     """
     D = q.shape[-1]
@@ -577,13 +945,8 @@ def flash_attention(
     if not on_tpu and not interpret:
         return attention_reference(q, k, v, causal=causal, sm_scale=scale,
                                    window=window)
-    qb, kb, vb, block_q, block_k, unpack = _prep_blocks(
-        q, k, v, block_q, block_k
-    )
-    return unpack(
-        _flash(qb, kb, vb, scale, causal, block_q, block_k, interpret,
-               window)
-    )
+    return _attend(q, k, v, scale, causal, block_q, block_k, interpret,
+                   window)
 
 
 @functools.partial(
@@ -605,7 +968,9 @@ def flash_attention_with_lse(
     that lets independent attention pieces be combined exactly
     (``ops.ring_attention.ring_flash_attention`` merges per-device block
     results through it).  Fully differentiable: the lse cotangent folds
-    into the backward kernels' ``dadj`` row term.
+    into the backward kernels' ``dadj`` row term.  Always the streaming
+    schedule: its consumers read the lane-replicated ``lse`` and hand
+    back a cotangent for it, which the resident kernels do not take.
 
     Off-TPU without ``interpret`` this computes the reference path plus a
     JAX logsumexp — same semantics, XLA-fused, differentiable.

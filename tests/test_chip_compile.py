@@ -28,9 +28,10 @@ from distributed_learning_tpu.ops import flash_attention as fa
 from distributed_learning_tpu.ops import gated_delta as gd
 
 GiB = 2.0**30
-#: (batch*heads, T, head_dim) of the flash cases, default blocks 256/512.
-#: The last is the Qwen3-Next cell's gated-attention layer: 2 agents x 16
-#: heads of 256 at T 4,096 (the GPT-2 cell runs head size 64).
+#: (batch*heads, T, head_dim) of the streaming flash cases, default blocks
+#: 256/512.  The last is the Qwen3-Next cell's gated-attention layer: 2
+#: agents x 16 heads of 256 at T 4,096.  The GPT-2 cell's head size 64
+#: takes the resident schedule: ``test_flash_compiles_at_the_gpt2_cell``.
 FLASH_SHAPES = [(8, 8192, 128), (16, 2048, 128), (32, 4096, 256)]
 BLOCK_Q, BLOCK_K = 256, 512
 
@@ -109,6 +110,26 @@ def test_flash_kernels_compile_for_v5e(one_chip, cache_off, shape, variant):
     fn = _flash_fn(variant, float(1.0 / np.sqrt(shape[-1])))
     compiled = _compile(fn, x, x, x)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("variant", ["fwd", "grad", "window"])
+def test_flash_compiles_at_the_gpt2_cell(one_chip, cache_off, variant):
+    """The GPT-2 cell's attention through the wrapper's own preparation
+    (``fa._attend``; the public wrapper asks ``jax.devices()``): 4 agents
+    vmapped x (2, 1024, 12, 64) bf16.  The shape takes the resident
+    schedule, two heads of 64 a 128-lane block: a block, an in-kernel
+    transpose or a loop bound Mosaic refuses is refused here."""
+    x = jax.ShapeDtypeStruct((4, 2, 1024, 12, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    window = 256 if variant == "window" else None
+    attend = jax.vmap(lambda q, k, v: fa._attend(
+        q, k, v, 0.125, True, BLOCK_Q, BLOCK_K, False, window))
+    fn = attend if variant == "fwd" else jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = _compile(fn, x, x, x).as_text()
+    assert "flash_fwd_resident" in text
+    assert ("flash_bwd_dq_dkv_resident" in text) == (variant != "fwd")
+    assert text.count("tpu_custom_call") == (1 if variant == "fwd" else 2)
 
 
 @pytest.mark.parametrize("variant", ["fwd", "grad"])
