@@ -347,6 +347,27 @@ class HeldExpertsMLP(nn.Module):
     a dispatch that gathers only the chosen rows would have to place.
     Nothing counts cut pairs, because dense dispatch has no way to cut
     one.  Input/output (B, T, d); parameters f32.
+
+    What it accepts beyond that layer (the defaults are that layer, its
+    parameter tree and its program as they were; anything else is a
+    ``ValueError`` that names the argument):
+
+    * ``score_func``: ``"softmax"`` over all experts, or ``"sigmoid"``,
+      each expert scored alone (DeepSeek-V3, arXiv:2412.19437, eq. 15);
+    * ``route_scale``: multiplies the chosen experts' (normalised)
+      weights, the published ``routed_scaling_factor``;
+    * ``bias_rate``: ``None``, or the step ``gamma`` of the balancing bias
+      of the same paper's section 2.1.2 (``noaux_tc``): one f32 value an
+      expert, zero at first, in the ``batch_stats`` collection — state no
+      gradient moves, which a trainer carries, shards and saves as it
+      does BatchNorm's and never mixes.  The top k are chosen on ``score
+      + bias``; the weights are the scores alone.  A ``train=True`` call
+      whose ``batch_stats`` are mutable leaves ``bias + gamma * sign(mean
+      load - load)`` behind, the load being the (token, choice) pairs
+      each of ALL the experts received from this call's tokens.
+      ``moe.load_max_all`` counts the fullest of them;
+    * ``shared_gate``: the shared expert's sigmoid gate, or (``False``)
+      the shared expert added as it is.
     """
 
     num_experts: int
@@ -357,9 +378,13 @@ class HeldExpertsMLP(nn.Module):
     shared_width: int = 512
     norm_topk: bool = True
     dtype: jnp.dtype = jnp.float32
+    score_func: str = "softmax"      # | "sigmoid"
+    route_scale: float = 1.0         # multiplies the chosen weights
+    bias_rate: float | None = None   # the balancing bias's step; None: none
+    shared_gate: bool = True         # the shared expert's sigmoid gate
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, train: bool = False):
         B, T, d = x.shape
         E, Eh, K = self.num_experts, self.experts_held, self.top_k
         if not (1 <= K <= E and 1 <= Eh and 0 <= self.first_expert
@@ -368,6 +393,16 @@ class HeldExpertsMLP(nn.Module):
                 f"top_k {K}, experts [{self.first_expert}, "
                 f"{self.first_expert + Eh}) do not fit {E} experts"
             )
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown score_func {self.score_func!r} "
+                "(want softmax|sigmoid)")
+        if self.bias_rate is not None and not self.bias_rate > 0:
+            raise ValueError(
+                f"bias_rate must be None or positive, got {self.bias_rate}")
+        if not self.route_scale > 0:
+            raise ValueError(
+                f"route_scale must be positive, got {self.route_scale}")
         S, h = B * T, self.expert_width
         tokens = x.reshape(S, d)
 
@@ -390,14 +425,32 @@ class HeldExpertsMLP(nn.Module):
                 tokens.astype(jnp.float32), info.nexp, info.nmant)
             self.sow("intermediates", "router_input", seen)
             logits = jnp.dot(seen, router, precision="highest")
-            gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
+            self.sow("intermediates", "router_logits", logits)
+            if self.score_func == "softmax":
+                scores = jax.nn.softmax(logits, -1)
+            else:
+                scores = jax.nn.sigmoid(logits)
+            if self.bias_rate is None:
+                gates, chosen = jax.lax.top_k(scores, K)
+            else:
+                bias = self.variable("batch_stats", "route_bias",
+                                     jnp.zeros, (E,), jnp.float32)
+                # the bias picks the experts; the weights never see it
+                _, chosen = jax.lax.top_k(scores + bias.value, K)
+                gates = jnp.take_along_axis(scores, chosen, axis=-1)
             self.sow("intermediates", "chosen", chosen)
             if self.norm_topk:
-                gates = gates / jnp.sum(gates, -1, keepdims=True)
+                total = jnp.sum(gates, -1, keepdims=True)
+                # sigmoid scores can all be zero: the published epsilon
+                gates = gates / (total if self.score_func == "softmax"
+                                 else total + 1e-20)
+            if self.route_scale != 1.0:
+                gates = gates * self.route_scale
             local = chosen - self.first_expert               # (S, K)
             on = local[..., None] == jnp.arange(Eh)          # (S, K, Eh)
             # weights[s, e]: token s's gate for held expert e, else 0
             weights = jnp.sum(jnp.where(on, gates[..., None], 0.0), axis=1)
+            self.sow("intermediates", "held_weights", weights)
         counts = jnp.sum(on, axis=(0, 1))                    # (Eh,) pairs
         for name, value in (
             ("moe.rows_held", jnp.sum(counts)),
@@ -405,6 +458,18 @@ class HeldExpertsMLP(nn.Module):
         ):
             self.sow("counters", name, value.astype(jnp.int32),
                      reduce_fn=lambda a, b: b)
+        if self.bias_rate is not None:
+            with jax.named_scope("moe_bias"):
+                # the pairs each of ALL the experts received
+                load = jnp.sum(chosen[..., None] == jnp.arange(E),
+                               axis=(0, 1)).astype(jnp.float32)
+                self.sow("counters", "moe.load_max_all",
+                         jnp.max(load).astype(jnp.int32),
+                         reduce_fn=lambda a, b: b)
+                if train and self.is_mutable_collection("batch_stats") \
+                        and not self.is_initializing():
+                    bias.value = bias.value + self.bias_rate * jnp.sign(
+                        jnp.mean(load) - load)
 
         with jax.named_scope("moe_experts"):
             init = nn.initializers.lecun_normal(batch_axis=(0,))
@@ -429,9 +494,12 @@ class HeldExpertsMLP(nn.Module):
                 nn.silu(dense(self.shared_width, "shared_gate_proj")(tokens))
                 * dense(self.shared_width, "shared_up")(tokens)
             )
-            out = out + shared.astype(jnp.float32) * jax.nn.sigmoid(
-                dense(1, "shared_gate")(tokens).astype(jnp.float32)
-            )
+            if self.shared_gate:
+                out = out + shared.astype(jnp.float32) * jax.nn.sigmoid(
+                    dense(1, "shared_gate")(tokens).astype(jnp.float32)
+                )
+            else:
+                out = out + shared.astype(jnp.float32)
         return out.reshape(B, T, d).astype(x.dtype)
 
 

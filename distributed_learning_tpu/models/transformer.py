@@ -384,6 +384,75 @@ class _RowDense(nn.Module):
         return jax.lax.psum(x @ kernel, self.tp_axis) + bias
 
 
+class _LatentAttention(nn.Module):
+    """Multi-head latent attention in its training form, nothing absorbed
+    (DeepSeek-V2, arXiv:2405.04434, section 2.1.2, without the query's
+    low-rank path: ``q_lora_rank`` null).  Keys and values come from ONE
+    compressed latent a token (``kv_rank`` wide, RMS-normed), the
+    positions from a rotary part beside it: ``rope_dim`` more columns of
+    the query's heads, and one rotary key of ``rope_dim`` that every head
+    shares.  A head's query/key is then ``nope_dim + rope_dim`` wide and
+    its value ``v_dim``: two widths, which the flash kernels take as they
+    are.  No biases; training path only.  Scopes ``mla_proj`` (the three
+    projections, the latent's norm, rotary) and ``mla_attn`` (the
+    kernels and the output projection)."""
+
+    num_heads: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+    attn_impl: str = "full"
+    dtype: jnp.dtype = jnp.float32
+    rope_base: float = 10000.0
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x, positions):
+        if self.attn_impl not in ("full", "flash"):
+            raise ValueError(
+                f"latent attention runs attn_impl full|flash, not "
+                f"{self.attn_impl!r}")
+        B, T, d = x.shape
+        H, Dn, Dr, Dv = self.num_heads, self.nope_dim, self.rope_dim, self.v_dim
+        R = self.kv_rank
+        proj = lambda features, name: nn.DenseGeneral(
+            features=features, use_bias=False, dtype=self.dtype, name=name)
+        turn = functools.partial(_rope, positions=positions,
+                                 base=self.rope_base)
+        with jax.named_scope("mla_proj"):
+            q = proj((H, Dn + Dr), "q_proj")(x)           # [q_nope | q_pe]
+            ckv = proj(R + Dr, "kv_a_proj")(x)            # [c | k_pe]
+            c = RMSNorm(self.norm_eps, self.dtype, name="kv_a_norm")(
+                ckv[..., :R])
+            kv = proj((H, Dn + Dv), "kv_b_proj")(c)       # [k_nope | v]
+            # the ONE rotary key of a token, which every head shares
+            k_pe = turn(ckv[..., None, R:])               # (B, T, 1, Dr)
+            k_pe = jnp.broadcast_to(k_pe, (B, T, H, Dr))
+            q = jnp.concatenate([q[..., :Dn], turn(q[..., Dn:])], axis=-1)
+            k = jnp.concatenate([kv[..., :Dn], k_pe], axis=-1)
+            v = kv[..., Dn:]
+            # read back only by a caller that makes "intermediates"
+            # mutable (the chip benchmark's check of the three operands)
+            for name, value in (("q", q), ("k", k), ("v", v)):
+                self.sow("intermediates", name, value)
+        with jax.named_scope("mla_attn"):
+            scale = float((Dn + Dr) ** -0.5)
+            if self.attn_impl == "full":
+                out = attention_reference(q, k, v, causal=True,
+                                          sm_scale=scale)
+            else:
+                from distributed_learning_tpu.ops.flash_attention import (
+                    flash_attention,
+                )
+
+                out = flash_attention(q, k, v, causal=True, sm_scale=scale)
+            return nn.DenseGeneral(
+                features=d, axis=(-2, -1), use_bias=False, dtype=self.dtype,
+                name="o_proj",
+            )(out)
+
+
 class _Block(nn.Module):
     num_heads: int
     head_dim: int
@@ -412,6 +481,9 @@ class _Block(nn.Module):
     # GatedDeltaNet's arguments: this block's mixer is linear attention
     linear_attn: Any = None
     held_experts: Any = None            # HeldExpertsMLP's (mlp="held_experts")
+    # _LatentAttention's arguments: this block's mixer is latent attention
+    latent_attn: Any = None
+    dense_width: int | None = None      # the SwiGLU's (mlp="swiglu")
 
     @nn.compact
     def __call__(self, x, positions=None, train: bool = False):
@@ -429,12 +501,14 @@ class _Block(nn.Module):
                 "manual tp_axis with mlp='moe' is not supported: shard "
                 "experts over an expert axis instead (moe_expert_axis)"
             )
-        hybrid = self.linear_attn is not None or self.mlp == "held_experts"
+        hybrid = (self.linear_attn is not None or self.latent_attn is not None
+                  or self.mlp in ("held_experts", "swiglu"))
         if hybrid and (self.decode or self.tp_axis is not None
                        or self.moe_expert_axis is not None):
             raise ValueError(
-                "linear-attention and held-experts blocks are "
-                "training-path layers: no decode, tp_axis or expert axis"
+                "linear-attention, latent-attention, held-experts and "
+                "SwiGLU blocks are training-path layers: no decode, "
+                "tp_axis or expert axis"
             )
         h = _norm(self.norm, self.norm_eps, self.dtype)(x)
         if self.linear_attn is not None:
@@ -445,6 +519,12 @@ class _Block(nn.Module):
             x = x + drop(GatedDeltaNet(
                 dtype=self.dtype, eps=self.norm_eps, **self.linear_attn
             )(h))
+        elif self.latent_attn is not None:
+            x = x + drop(_LatentAttention(
+                self.num_heads, attn_impl=self.attn_impl, dtype=self.dtype,
+                rope_base=self.rope_base, norm_eps=self.norm_eps,
+                **self.latent_attn
+            )(h, positions))
         else:
             x = x + drop(_Attention(
                 self.num_heads, self.head_dim, self.attn_impl, self.seq_axis,
@@ -459,7 +539,18 @@ class _Block(nn.Module):
 
             return x + drop(HeldExpertsMLP(
                 dtype=self.dtype, **self.held_experts
-            )(h))
+            )(h, train))
+        if self.mlp == "swiglu":
+            # the dense layers before a many-expert stack's first expert
+            # layer (the published first_k_dense_replace): no biases
+            if not self.dense_width:
+                raise ValueError("mlp='swiglu' needs dense_width")
+            dense = lambda f, name: nn.Dense(
+                f, use_bias=False, dtype=self.dtype, name=name)
+            with jax.named_scope("mlp_dense"):
+                return x + drop(dense(x.shape[-1], "down_proj")(
+                    nn.silu(dense(self.dense_width, "gate_proj")(h))
+                    * dense(self.dense_width, "up_proj")(h)))
         if self.mlp == "moe":
             # Expert-parallel feed-forward (models/moe.py): params become
             # stacked (E, ...) kernels shardable over an expert mesh axis.
@@ -472,7 +563,8 @@ class _Block(nn.Module):
             )(h))
         if self.mlp != "dense":
             raise ValueError(
-                f"unknown mlp {self.mlp!r} (want dense|moe|held_experts)"
+                f"unknown mlp {self.mlp!r} "
+                "(want dense|moe|held_experts|swiglu)"
             )
         d = x.shape[-1]
         if self.tp_axis is not None:
@@ -561,6 +653,26 @@ class TransformerLM(nn.Module):
     expert_width: int = 512
     shared_expert_width: int = 512
     remat_blocks: bool = False      # rematerialise each block in backward
+    # --- latent attention and the sigmoid-routed expert stack (training
+    # path only; the defaults build the models above as they were).
+    # kv_lora_rank set: every attention layer is _LatentAttention, a
+    # head's query/key qk_nope_head_dim + qk_rope_head_dim wide (rotary on
+    # the second part, pos_emb="rope") and its value v_head_dim; head_dim
+    # is then not read.
+    kv_lora_rank: int | None = None
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    # The first num_dense_layers blocks take a SwiGLU of dense_width in
+    # place of ``mlp`` (the published first_k_dense_replace).
+    num_dense_layers: int = 0
+    dense_width: int | None = None
+    # HeldExpertsMLP's router (its docstring): score_func, route_scale,
+    # bias_rate, shared_gate.
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    route_bias_rate: float | None = None
+    shared_expert_gate: bool = True
 
     @property
     def layer_types(self) -> tuple:
@@ -581,14 +693,15 @@ class TransformerLM(nn.Module):
             and self.mlp != "held_experts" and not self.attn_gate
             and self.norm == "layernorm" and self.hidden_size is None
             and self.rope_fraction == 1.0
+            and self.kv_lora_rank is None and not self.num_dense_layers
         )
 
     def require_uniform(self, what: str) -> None:
         if not self.uniform:
             raise ValueError(
                 f"{what} supports only uniform attention blocks; hybrid "
-                "layer kinds (linear attention, gated attention, held "
-                "experts, rmsnorm) run on the training path alone"
+                "layer kinds (linear or latent attention, gated attention, "
+                "held experts, rmsnorm) run on the training path alone"
             )
 
     @nn.compact
@@ -659,7 +772,23 @@ class TransformerLM(nn.Module):
                 first_expert=self.first_expert,
                 expert_width=self.expert_width,
                 shared_width=self.shared_expert_width,
+                score_func=self.router_score, route_scale=self.route_scale,
+                bias_rate=self.route_bias_rate,
+                shared_gate=self.shared_expert_gate,
             )
+        if self.kv_lora_rank is not None:
+            if not use_rope or self.full_attention_interval is not None \
+                    or self.attn_gate or self.num_kv_heads is not None:
+                raise ValueError(
+                    "kv_lora_rank (latent attention) needs pos_emb='rope' "
+                    "and takes no full_attention_interval, attn_gate or "
+                    "num_kv_heads")
+            hybrid["latent_attn"] = dict(
+                nope_dim=self.qk_nope_head_dim, rope_dim=self.qk_rope_head_dim,
+                v_dim=self.v_head_dim, kv_rank=self.kv_lora_rank,
+            )
+        if self.num_dense_layers:
+            hybrid["dense_width"] = self.dense_width
         linear_attn = dict(
             num_key_heads=self.linear_num_key_heads,
             num_value_heads=self.linear_num_value_heads,
@@ -674,7 +803,8 @@ class TransformerLM(nn.Module):
             x = block_cls(
                 self.num_heads, self.head_dim, self.mlp_ratio,
                 self.attn_impl, self.seq_axis, self.dtype,
-                self.mlp, self.num_experts, self.moe_top_k,
+                "swiglu" if i < self.num_dense_layers else self.mlp,
+                self.num_experts, self.moe_top_k,
                 self.attn_window, self.decode, self.max_len,
                 use_rope, self.num_kv_heads, self.dropout_rate,
                 moe_capacity_factor=self.moe_capacity_factor, **hybrid,

@@ -33,7 +33,7 @@ def collect_counters(state: Any) -> Dict[str, Any]:
     """One traced scalar per counter name from the ``counters``
     collection a model sowed (``model.apply(..., mutable=["counters"])``):
     layers that sow the same name are summed, or their maximum taken
-    where the name ends in ``_max``.  ``{}`` for a model that sows none
+    where the name ends in ``_max`` or ``_max_all``.  ``{}`` for a model that sows none
     (every model but the held-experts LM): no leaf, so the step program
     is the one it was."""
     import jax
@@ -47,7 +47,7 @@ def collect_counters(state: Any) -> Dict[str, Any]:
         )
         if name not in out:
             out[name] = leaf
-        elif name.endswith("_max"):
+        elif name.endswith(("_max", "_max_all")):
             out[name] = jnp.maximum(out[name], leaf)
         else:
             out[name] = out[name] + leaf

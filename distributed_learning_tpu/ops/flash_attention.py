@@ -17,8 +17,10 @@ at trace time from the shape alone (:func:`_resident_plan`):
   Kernel names ``flash_fwd_resident``, ``flash_bwd_dq_dkv_resident``
   (the section further down).
 * **streaming** — every other shape (long sequences, the Qwen3-Next
-  layer's T 4,096 x head size 256, heads that do not tile 128 lanes),
-  and ``flash_attention_with_lse`` (ring flash attention) always.  Grid
+  layer's T 4,096 x head size 256, heads that do not tile 128 lanes,
+  and a query/key width that differs from the value's: latent
+  attention's 192 against 128, which go through unpadded), and
+  ``flash_attention_with_lse`` (ring flash attention) always.  Grid
   = (batch*heads, q-blocks, k-blocks): the innermost k dimension
   iterates sequentially on a TPU core, so the (block_q, D) accumulator
   and the running max/denominator live in VMEM scratch across k steps —
@@ -51,7 +53,8 @@ innermost grid axis:
 * dK/dV kernel — grid (BH, k-blocks, q-blocks): for one K/V block, walk
   Q blocks accumulating dV += P^T @ dO and dK += scale * dS^T @ Q.
 
-Streaming, head dims that do not fill a 128-lane tile are zero-padded to
+Streaming, head dims that do not fill a 128-lane tile (q, k and v of one
+width) are zero-padded to
 128 before the kernels and sliced after — scores and softmax are
 unchanged by zero columns, and the pad/slice pair is differentiable, so
 the padding composes with the custom VJP.
@@ -327,6 +330,7 @@ def _fwd_call(qb, kb, vb, sm_scale, causal, block_q, block_k, interpret,
     omits the lse output entirely so forward-only callers don't pay a
     (BH, T, 128) f32 HBM write they would immediately discard."""
     BH, T, D = qb.shape
+    Dv = vb.shape[-1]  # the value's (and the output's) own width
     if with_lse:
         kernel = functools.partial(
             _flash_kernel, sm_scale=sm_scale, causal=causal, window=window
@@ -336,11 +340,11 @@ def _fwd_call(qb, kb, vb, sm_scale, causal, block_q, block_k, interpret,
             _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, acc_ref, m_ref,
                           l_ref, sm_scale=sm_scale, causal=causal,
                           window=window)
-    o_spec = pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0))
+    o_spec = pl.BlockSpec((1, block_q, Dv), lambda bh, qi, kj: (bh, qi, 0))
     lse_spec = pl.BlockSpec(
         (1, block_q, _LANES), lambda bh, qi, kj: (bh, qi, 0)
     )
-    o_shape = _sds((BH, T, D), qb.dtype, qb)
+    o_shape = _sds((BH, T, Dv), qb.dtype, qb)
     lse_shape = _sds((BH, T, _LANES), jnp.float32, qb)
     return pl.pallas_call(
         kernel,
@@ -348,12 +352,12 @@ def _fwd_call(qb, kb, vb, sm_scale, causal, block_q, block_k, interpret,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, qi, kj: (bh, kj, 0)),
         ],
         out_specs=[o_spec, lse_spec] if with_lse else o_spec,
         out_shape=[o_shape, lse_shape] if with_lse else o_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
@@ -373,6 +377,7 @@ def _bwd_call(qb, kb, vb, out, do, lse, dadj, sm_scale, causal, block_q,
     kernel input entirely instead of streaming a known-zero tensor
     through both kernels' grids."""
     BH, T, D = qb.shape
+    Dv = vb.shape[-1]  # v, o, dO and dV; q, k, dQ and dK are D wide
     lse_spec_q = pl.BlockSpec(
         (1, block_q, _LANES), lambda bh, qi, kj: (bh, qi, 0)
     )
@@ -395,9 +400,9 @@ def _bwd_call(qb, kb, vb, out, do, lse, dadj, sm_scale, causal, block_q,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, qi, kj: (bh, kj, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, qi, kj: (bh, qi, 0)),
             lse_spec_q,
         ] + ([] if dadj is None else [lse_spec_q]),
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, kj: (bh, qi, 0)),
@@ -424,22 +429,22 @@ def _bwd_call(qb, kb, vb, out, do, lse, dadj, sm_scale, causal, block_q,
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, kj, qi: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, kj, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh, kj, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, kj, qi: (bh, kj, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, kj, qi: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, kj, qi: (bh, qi, 0)),
             lse_spec_kv,
         ] + ([] if dadj is None else [lse_spec_kv]),
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, kj, qi: (bh, kj, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda bh, kj, qi: (bh, kj, 0)),
         ],
         out_shape=[
             _sds((BH, T, D), kb.dtype, qb),
-            _sds((BH, T, D), vb.dtype, qb),
+            _sds((BH, T, Dv), vb.dtype, qb),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -850,6 +855,7 @@ def _prep_blocks(q, k, v, block_q, block_k):
 
     block_q = _fit(block_q)
     block_k = _fit(block_k)
+    Dv = v.shape[-1]
     if jax.devices()[0].platform == "tpu" and T % 8:
         # Unaligned T cannot produce 8-aligned blocks; fail with a clear
         # message instead of a Mosaic lowering error.
@@ -860,14 +866,19 @@ def _prep_blocks(q, k, v, block_q, block_k):
     # The TPU lowering tiles the last two block dims to (8, 128): pad the
     # head dim up to a lane multiple.  Zero K/Q columns leave every score
     # unchanged; zero V columns produce zero output columns, sliced off.
-    Dp = max(_LANES, -(-D // _LANES) * _LANES)
+    # Two widths (q, k of D against v of Dv, latent attention's 192 / 128)
+    # go through as they lie: a block's last dimension is then the whole
+    # array's, which Mosaic tiles itself, and padding one to the other's
+    # width in HBM would move and multiply the dead columns.
+    Dp = D if Dv != D else max(_LANES, -(-D // _LANES) * _LANES)
     if Dp != D:
         pad = [(0, 0), (0, 0), (0, 0), (0, Dp - D)]
         q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
-    to_bh = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, T, Dp)
+    to_bh = lambda x: x.transpose(0, 2, 1, 3).reshape(
+        B * H, T, x.shape[-1])
 
     def unpack(out):
-        out = out.reshape(B, H, T, Dp).transpose(0, 2, 1, 3)
+        out = out.reshape(B, H, T, out.shape[-1]).transpose(0, 2, 1, 3)
         return out[..., :D] if Dp != D else out
 
     return to_bh(q), to_bh(k), to_bh(v), block_q, block_k, unpack
@@ -877,7 +888,8 @@ def _attend(q, k, v, scale, causal, block_q, block_k, interpret, window):
     """The kernels under :func:`flash_attention` on (B, T, H, D), in the
     schedule the shape takes (:func:`_resident_plan`)."""
     B, T, H, D = q.shape
-    plan = _resident_plan(T, H, D, q.dtype)
+    # the resident kernels walk lane blocks of one width: two widths stream
+    plan = (_resident_plan(T, H, D, q.dtype) if v.shape[-1] == D else None)
     if plan is not None:
         view = lambda x: x.reshape(B, T, H * D)  # free: no transpose, no pad
         out = _flash_resident(view(q), view(k), view(v), D, *plan, scale,
@@ -910,7 +922,15 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
 ) -> jax.Array:
-    """Fused attention on (B, T, H, D); T must divide by the block sizes.
+    """Fused attention on q, k (B, T, H, D) and v (B, T, H, Dv) ->
+    (B, T, H, Dv); T must divide by the block sizes.
+
+    ``Dv`` may differ from ``D`` (latent attention: a query/key of 192
+    against a value of 128): such a call always streams, on the operands'
+    own widths, nothing padded in HBM, forward and both backward kernels;
+    ``sm_scale`` defaults to ``1 / sqrt(D)``, the query/key width.  q and
+    k must agree in every dimension and v with them in all but the last;
+    anything else is a ``ValueError`` that names the argument.
 
     Differentiable: gradients run through the Pallas backward kernels
     (``jax.custom_vjp``), so the transformer's ``attention="flash"`` mode
@@ -934,6 +954,13 @@ def flash_attention(
     kernels of either schedule, so cost scales O(T * window) instead of O(T^2) — the
     standard long-context local-attention trade (Mistral-style).
     """
+    if q.ndim != 4:
+        raise ValueError(f"q must be (B, T, H, D), got shape {q.shape}")
+    if k.shape != q.shape:
+        raise ValueError(f"k must have q's shape {q.shape}, got {k.shape}")
+    if v.ndim != 4 or v.shape[:3] != q.shape[:3]:
+        raise ValueError(
+            f"v must be (B, T, H, Dv) with q's {q.shape[:3]}, got {v.shape}")
     D = q.shape[-1]
     scale = sm_scale if sm_scale is not None else float(1.0 / np.sqrt(D))
     if window is not None:

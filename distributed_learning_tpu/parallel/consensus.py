@@ -100,6 +100,25 @@ class AsyncGossipState(NamedTuple):
     rnd: jax.Array  # () int32
 
 
+#: The largest state an agent (bytes of one device's shard) that a fused
+#: program under a mesh still packs into one buffer per dtype.  Forced by
+#: memory: the pack holds a packed copy of the state in every stage of a
+#: round, which does not fit beside a 1.58 GiB shard and its optimizer
+#: state on a 16 GB chip; such a state is mixed leaf by leaf.  NOT
+#: measured: where the pack's O(buckets) messages a round stop paying.
+#: No chip run timed the two forms at one small shard and no benchmark
+#: cell lies under this bound (ROADMAP D3 has the measurement).
+FUSE_MAX_BYTES = 256 << 20
+
+
+def _packs(x: Pytree) -> bool:
+    """Whether this (local) state is small enough to pack: decided from
+    the shapes when the program is traced."""
+    return sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(x)
+    ) <= FUSE_MAX_BYTES
+
+
 def make_agent_mesh(n: int, *, axis_name: str = "agents") -> Mesh:
     """Mesh over the first ``n`` available devices with a single agent axis."""
     devices = jax.devices()
@@ -402,7 +421,8 @@ class ConsensusEngine:
         primitive in this module is layout-agnostic, so the same loop
         bodies serve both layouts — and the result is unraveled once at
         exit: ppermutes then move one fused message per bucket instead
-        of one per leaf.  The dense programs get the tree as it is: the
+        of one per leaf (a shard above ``FUSE_MAX_BYTES`` stays a tree).
+        The dense programs get the tree as it is: the
         pack saves them nothing and on a TPU it is a physical relayout
         of every leaf (``(N, a, b) -> (N, a*b)`` re-tiles the array;
         43 ms a call on the 4 x GPT-2 small tree, PERF.md).
@@ -418,6 +438,8 @@ class ConsensusEngine:
             return wrapped
 
         def wrapped(x, *args):
+            if not _packs(x):
+                return run(x, *args)
             buffers, layout = ops.flatten_stacked(x)
             out = run(buffers, *args)
             if isinstance(out, tuple):
@@ -433,7 +455,7 @@ class ConsensusEngine:
         max_std) read: under a mesh with ``fused=True`` the buckets (the
         statistic is leaf-order invariant, and O(buckets) ``pmean``s
         replace O(leaves)); the leaves themselves in the dense layout."""
-        if not self.fused or self.mesh is None:
+        if not self.fused or self.mesh is None or not _packs(x):
             return x
         return ops.flatten_stacked(x)[0]
 
@@ -461,6 +483,7 @@ class ConsensusEngine:
             "consensus.fused_buckets",
             layout.bucket_count
             if self.fused and self.mesh is not None
+            and layout.bytes_per_round(1) <= FUSE_MAX_BYTES
             else layout.leaf_count,
         )
         if rounds is not None and not isinstance(rounds, jax.core.Tracer):
@@ -1425,6 +1448,8 @@ class ConsensusEngine:
             return run
 
         def wrapped(x, pub, *rest):
+            if not _packs(x):
+                return run(x, pub, *rest)
             bx, layout = ops.flatten_stacked(x)
             bp, _ = ops.flatten_stacked(pub, layout)
             out = run(bx, bp, *rest)

@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, Hashable, List, Mapping, Optional, Seque
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 import numpy as np
 import optax
 
@@ -830,7 +831,30 @@ class GossipTrainer:
                 params = optax.apply_updates(params, updates)
             return params, new_bs, opt_state, loss, acc, gnorm, counters
 
-        vstep = jax.vmap(train_step)
+        engine = self.engine
+
+        def per_chip(fn):
+            """``fn`` takes and returns arrays with the agents on their
+            leading axis, and no agent reads another's.  Under a mesh each
+            chip then runs it on its own agents' operands (``shard_map``
+            over the agent axis, no collective inside): left to the
+            partitioner, a Pallas kernel in the step has no partitioning
+            rule and is fed all-gathered operands on every chip.  The
+            mesh is read when the program is traced; without one this is
+            ``fn`` itself."""
+            def run(*operands):
+                if engine.mesh is None:
+                    return fn(*operands)
+                spec = P(engine.axis_name)
+                return jax.shard_map(
+                    fn, mesh=engine.mesh, in_specs=spec, out_specs=spec,
+                    check_vma=False,
+                )(*operands)
+
+            return run
+
+        vstep = per_chip(jax.vmap(train_step))
+        take = per_chip(jax.vmap(lambda X, i: jnp.take(X, i, axis=0)))
 
         def epoch_fn(state, Xs, ys, idx):
             """scan over epoch_len steps of the vmapped train step.
@@ -844,8 +868,6 @@ class GossipTrainer:
             device-side metrics carry) and ``{name: (steps, n)}`` of the
             model's own counters (``{}`` where it counts nothing).
             """
-            take = jax.vmap(lambda X, i: jnp.take(X, i, axis=0))
-
             def body(carry, idx_t):
                 params, bs, opt, rng = carry
                 with jax.named_scope("gather"):
@@ -934,11 +956,28 @@ class GossipTrainer:
         return total / max(seen, 1)
 
     # ------------------------------------------------------------------ #
+    def _on_every_chip(self, x):
+        """The state's one unsharded leaf (the step key) where the epoch
+        program leaves it: replicated over the mesh.  Left on one device,
+        the first epoch's program is compiled for that placement and the
+        second epoch's again for the one the first returned: a compile
+        inside a caller's timed window."""
+        mesh = self.engine.mesh
+        return x if mesh is None else jax.device_put(
+            x, NamedSharding(mesh, P()))
+
     def initialize_nodes(self):
         """Create the shared init and per-node optimizer/batch-stat state
         (parity: ``master.initialize_nodes()``)."""
         rng = jax.random.key(self.seed)
         x0 = self._Xs[0, : self.batch_size]
+        mesh = self.engine.mesh
+        if mesh is not None:
+            # The shared init runs on ONE device, from a host copy of the
+            # sample: a slice of the sharded shards lives on the whole
+            # mesh, the init program would be partitioned over it, and a
+            # Pallas kernel in the model's forward cannot be.
+            x0 = np.asarray(x0)
         variables = self._jit_init(rng, x0)
         params0 = variables["params"]
         bs0 = variables.get("batch_stats", None)
@@ -946,20 +985,29 @@ class GossipTrainer:
         stack = lambda t: jax.tree.map(
             lambda v: jnp.broadcast_to(v[None], (n,) + v.shape), t
         )
-        params = stack(params0)
-        batch_stats = stack(bs0) if bs0 is not None else None
-        opt_state = jax.vmap(self.tx.init)(params)
-        # Every stacked leaf goes where its agent lives (one device per
-        # agent under a mesh; a no-op on the dense layout) — optimizer
-        # slots and BatchNorm stats too, not only the params.
-        params, batch_stats, opt_state = self.engine.shard(
-            (params, batch_stats, opt_state)
-        )
+
+        def replicas(params0, bs0):
+            params = stack(params0)
+            return (params, stack(bs0) if bs0 is not None else None,
+                    jax.vmap(self.tx.init)(params))
+
+        if mesh is None:
+            params, batch_stats, opt_state = self.engine.shard(
+                replicas(params0, bs0))
+        else:
+            # Every stacked leaf is made where its agent lives (one device
+            # per agent): optimizer slots and BatchNorm stats too, not
+            # only the params.  Stacked on one device first, four replicas
+            # of a 1.7 GB model and their Adam moments are 20 GB.
+            params, batch_stats, opt_state = jax.jit(
+                replicas, out_shardings=NamedSharding(
+                    mesh, P(self.engine.axis_name)),
+            )(params0, bs0)
         self._state = (
             params,
             batch_stats,
             opt_state,
-            jax.random.key(self.seed + 1),
+            self._on_every_chip(jax.random.key(self.seed + 1)),
         )
         self._choco_xhat = None  # fresh run: CHOCO estimates restart at 0
         self._choco_ef = None
@@ -2174,7 +2222,7 @@ class GossipTrainer:
             restored["params"],
             restored["batch_stats"] if bs is not None else None,
             restored["opt_state"],
-            jax.random.wrap_key_data(restored["rng"]),
+            self._on_every_chip(jax.random.wrap_key_data(restored["rng"])),
         )
         self._choco_xhat = None
         self._choco_ef = None

@@ -112,6 +112,31 @@ def test_flash_kernels_compile_for_v5e(one_chip, cache_off, shape, variant):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("variant", ["fwd", "grad"])
+def test_flash_compiles_at_two_widths_for_the_latent_cell(one_chip, cache_off,
+                                                          variant):
+    """The kanana cell's attention through the wrapper's own preparation:
+    one agent a chip, (1, 8192, 32 heads) with a query/key of 192 against
+    a value of 128.  The streaming kernels take both widths as they lie (a
+    block's last dimension is the array's own, 192, which is off the
+    128-lane grid): nothing is padded in HBM, and what Mosaic refuses of
+    such a block is refused here."""
+    qk = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    attend = lambda q, k, v: fa._attend(
+        q, k, v, 192 ** -0.5, True, BLOCK_Q, BLOCK_K, False, None)
+    fn = attend if variant == "fwd" else jax.grad(
+        lambda *a: attend(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = _compile(fn, qk, qk, v).as_text()
+    assert "resident" not in text and "flash_fwd" in text
+    assert ("flash_bwd_dq" in text and "flash_bwd_dkv" in text) == (
+        variant != "fwd")
+    assert text.count("tpu_custom_call") == (1 if variant == "fwd" else 3)
+    assert "bf16[32,8192,256]" not in text  # no operand padded to 256
+
+
 @pytest.mark.parametrize("variant", ["fwd", "grad", "window"])
 def test_flash_compiles_at_the_gpt2_cell(one_chip, cache_off, variant):
     """The GPT-2 cell's attention through the wrapper's own preparation
@@ -234,3 +259,64 @@ def test_sharded_ring_mix_compiles_to_collective_permute(agent_mesh, cache_off):
     assert "all-gather" not in text  # the state never leaves its device whole
     # Per device: one agent's 16 MiB in, 16 MiB out.
     assert compiled.memory_analysis().argument_size_in_bytes < 0.02 * GiB
+
+
+def test_the_sharded_step_with_kernels_compiles_one_agent_a_chip(
+        agent_mesh, cache_off, monkeypatch):
+    """The latent-attention LM's epoch program for the 2x2, one agent a
+    chip, at the kanana cell's widths (two layers, T 1,024, a 2,048-row
+    vocabulary): the vmapped step runs under ``shard_map`` over the agent
+    axis, so every chip gets its own Mosaic calls and nothing is gathered;
+    left to the partitioner a kernel has no rule."""
+    from distributed_learning_tpu.models import TransformerLM
+    from distributed_learning_tpu.parallel.topology import Topology
+    from distributed_learning_tpu.training.trainer import GossipTrainer
+
+    # the public wrapper asks jax.devices() and would take its CPU branch
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        lambda q, k, v, causal=True, sm_scale=None, window=None: fa._attend(
+            q, k, v, sm_scale, causal, BLOCK_Q, BLOCK_K, False, window))
+    n, T, steps, vocab = 4, 1024, 2, 2048
+    model = TransformerLM(
+        vocab_size=vocab, num_layers=2, hidden_size=2048, num_heads=32,
+        head_dim=64, max_len=T, pos_emb="rope", rope_base=1e6,
+        attn_impl="flash", norm="rmsnorm", head_bias=False, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_dense_layers=1, dense_width=6144, mlp="held_experts",
+        num_experts=128, moe_top_k=6, experts_held=8, expert_width=768,
+        shared_expert_width=1536, router_score="sigmoid", route_scale=2.448,
+        route_bias_rate=0.001, shared_expert_gate=False, remat_blocks=True,
+        dtype=jnp.bfloat16)
+    ids = np.zeros((steps, T), np.int32)
+    trainer = GossipTrainer(
+        node_names=list(range(n)), model=model, optimizer="adam",
+        learning_rate=3e-4, error="cross_entropy", weights=Topology.ring(n),
+        train_data={a: (ids, ids) for a in range(n)}, test_data=None,
+        batch_size=1, epoch_len=steps, epoch=1 << 30, mesh=None,
+        dropout=False, seed=0)
+    trainer.engine.mesh = agent_mesh  # read when the program is traced
+    per_agent = NamedSharding(agent_mesh, P("agents"))
+    everywhere = NamedSharding(agent_mesh, P())
+    var = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, T), jnp.int32)))
+    stacked = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype,
+                                       sharding=per_agent), tree)
+    opt = stacked(jax.eval_shape(trainer.tx.init, var["params"]))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = (stacked(var["params"]), stacked(var["batch_stats"]), opt,
+             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=everywhere))
+    data = jax.ShapeDtypeStruct((n, steps, T), jnp.int32, sharding=per_agent)
+    idx = jax.ShapeDtypeStruct((steps, n, 1), jnp.int32, sharding=everywhere)
+    compiled = jax.jit(trainer._epoch_fn, donate_argnums=(0,)).lower(
+        state, data, data, idx).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "flash_bwd_dkv" in text
+    for collective in ("all-gather", "all-to-all", "all-reduce",
+                       "collective-permute"):
+        assert collective + "(" not in text and collective + "-start(" not in text
+    # per chip: one agent's state, not four
+    one_agent = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (var["params"], jax.eval_shape(trainer.tx.init, var["params"]))))
+    assert compiled.memory_analysis().argument_size_in_bytes < 1.05 * one_agent

@@ -23,7 +23,7 @@ import pytest
 
 from tools.graftlint import RULES, lint_file, lint_paths
 from tools.graftlint import jaxpr_audit
-from tools.graftlint.core import REPO_ROOT
+from tools.graftlint.core import DEFAULT_ROOTS, REPO_ROOT
 
 
 def _lint(tmp_path, code, relname="snippet.py", rules=None):
@@ -737,20 +737,38 @@ def test_precommit_clean_tree_exits_zero():
     assert "graftlint:" in out.stderr
 
 
-def test_precommit_fails_on_seeded_violation():
-    seed = os.path.join(REPO_ROOT, "examples", "_precommit_seed_tmp.py")
-    try:
-        with open(seed, "w") as fh:
-            fh.write("import cvxpy\n")
-        out = subprocess.run(
-            ["bash", os.path.join(REPO_ROOT, "tools", "precommit.sh")],
-            cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
-        )
-        assert out.returncode == 1, (out.stdout, out.stderr)
-        assert "banned-import" in out.stdout
-        assert "_precommit_seed_tmp.py" in out.stdout
-    finally:
-        os.unlink(seed)
+def test_precommit_fails_on_seeded_violation(tmp_path):
+    """The violation is planted in a checkout of its own (the scanned
+    roots and ``tools/``, committed in a fresh git repository): a file
+    planted in the shared tree is seen, and then missed, by whatever
+    another test worker lints or audits meanwhile."""
+    import shutil
+
+    root = tmp_path / "checkout"
+    skip = shutil.ignore_patterns("__pycache__", "*.so", ".san_cache")
+    for name in ("tools", *DEFAULT_ROOTS):
+        src = os.path.join(REPO_ROOT, name)
+        if os.path.isdir(src):
+            shutil.copytree(src, root / name, ignore=skip)
+        else:
+            shutil.copy(src, root / name)
+    git = lambda *args: subprocess.run(
+        ["git", "-c", "user.name=t", "-c", "user.email=t@t", *args],
+        cwd=root, check=True, capture_output=True, timeout=60)
+    git("init", "-q")
+    git("add", "-A")
+    git("commit", "-q", "-m", "clean")
+    seed = root / "examples" / "_precommit_seed_tmp.py"
+    seed.write_text("import cvxpy\n")
+    out = subprocess.run(
+        ["bash", str(root / "tools" / "precommit.sh")],
+        cwd=root, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 1, (out.stdout, out.stderr)
+    assert "banned-import" in out.stdout
+    assert "_precommit_seed_tmp.py" in out.stdout
+    assert not os.path.exists(
+        os.path.join(REPO_ROOT, "examples", "_precommit_seed_tmp.py"))
 
 
 # --------------------------------------------------------------------- #
