@@ -24,7 +24,11 @@ Knobs:
   ``mlp="held_experts"`` — hybrid blocks (training path only): Gated
   DeltaNet layers (``models/gated_delta.py``) with every n-th layer
   gated softmax attention, zero-centred RMSNorm, partial rotary, and one
-  chip's share of a many-expert layer (``models/moe.py::HeldExpertsMLP``).
+  chip's share of a many-expert layer (``models/moe.py::HeldExpertsMLP``);
+* ``layers`` — a per-layer description (training path only): SambaY's
+  Mamba, GMU, differential and cross-attention blocks
+  (``models/sambay.py``), each layer with its own window, a layer's
+  memory or K/V handed on to later ones, a tied head.
 """
 
 from __future__ import annotations
@@ -673,11 +677,23 @@ class TransformerLM(nn.Module):
     route_scale: float = 1.0
     route_bias_rate: float | None = None
     shared_expert_gate: bool = True
+    # --- a per-layer description (training path only): a sequence of
+    # dicts, one a block, ``{"mixer": "mamba" | "gmu" | "attention" |
+    # "cross_attention", "depth": the layer's index in the published
+    # stack, "window": a sliding window (attention), "memory": "write"
+    # (a Mamba layer whose y the GMUs read), "kv": "write" (the attention
+    # layer whose K/V the cross-attention layers read)}``: the SambaY
+    # stack of models/sambay.py, LayerNorm'd blocks with a SwiGLU of
+    # dense_width, no positional encoding, the head tied to the
+    # embedding.  num_layers is then not read.
+    layers: Any = None
 
     @property
     def layer_types(self) -> tuple:
         """Each layer's token mixer, ``"full_attention"`` or
-        ``"linear_attention"``."""
+        ``"linear_attention"``; with ``layers``, each one's ``"mixer"``."""
+        if self.layers is not None:
+            return tuple(spec["mixer"] for spec in self.layers)
         n = self.full_attention_interval
         return tuple(
             "linear_attention" if n and (i + 1) % n else "full_attention"
@@ -694,6 +710,7 @@ class TransformerLM(nn.Module):
             and self.norm == "layernorm" and self.hidden_size is None
             and self.rope_fraction == 1.0
             and self.kv_lora_rank is None and not self.num_dense_layers
+            and self.layers is None
         )
 
     def require_uniform(self, what: str) -> None:
@@ -716,6 +733,8 @@ class TransformerLM(nn.Module):
             )
         if self.decode:
             self.require_uniform("decode")
+        if self.layers is not None:
+            return self._described(tokens)
         d_model = self.hidden_size or self.num_heads * self.head_dim
         T = tokens.shape[1]
         x = nn.Embed(self.vocab_size, d_model, dtype=self.dtype)(tokens)
@@ -817,6 +836,37 @@ class TransformerLM(nn.Module):
         logits = nn.Dense(self.vocab_size, use_bias=self.head_bias,
                           dtype=self.dtype)(x)
         return logits.astype(jnp.float32)
+
+    def _described(self, tokens):
+        """The stack ``layers`` describes (its comment above): embedding,
+        the blocks, a final LayerNorm and the tied head."""
+        if (self.attn_impl not in ("full", "flash") or self.pos_emb != "none"
+                or self.dropout_rate):
+            raise ValueError("a described stack runs attn_impl full|flash "
+                             "and takes pos_emb='none', no dropout")
+        d = self.hidden_size or self.num_heads * self.head_dim
+        embed = nn.Embed(self.vocab_size, d, dtype=self.dtype)
+        x = embed(tokens)
+        made = set()
+        for i, spec in enumerate(self.layers):
+            need = {"gmu": "memory", "cross_attention": "kv"}.get(spec["mixer"])
+            if need and need not in made:
+                raise ValueError(f"layer {i} ({spec['mixer']}) reads the "
+                                 f"{need} of an earlier layer; none writes it")
+            made |= {k for k in ("memory", "kv") if spec.get(k) == "write"}
+        from distributed_learning_tpu.models.sambay import SambaYBlock
+
+        block_cls = nn.remat(SambaYBlock) if self.remat_blocks else SambaYBlock
+        shared = {}
+        for i, spec in enumerate(self.layers):
+            x, shared = block_cls(
+                dict(spec), self.num_heads,
+                self.num_kv_heads or self.num_heads, self.head_dim,
+                self.dense_width, attn_impl=self.attn_impl,
+                norm_eps=self.norm_eps, dtype=self.dtype, name=f"layer_{i}",
+            )(x, shared)
+        x = nn.LayerNorm(epsilon=self.norm_eps, dtype=self.dtype)(x)
+        return embed.attend(x).astype(jnp.float32)
 
 
 def generate(

@@ -127,6 +127,10 @@ class Topology:
 
     @staticmethod
     def complete(n: int) -> "Topology":
+        """Every pair of the ``n`` agents an edge; ``n = 1`` is the one
+        agent alone, no edge, Metropolis ``W = [[1]]``."""
+        if n == 1:
+            return Topology(n_agents=1, edges=(), tokens=(0,))
         return Topology.from_edges([(i, j) for i in range(n) for j in range(i + 1, n)])
 
     @staticmethod

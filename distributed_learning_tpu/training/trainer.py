@@ -992,8 +992,15 @@ class GossipTrainer:
                     jax.vmap(self.tx.init)(params))
 
         if mesh is None:
-            params, batch_stats, opt_state = self.engine.shard(
-                replicas(params0, bs0))
+            # The replicas, then the init's own tree let go of, then the
+            # optimizer's slots in one program: made eagerly with the init
+            # still held, a state of one 697 M-parameter agent was twice
+            # on the chip at once (16.7 of its 16.9 GB, PERF.md PR 37).
+            params = stack(params0)
+            batch_stats = stack(bs0) if bs0 is not None else None
+            del variables, params0, bs0
+            params, batch_stats, opt_state = self.engine.shard((
+                params, batch_stats, jax.jit(jax.vmap(self.tx.init))(params)))
         else:
             # Every stacked leaf is made where its agent lives (one device
             # per agent): optimizer slots and BatchNorm stats too, not

@@ -320,3 +320,60 @@ def test_the_sharded_step_with_kernels_compiles_one_agent_a_chip(
     one_agent = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
         (var["params"], jax.eval_shape(trainer.tx.init, var["params"]))))
     assert compiled.memory_analysis().argument_size_in_bytes < 1.05 * one_agent
+
+
+def test_the_phi4flash_epoch_program_compiles_for_one_v5e(one_chip, cache_off,
+                                                          monkeypatch):
+    """The Phi-4-mini-flash cell's epoch program at its widths and T 8,192:
+    one agent, 697 M parameters, 8 Adam steps under ``lax.scan``, the
+    selective scan's kernels and the flash kernels at a query/key of 64
+    against a value of 128 (with and without the window).  The compiler
+    refuses a program that does not fit the chip's HBM; with
+    ``remat_blocks`` it reports 15.68 GiB (PERF.md, PR 37)."""
+    import json
+
+    from distributed_learning_tpu.models import TransformerLM
+    from distributed_learning_tpu.ops import selective_scan as ss
+    from distributed_learning_tpu.parallel.topology import Topology
+    from distributed_learning_tpu.training.trainer import GossipTrainer
+
+    # the public wrappers ask jax.devices() and would take their CPU branch
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        lambda q, k, v, causal=True, sm_scale=None, window=None: fa._attend(
+            q, k, v, sm_scale, causal, BLOCK_Q, BLOCK_K, False, window))
+    monkeypatch.setattr(ss, "_on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "phi4-mini-flash-solo1.json"),
+              encoding="utf-8") as fh:
+        kw = dict(json.load(fh)["model"]["kwargs"])
+    kw["dtype"] = jnp.dtype(kw["dtype"])
+    T, steps = 8192, 8
+    model = TransformerLM(**kw)
+    ids = np.zeros((steps, T), np.int32)
+    trainer = GossipTrainer(
+        node_names=[0], model=model, optimizer="adam", learning_rate=3e-4,
+        error="cross_entropy", weights=Topology.complete(1),
+        train_data={0: (ids, ids)}, test_data=None, batch_size=1,
+        epoch_len=steps, epoch=1 << 30, mesh=None, dropout=False, seed=0)
+    var = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), jnp.zeros((1, T), jnp.int32)))
+    one = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        (1,) + a.shape, a.dtype, sharding=one_chip), tree)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = (one(var["params"]), one(var.get("batch_stats", {})),
+             one(jax.eval_shape(trainer.tx.init, var["params"])),
+             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip))
+    data = jax.ShapeDtypeStruct((1, steps, T), jnp.int32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((steps, 1, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(trainer._epoch_fn, donate_argnums=(0,)).lower(
+        state, data, data, idx).compile()
+    text = compiled.as_text()
+    for kernel in ("mamba_scan_fwd", "mamba_scan_bwd", "flash_fwd",
+                   "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+    # the state, f32 weights and Adam's two moments, is the argument
+    n = sum(a.size for a in jax.tree.leaves(var["params"]))
+    assert n == 697_073_792
+    assert compiled.memory_analysis().argument_size_in_bytes < 12.01 * n
