@@ -7,11 +7,12 @@ tabular/image nets); ``models/transformer.py::_Block`` places it where
 to q, k (``num_key_heads`` x ``key_head_dim``), v and the output gate z
 (``num_value_heads`` x ``value_head_dim``), one to the per-head write
 strength ``b`` and decay input ``a``; a causal depthwise convolution with
-SiLU over the concatenated q, k, v; q, k repeated up to the value heads
-and L2-normalised, q scaled by ``key_head_dim ** -0.5``; the gated delta
-rule (``ops/gated_delta.py``) with ``g = -exp(A_log) softplus(a +
-dt_bias)`` and ``beta = sigmoid(b)``; then RMSNorm of each head's output
-times a learned weight times ``silu(z)``, and the output projection.
+SiLU over the concatenated q, k, v; q, k L2-normalised, q scaled by
+``key_head_dim ** -0.5``; the gated delta rule (``ops/gated_delta.py``;
+value head ``h`` reads key head ``h // (num_value_heads /
+num_key_heads)``) with ``g = -exp(A_log) softplus(a + dt_bias)`` and
+``beta = sigmoid(b)``; then RMSNorm of each head's output times a learned
+weight times ``silu(z)``, and the output projection.
 
 The ``jax.named_scope`` blocks (``gdn_proj``, ``gdn_conv``, ``gdn_rule``,
 ``gdn_out``) name the layer's parts in a profile
@@ -104,8 +105,7 @@ class GatedDeltaNet(nn.Module):
             v = qkv[..., 2 * key_dim:].reshape(B, T, Hv, Dv)
             q = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + self.eps)
             k = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + self.eps)
-            q = jnp.repeat(q * Dk ** -0.5, Hv // Hk, axis=2)
-            k = jnp.repeat(k, Hv // Hk, axis=2)
+            q = q * Dk ** -0.5
             beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
             g = -jnp.exp(A_log) * jax.nn.softplus(
                 ba[..., Hv:].astype(f32) + dt_bias

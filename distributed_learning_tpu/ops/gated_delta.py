@@ -23,14 +23,21 @@ state.
 Neither sequential part runs on a slow path.  The solve is block forward
 substitution written as batched matrix products
 (:func:`_unit_lower_solve`: no triangular-solve custom call, its
-transpose two products more).  The steps over chunks are a ``lax.scan``
-under autodiff wherever the program runs, and on a TPU, at head sizes
-that fill the 128 lanes, a Pallas kernel pair under ``jax.custom_vjp``
-(``gdn_scan_fwd`` / ``gdn_scan_bwd``: grid (blocks of heads, chunks), the
-states in VMEM along the chunk axis, one chunk a grid step) that the scan
-is the oracle of.  What tracing and lowering cost does not grow with
-``T``: the kernel bodies are one chunk's arithmetic, reached once a
-direction (``tests/test_gated_delta_kernel.py`` holds both to that).
+transpose two products more), and the steps over chunks are a
+``lax.scan`` under autodiff: the rule in XLA, wherever the program runs.
+On a TPU, at head sizes that fill the 128 lanes, the whole rule is
+instead a Pallas kernel pair under ``jax.custom_vjp`` (``gdn_scan_fwd`` /
+``gdn_scan_bwd``: grid (blocks of heads, chunks), the states in VMEM along
+the chunk axis, one chunk a grid step) that the XLA form is the oracle
+of: a grid step reads its chunk's q, k (at the key heads), v, g's
+cumulative sum and beta once, and builds the decay matrix, the masked
+products, the inverse and the solve's result in VMEM before the step
+against the state; backward it builds them again from the same inputs
+and the chunk's entry state, and carries the cotangents back through
+all of it.  No (chunk, ...) f32 array of the preparation reaches HBM.
+What tracing and lowering cost does not grow with ``T``: the kernel
+bodies are one chunk's arithmetic, reached once a direction
+(``tests/test_gated_delta_kernel.py`` holds both to that).
 """
 
 from __future__ import annotations
@@ -49,7 +56,7 @@ __all__ = ["gated_delta_rule", "gated_delta_recurrence"]
 _hi = functools.partial(jnp.matmul, precision="highest")
 
 
-def _unit_lower_inverse(strict):
+def _unit_lower_inverse(strict, index=None):
     """``(I + strict)^-1`` for a strictly lower triangular ``strict``
     (..., C, C), built from the diagonal out by block forward substitution
     written as batched matrix products.
@@ -64,12 +71,14 @@ def _unit_lower_inverse(strict):
     2)`` products whatever the number of chunks, heads or agents.
     """
     C = strict.shape[-1]
-    # which block of size s an index lies in: masks made with numpy are
-    # constants of the traced program, not equations of it
-    block = lambda s: np.arange(C) // s
-    same = lambda s: block(s)[:, None] == block(s)[None, :]
-    pairs = lambda s: same(2 * s) & ~same(s) & np.tri(C, k=-1, dtype=bool)
-    T = jnp.eye(C, dtype=strict.dtype) - jnp.where(pairs(1), strict, 0.0)
+    # row and column index: numpy's are constants of the traced program,
+    # not equations of it; a kernel hands in iotas (``index``).  Two
+    # indices lie in one block of size s (a power of two) where they agree
+    # above its bits.
+    row, col = np.indices((C, C)) if index is None else index
+    same = lambda s: (row & -s) == (col & -s)
+    pairs = lambda s: same(2 * s) & ~same(s) & (row > col)
+    T = (row == col).astype(strict.dtype) - jnp.where(pairs(1), strict, 0.0)
     s = 2
     while s < C:  # log2(C / 2) levels: 5 at the chunk of 64
         T = T - jnp.where(pairs(s), _hi(_hi(T, strict), T), 0.0)
@@ -115,26 +124,39 @@ def _on_tpu() -> bool:
 
 
 def _scan_kernel_runs(C: int, Dk: int, Dv: int) -> bool:
-    """Whether the chunk scan runs as the Pallas kernel pair: on a TPU,
-    at head sizes that fill the 128 lanes and a chunk that fills the 8
-    sublanes (what ``flash_attention`` asks of its blocks).  Everything
-    else takes the ``lax.scan`` inside :func:`gated_delta_rule`."""
+    """Whether the rule runs as the Pallas kernel pair: on a TPU, at head
+    sizes that fill the 128 lanes and a chunk that fills the 8 sublanes
+    (what ``flash_attention`` asks of its blocks).  Everything else takes
+    the ``lax.scan`` inside :func:`gated_delta_rule`."""
     return _on_tpu() and Dk % 128 == 0 and Dv % 128 == 0 and C % 8 == 0
 
 
-#: heads a grid step of the scan kernels takes at head size 128 (fewer
-#: where ``B * H`` has no such divisor, or the heads are larger): their
-#: products are independent, so the four matrix units overlap them, and a
-#: step's fixed cost is paid once for all.  Eight (128, 128) f32 states and
-#: their blocks, double-buffered, fit the 16 MB of VMEM a kernel may use.
-_HEADS_PER_STEP = 8
+#: VMEM a grid step of the kernels may hold (of the 128 MiB a v5e core has)
+_VMEM_LIMIT = 32 * 2**20
+#: what one head of a grid step holds there, in (C, 128) f32 tiles, beside
+#: five (Dk, Dv) f32 states (the scratch and the double-buffered blocks of
+#: the entry states): the backward body's live (C, C) and (C, D) arrays
+#: and blocks (a 64-wide row pads to the 128 lanes), as the compiler for
+#: a v5e placed them at the Qwen3-Next cell's shape (72 MiB for 32 heads
+#: of 128 at the highest precision, 44 MiB for 8 of 256)
+_TILES_PER_HEAD = 62
+
+
+def _heads_per_step(BH: int, r: int, C: int, Dk: int, Dv: int) -> int:
+    """Heads a grid step takes: the most that divide ``BH``, come in whole
+    groups of the ``r`` value heads that share a key head, and fit
+    ``_VMEM_LIMIT`` (their products are independent, so the matrix units
+    overlap them, and a step's fixed cost is paid once for all)."""
+    per_head = 4 * (_TILES_PER_HEAD * C * max(Dk, Dv, 128) + 5 * Dk * Dv)
+    most = max(r, _VMEM_LIMIT // per_head)
+    return max(h for h in range(r, most + 1, r) if BH % h == 0)
 
 
 def _kernel_dot(precision):
     """The kernels' matrix product over a block of heads, ``dot(a, b, ca,
     cb)`` contracting axis ``ca`` of ``a`` with ``cb`` of ``b`` (axis 0 is
     the heads), f32 out.  At the default precision the operands are
-    rounded to bf16 first: what XLA's default does to the scan's f32
+    rounded to bf16 first: what XLA's default does to the rule's f32
     products on a TPU, said out loud because Mosaic would otherwise run
     them in several passes."""
     def dot(a, b, ca=2, cb=1):
@@ -147,161 +169,257 @@ def _kernel_dot(precision):
     return dot
 
 
-def _scan_fwd_kernel(u_ref, w_ref, qk_ref, q_ref, k_ref, a_ref, o_ref,
+class _Chunk:
+    """One chunk's preparation for a block of heads, in VMEM: what
+    :func:`gated_delta_rule` computes in XLA before its ``step``, from the
+    blocks of the chunk's q, k (f32, at the key heads), v, and the rows of
+    g's cumulative sum and of beta (``(heads, 1, C)``).  Every product is
+    rounded where the XLA path rounds it: ``k k^T`` and ``q k^T`` at the
+    rule's ``precision``, the solve at the highest."""
+
+    def __init__(self, q_ref, k_ref, v_ref, gc_ref, beta_ref, dot):
+        gc_row = gc_ref[...]
+        C = gc_row.shape[-1]
+        self.r = v_ref.shape[0] // q_ref.shape[0]  # value heads a key head
+        q, k = (jnp.repeat(x[...], self.r, axis=0) for x in (q_ref, k_ref))
+        v = v_ref[...].astype(jnp.float32)
+        # index iotas: a numpy constant would be an array the kernel captures
+        self.row, self.col = (jax.lax.broadcasted_iota(jnp.int32, (C, C), d)
+                              for d in (0, 1))
+        self.eye = self.row == self.col
+        self.lower = self.row >= self.col
+        self.q, self.k, self.v = q, k, v
+        gc, self.beta = self.column(gc_row), self.column(beta_ref[...])
+        # exp(gc_i - gc_j) for i >= j, the upper triangle kept out of exp
+        self.decay = jnp.where(self.lower, jnp.exp(
+            jnp.where(self.lower, gc - gc_row, 0.0)), 0.0)
+        self.e = jnp.exp(gc)
+        self.k_beta = k * self.beta
+        self.kk = dot(self.k_beta, k, 2, 2)
+        self.strict = jnp.where(self.row > self.col, self.kk * self.decay, 0.0)
+        self.T = _unit_lower_inverse(self.strict, (self.row, self.col))
+        self.u = _hi(self.T, v * self.beta)
+        self.w = _hi(self.T, self.k_beta * self.e)
+        self.qk0 = dot(q, k, 2, 2)
+        self.qk = self.qk0 * self.decay
+        self.q_in = q * self.e
+        self.g_end = jnp.sum(jnp.where(self.col[:1] == C - 1, gc_row, 0.0),
+                             axis=2, keepdims=True)  # (heads, 1, 1)
+        self.a = jnp.exp(self.g_end)
+        self.f = jnp.exp(self.g_end - gc)
+        self.k_out = k * self.f
+
+    def column(self, row):
+        """(heads, 1, C) -> (heads, C, 1), exactly: a sum of one entry."""
+        return jnp.sum(jnp.where(self.eye, row, 0.0), axis=2, keepdims=True)
+
+    def row_of(self, column):
+        """(heads, C, 1) -> (heads, 1, C), the same way."""
+        return jnp.sum(jnp.where(self.eye, column, 0.0), axis=1, keepdims=True)
+
+
+def _rule_fwd_kernel(q_ref, k_ref, v_ref, gc_ref, beta_ref, o_ref,
                      states_ref, S, *, precision):
-    """One chunk of a block of heads: ``step`` of :func:`gated_delta_rule`,
-    the states in VMEM from the heads' first chunk to their last."""
+    """One chunk of a block of heads: the preparation and ``step`` of
+    :func:`gated_delta_rule`, the states in VMEM from the heads' first
+    chunk to their last."""
     dot = _kernel_dot(precision)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         S[...] = jnp.zeros_like(S)
 
+    c = _Chunk(q_ref, k_ref, v_ref, gc_ref, beta_ref, dot)
     s = S[...]
     if states_ref is not None:  # the state the chunk entered with
         states_ref[...] = s
-    new = u_ref[...] - dot(w_ref[...], s)
-    o_ref[...] = dot(q_ref[...], s) + dot(qk_ref[...], new)
-    S[...] = s * a_ref[...] + dot(k_ref[...], new, 1, 1)
+    new = c.u - dot(c.w, s)
+    o_ref[...] = dot(c.q_in, s) + dot(c.qk, new)
+    S[...] = s * c.a + dot(c.k_out, new, 1, 1)
 
 
-def _scan_bwd_kernel(u_ref, w_ref, qk_ref, q_ref, k_ref, a_ref, states_ref,
-                     do_ref, du_ref, dw_ref, dqk_ref, dq_ref, dk_ref, da_ref,
-                     dS, *, precision):
+def _rule_bwd_kernel(q_ref, k_ref, v_ref, gc_ref, beta_ref, states_ref,
+                     do_ref, dq_ref, dk_ref, dv_ref, dgc_ref, dbeta_ref, dS,
+                     *, precision):
     """The transpose of one chunk, the grid walking the chunks from the
-    last to the first with the states' cotangent in VMEM.  ``new`` is
-    computed again from the entry state the forward wrote out."""
+    last to the first with the states' cotangent in VMEM: the preparation
+    built again from the inputs, ``step``'s transpose on the entry state
+    the forward wrote out, then the preparation's, ending in each token's
+    dq, dk, dv, d beta and d gc (the caller's cumulative sum takes the
+    last to dg)."""
     dot = _kernel_dot(precision)
+    sum_lanes = lambda x: jnp.sum(x, axis=2, keepdims=True)
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         dS[...] = jnp.zeros_like(dS)
 
+    c = _Chunk(q_ref, k_ref, v_ref, gc_ref, beta_ref, dot)
     s, ds, do = states_ref[...], dS[...], do_ref[...]
-    w, q = w_ref[...], q_ref[...]
-    new = u_ref[...] - dot(w, s)
-    dnew = dot(qk_ref[...], do, 1, 1) + dot(k_ref[...], ds)
-    du_ref[...] = dnew
-    dw_ref[...] = -dot(dnew, s, 2, 2)
-    dqk_ref[...] = dot(do, new, 2, 2)
-    dq_ref[...] = dot(do, s, 2, 2)
-    dk_ref[...] = dot(new, ds, 2, 2)
-    # d exp(g_end) = sum(s * ds): the rows here, the lanes by the caller
-    da_ref[...] = jnp.sum(s * ds, axis=1, keepdims=True)
-    dS[...] = ds * a_ref[...] + dot(q, do, 1, 1) - dot(w, dnew, 1, 1)
+    # step's transpose
+    new = c.u - dot(c.w, s)
+    du = dot(c.qk, do, 1, 1) + dot(c.k_out, ds)
+    dw = -dot(du, s, 2, 2)
+    dqk = dot(do, new, 2, 2)
+    dq_in = dot(do, s, 2, 2)
+    dk_out = dot(new, ds, 2, 2)
+    da = jnp.sum(sum_lanes(s * ds), axis=1, keepdims=True)
+    dS[...] = ds * c.a + dot(c.q_in, do, 1, 1) - dot(c.w, du, 1, 1)
+    # the solve's: d rhs = T^T dX, d strict = -d rhs X^T below the diagonal
+    hi = _kernel_dot("highest")
+    d_rv, d_rw = hi(c.T, du, 1, 1), hi(c.T, dw, 1, 1)
+    d_strict = jnp.where(c.row > c.col, -(hi(d_rv, c.u, 2, 2) +
+                                           hi(d_rw, c.w, 2, 2)), 0.0)
+    # the decay-masked products
+    dkk = d_strict * c.decay
+    dqk0 = dqk * c.decay
+    d_decay = d_strict * c.kk + dqk * c.qk0
+    dk_beta = d_rw * c.e + dot(dkk, c.k)
+    dq = dot(dqk0, c.k) + dq_in * c.e
+    dk = (dot(dkk, c.k_beta, 1, 1) + dot(dqk0, c.q, 1, 1) + dk_out * c.f
+          + dk_beta * c.beta)
+    dv = d_rv * c.beta
+    dbeta = sum_lanes(d_rv * c.v) + sum_lanes(dk_beta * c.k)
+    # the exponents: exp(gc) (q_in, the right-hand side), exp(g_end - gc)
+    # (k_out), exp(g_end) (the state's decay), exp(gc_i - gc_j) (decay)
+    de = sum_lanes(dq_in * c.q) + sum_lanes(d_rw * c.k_beta)
+    df = sum_lanes(dk_out * c.k) * c.f
+    d_end = jnp.sum(df, axis=1, keepdims=True) + da * c.a
+    d_diff = jnp.where(c.lower, d_decay * c.decay, 0.0)
+    dgc = (c.row_of(de * c.e - df + sum_lanes(d_diff))
+           - jnp.sum(d_diff, axis=1, keepdims=True)
+           + jnp.where(c.col[:1] == c.col.shape[1] - 1, d_end, 0.0))
+    key_heads = lambda x: x.reshape((-1, c.r) + x.shape[1:]).sum(1)
+    dq_ref[...] = key_heads(dq)
+    dk_ref[...] = key_heads(dk)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+    dgc_ref[...] = dgc
+    dbeta_ref[...] = c.row_of(dbeta)
 
 
-#: the kernels' common operands, by the name of the block spec each goes by
-_OPERANDS = ("u", "w", "qk", "q", "k", "a")
+def _rule_call(kernel, name, ins, outs, reverse, precision, interpret):
+    """``pallas_call`` over the grid (blocks of heads, chunks): the blocks
+    in parallel, a block's chunks in order (from the last backwards if
+    ``reverse``) with its (Dk, Dv) f32 states in VMEM across them.
+    ``ins`` and ``outs`` (shapes) are the rule's arrays laid out heads
+    first: (B*H, T, D) a chunk's (C, D) block, (B*H, N, ...) a chunk's
+    entry; q and k (and their cotangents) have the key heads, ``ins[2]``
+    (v) and the rest the value heads."""
+    q, v, gc = ins[0], ins[2], ins[3]
+    (BH, _, Dv), Dk, (N, _, C) = v.shape, q.shape[-1], gc.shape[1:]
+    heads = _heads_per_step(BH, BH // q.shape[0], C, Dk, Dv)
+    at = (lambda n: N - 1 - n) if reverse else (lambda n: n)
 
+    def spec(x):
+        hb = heads * x.shape[0] // BH  # the key heads of a block: fewer
+        if len(x.shape) == 3:
+            return pl.BlockSpec((hb, C, x.shape[2]),
+                                lambda b, n: (b, at(n), 0))
+        return pl.BlockSpec((hb, None) + tuple(x.shape[2:]),
+                            lambda b, n: (b, at(n), 0, 0))
 
-def _scan_call(kernel, name, ins, outs, reverse, precision, interpret):
-    """``pallas_call`` over the grid (blocks of heads, chunks) of arrays
-    laid out (N, B*H, ...): the blocks in parallel, a block's chunks in
-    order (from the last backwards if ``reverse``) with its (Dk, Dv) f32
-    states in VMEM across them.  ``ins``: (spec name, array), the first
-    two ``u`` and ``w``; ``outs``: spec names, all f32.  A grid step takes
-    as many heads as divide ``B * H`` and keep the states within
-    ``_HEADS_PER_STEP`` of (128, 128)."""
-    (_, u), (_, w) = ins[:2]
-    N, BH, C, Dv = u.shape
-    Dk = w.shape[-1]
-    most = max(1, _HEADS_PER_STEP * 128 * 128 // (Dk * Dv))
-    heads = max(h for h in range(1, most + 1) if BH % h == 0)
-    at = (lambda bh, n: (N - 1 - n, bh, 0, 0)) if reverse else (
-        lambda bh, n: (n, bh, 0, 0))
-    blocks = dict(u=(C, Dv), w=(C, Dk), qk=(C, C), q=(C, Dk), k=(C, Dk),
-                  a=(1, 1), states=(Dk, Dv), colsum=(1, Dv))
-    spec = lambda s: pl.BlockSpec((None, heads) + blocks[s], at)
     return pl.pallas_call(
         functools.partial(kernel, precision=precision),
         grid=(BH // heads, N),
-        in_specs=[spec(s) for s, _ in ins],
-        out_specs=[spec(s) for s in outs],
-        out_shape=[jax.ShapeDtypeStruct((N, BH) + blocks[s], jnp.float32)
-                   for s in outs],
+        in_specs=[spec(x) for x in ins],
+        out_specs=[spec(x) for x in outs],
+        out_shape=outs,
         scratch_shapes=[pltpu.VMEM((heads, Dk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
         name=name,
-    )(*(x for _, x in ins))
+    )(*ins)
 
 
-def _scan_operands(u, w, qk, q_in, k_out, g_end, precision):
-    """The kernels' common operands, in ``_OPERANDS``' order: (N, B, H,
-    ...) -> (N, B*H, ...), the chunk's decay as a (1, 1) block.  What a
-    kernel only ever multiplies at the default precision goes in as the
-    bf16 it would round to: half the bytes to write, keep for the backward
-    pass and read."""
-    N, B, H = g_end.shape
-    flat = lambda x: x.reshape((N, B * H) + x.shape[3:])
-    factor = lambda x: flat(x.astype(jnp.bfloat16) if precision is None else x)
-    return [flat(u), factor(w), factor(qk), factor(q_in), factor(k_out),
-            jnp.exp(g_end).reshape(N, B * H, 1, 1)]
-
-
-def _scan_fwd_call(operands, precision, interpret, with_states):
-    kernel = _scan_fwd_kernel
-    if not with_states:  # the primal alone writes no residual
+def _rule_fwd_call(ins, precision, interpret, with_states):
+    q, v, gc = ins[0], ins[2], ins[3]
+    (BH, T, Dv), Dk, N = v.shape, q.shape[-1], gc.shape[1]
+    f32 = jnp.float32
+    outs = [jax.ShapeDtypeStruct((BH, T, Dv), f32)]
+    kernel = _rule_fwd_kernel
+    if with_states:
+        outs.append(jax.ShapeDtypeStruct((BH, N, Dk, Dv), f32))
+    else:  # the primal alone writes no residual
         def kernel(*refs, precision):
-            _scan_fwd_kernel(*refs[:7], None, *refs[7:], precision=precision)
-    return _scan_call(
-        kernel, "gdn_scan_fwd", list(zip(_OPERANDS, operands)),
-        ["u", "states"] if with_states else ["u"], False, precision,
-        interpret)
+            _rule_fwd_kernel(*refs[:6], None, *refs[6:], precision=precision)
+    return _rule_call(kernel, "gdn_scan_fwd", ins, outs, False, precision,
+                      interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _chunk_scan(u, w, qk, q_in, k_out, g_end, precision, interpret):
-    """The scan over chunks of :func:`gated_delta_rule` as a kernel pair:
-    same operands (N, B, H, C, ...) and ``g_end`` (N, B, H), same ``o``
-    (N, B, H, C, Dv).  The residuals are the kernels' own operands and
-    each chunk's entry state, what the scan's transpose keeps."""
-    operands = _scan_operands(u, w, qk, q_in, k_out, g_end, precision)
-    (o,) = _scan_fwd_call(operands, precision, interpret, with_states=False)
-    return o.reshape(u.shape)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule_kernels(q, k, v, gc, beta, precision, interpret):
+    """The rule as a kernel pair on arrays laid out heads first: q, k
+    (B*Hk, T, Dk) f32, v (B*H, T, Dv), g's cumulative sum within each
+    chunk and beta (B*H, N, 1, C) f32; ``o`` (B*H, T, Dv) f32.  The
+    residuals are the inputs and each chunk's entry state."""
+    (o,) = _rule_fwd_call((q, k, v, gc, beta), precision, interpret, False)
+    return o
 
 
-def _chunk_scan_fwd(u, w, qk, q_in, k_out, g_end, precision, interpret):
-    operands = _scan_operands(u, w, qk, q_in, k_out, g_end, precision)
-    o, states = _scan_fwd_call(operands, precision, interpret,
-                               with_states=True)
-    return o.reshape(u.shape), (operands, states, g_end)
+def _rule_kernels_fwd(q, k, v, gc, beta, precision, interpret):
+    ins = (q, k, v, gc, beta)
+    o, states = _rule_fwd_call(ins, precision, interpret, True)
+    return o, (ins, states)
 
 
-def _chunk_scan_bwd(precision, interpret, res, do):
-    operands, states, g_end = res
-    N, B, H = g_end.shape
+def _rule_kernels_bwd(precision, interpret, res, do):
+    ins, states = res
     do = do.astype(jnp.bfloat16) if precision is None else do
-    ins = list(zip(_OPERANDS, operands)) + [
-        ("states", states), ("u", do.reshape(operands[0].shape))]
-    *grads, da = _scan_call(
-        _scan_bwd_kernel, "gdn_scan_bwd", ins,
-        ["u", "w", "qk", "q", "k", "colsum"], True, precision, interpret)
-    grads = [dx.reshape((N, B, H) + dx.shape[2:]) for dx in grads]
-    return (*grads, jnp.exp(g_end) * da.sum((-2, -1)).reshape(N, B, H))
+    outs = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in ins]
+    return tuple(_rule_call(
+        _rule_bwd_kernel, "gdn_scan_bwd", (*ins, states, do), outs, True,
+        precision, interpret))
 
 
-_chunk_scan.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
+_rule_kernels.defvjp(_rule_kernels_fwd, _rule_kernels_bwd)
+
+
+def _rule_on_tpu(q, k, v, g, beta, C, precision, interpret=False):
+    """:func:`gated_delta_rule` where the kernels run: one XLA pass lays
+    q, k, v, beta and g's cumulative sum within each chunk out heads
+    first, in their own dtypes and at their own heads, and one lays ``o``
+    back."""
+    B, T, H, Dv = v.shape
+    pad = -T % C
+
+    def heads_first(x):  # (B, T, H, ...) -> (B*H, T + pad, ...)
+        if pad:
+            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = jnp.moveaxis(x, 2, 1)
+        return x.reshape((-1,) + x.shape[2:])
+
+    def rows(x):  # (B, T, H) -> (B*H, N, 1, C)
+        return heads_first(x).reshape(B * H, -1, 1, C)
+
+    gc = jnp.cumsum(rows(g.astype(jnp.float32)), axis=-1)
+    o = _rule_kernels(heads_first(q), heads_first(k), heads_first(v), gc,
+                      rows(beta.astype(jnp.float32)), precision, interpret)
+    return jnp.moveaxis(o.reshape(B, H, T + pad, Dv), 1, 2)[:, :T]
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, precision=None):
     """Chunked gated delta rule (arXiv:2412.06464 §3.3).
 
-    ``q``, ``k``: (B, T, H, Dk), already normalised and scaled by the
-    caller; ``v``: (B, T, H, Dv); ``g`` (log decay, <= 0) and ``beta``
-    (write strength): (B, T, H).  Returns ``o``: (B, T, H, Dv) in f32.
-    The state starts at zero and lives in f32 whatever the inputs' dtype;
-    ``precision`` is that of the rule's matrix products (``None``: the
-    backend's default, on a TPU one bf16 pass with f32 accumulation).
-    ``T`` need not divide by ``chunk``: the tail is padded with tokens
-    that neither decay nor write (``g = 0``, ``beta = 0``, ``k = 0``).
+    ``q``, ``k``: (B, T, Hk, Dk), already normalised and scaled by the
+    caller; ``v``: (B, T, H, Dv), where ``Hk`` divides ``H`` (value head
+    ``h`` reads key head ``h // (H / Hk)``); ``g`` (log decay, <= 0) and
+    ``beta`` (write strength): (B, T, H).  Returns ``o``: (B, T, H, Dv)
+    in f32.  The state starts at zero and lives in f32 whatever the
+    inputs' dtype; ``precision`` is that of the rule's matrix products
+    (``None``: the backend's default, on a TPU one bf16 pass with f32
+    accumulation).  ``T`` need not divide by ``chunk``: the tail is
+    padded with tokens that neither decay nor write (``g = 0``, ``beta =
+    0``, ``k = 0``).
     """
-    B, T, H, Dk = q.shape
-    Dv = v.shape[-1]
+    B, T, Hk, Dk = q.shape
+    H, Dv = v.shape[2:]
     C = int(chunk)
+    if _scan_kernel_runs(C, Dk, Dv):
+        return _rule_on_tpu(q, k, v, g, beta, C, precision)
+    q, k = (jnp.repeat(x, H // Hk, axis=2) for x in (q, k))
     pad = -T % C
     f32 = jnp.float32
 
@@ -347,11 +465,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64, precision=None):
         )
         return S, o
 
-    if _scan_kernel_runs(C, Dk, Dv):
-        o = _chunk_scan(u, w, qk, q_in, k_out, g_end, precision, False)
-    else:
-        S0 = jnp.zeros((B, H, Dk, Dv), f32)
-        _, o = jax.lax.scan(step, S0, (u, w, qk, q_in, k_out, g_end))
+    S0 = jnp.zeros((B, H, Dk, Dv), f32)
+    _, o = jax.lax.scan(step, S0, (u, w, qk, q_in, k_out, g_end))
     o = jnp.moveaxis(o.swapaxes(0, 1), 2, 3).reshape(B, T + pad, H, Dv)
     return o[:, :T]
 

@@ -161,21 +161,27 @@ def test_flash_compiles_at_the_gpt2_cell(one_chip, cache_off, variant):
 @pytest.mark.parametrize("precision, D", [
     (None, 128), ("highest", 128), (None, 256)],
     ids=["default", "highest", "head256"])
-def test_gdn_scan_kernels_compile_for_v5e(one_chip, cache_off, precision, D,
-                                          variant):
-    """The delta rule's chunk-scan kernel pair at the Qwen3-Next cell's
-    shape: 2 agents vmapped over 64 chunks of 64 tokens, 32 heads of 128
-    (the rule itself asks ``jax.devices()`` and would take its scan); and
-    at a head size of 256, where a grid step takes fewer heads."""
-    agents, N, B, H, C = 2, 64, 1, 32, 64
-    sds = lambda *s: jax.ShapeDtypeStruct(
-        (agents, N, B, H) + s, jnp.float32, sharding=one_chip)
-    shapes = (sds(C, D), sds(C, D), sds(C, C), sds(C, D), sds(C, D), sds())
-    scan = jax.vmap(lambda *a: gd._chunk_scan(*a, precision, False))
-    fn = scan if variant == "fwd" else jax.grad(
-        lambda *a: jnp.sin(scan(*a)).sum(), argnums=(0, 1, 2, 3, 4, 5))
+def test_gdn_scan_kernels_compile_for_v5e(one_chip, cache_off, monkeypatch,
+                                          precision, D, variant):
+    """The delta rule's fused kernel pair through ``gated_delta_rule``'s
+    TPU entry at the Qwen3-Next cell's shape: 2 agents vmapped over T
+    4,096, 16 key and 32 value heads of 128, bf16 values (the rule itself
+    asks ``jax.devices()``, which answers the CPU here, so the test says
+    TPU); and at a head size of 256, where a grid step takes fewer heads.
+    What Mosaic refuses of a block, or a body over the VMEM it may use,
+    is refused here."""
+    monkeypatch.setattr(gd, "_on_tpu", lambda: True)
+    agents, B, T, Hk, H = 2, 1, 4096, 16, 32
+    sds = lambda dtype, *s: jax.ShapeDtypeStruct(
+        (agents, B, T) + s, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    shapes = (sds(f32, Hk, D), sds(f32, Hk, D), sds(jnp.bfloat16, H, D),
+              sds(f32, H), sds(f32, H))
+    rule = jax.vmap(lambda *a: gd.gated_delta_rule(*a, precision=precision))
+    fn = rule if variant == "fwd" else jax.grad(
+        lambda *a: jnp.sin(rule(*a)).sum(), argnums=(0, 1, 2, 3, 4))
     text = _compile(fn, *shapes).as_text()
-    assert text.count("tpu_custom_call") >= (1 if variant == "fwd" else 2)
+    assert text.count("tpu_custom_call") == (1 if variant == "fwd" else 2)
     assert "gdn_scan_fwd" in text and ("gdn_scan_bwd" in text) == (
         variant == "grad")
 

@@ -1,10 +1,10 @@
-"""The two halves that took the chunked delta rule's sequential parts off
-the slow path (``ops/gated_delta.py``): the chunk's unit-lower-triangular
-solve as block products, and the scan over chunks as a Pallas kernel pair
-(here under ``interpret=True``: the kernels' arithmetic, not their speed).
-Both are held to what they replaced; the last tests guard what tracing
-and lowering them costs, and the lines the chip benchmark's fault
-controls patch."""
+"""The chunked delta rule off the slow path (``ops/gated_delta.py``): the
+chunk's unit-lower-triangular solve as block products (the XLA path), and
+on a TPU the whole rule, each chunk's preparation, solve and step, as a
+fused Pallas kernel pair (here under ``interpret=True``: the kernels'
+arithmetic, not their speed).  Both are held to what they replaced; the
+last tests guard what tracing and lowering them costs, and the lines the
+chip benchmark's fault controls patch."""
 
 from __future__ import annotations
 
@@ -83,29 +83,39 @@ def test_product_solve_gradients_match_the_triangular_solves(C, strong):
 
 
 # ---------------------------------------------------------------------- #
-# B: the kernel pair, interpreted                                        #
+# B: the fused kernel pair, interpreted                                  #
 # ---------------------------------------------------------------------- #
-def _rule_inputs(t, seed=0, decay=0.5, B=1, H=2, Dk=128, Dv=128, lead=()):
+def _rule_inputs(t, seed=0, decay=0.5, B=1, H=2, Dk=128, Dv=128, lead=(),
+                 Hk=None):
+    """q, k at ``Hk`` key heads (default ``H``), v, g, beta at ``H``."""
     ks = jax.random.split(jax.random.key(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     shape = lead + (B, t, H)
+    keys = lead + (B, t, Hk or H)
     return (
-        unit(jax.random.normal(ks[0], shape + (Dk,))) * Dk ** -0.5,
-        unit(jax.random.normal(ks[1], shape + (Dk,))),
+        unit(jax.random.normal(ks[0], keys + (Dk,))) * Dk ** -0.5,
+        unit(jax.random.normal(ks[1], keys + (Dk,))),
         jax.random.normal(ks[2], shape + (Dv,)),
         -decay * jax.nn.softplus(jax.random.normal(ks[3], shape)),
         jax.nn.sigmoid(jax.random.normal(ks[4], shape)),
     )
 
 
+def _recurrence(q, k, v, g, beta):
+    """The oracle at the rule's own heads: q, k repeated to v's."""
+    r = v.shape[-2] // q.shape[-2]
+    return gated_delta_recurrence(
+        jnp.repeat(q, r, axis=-2), jnp.repeat(k, r, axis=-2), v, g, beta)
+
+
 @pytest.fixture
 def kernel_path(monkeypatch):
     """The rule as it runs on a TPU, its kernels interpreted: the backend
     test answers yes, and ``interpret`` (never on by default) is on."""
-    compiled = gd._chunk_scan
+    compiled = gd._rule_kernels
     monkeypatch.setattr(gd, "_on_tpu", lambda: True)
     monkeypatch.setattr(
-        gd, "_chunk_scan", lambda *a: compiled(*a[:-1], True))
+        gd, "_rule_kernels", lambda *a: compiled(*a[:-1], True))
 
 
 def _scan_path(*args, **kw):
@@ -117,16 +127,23 @@ _ALL = (0, 1, 2, 3, 4)
 _loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)))
 
 
-@pytest.mark.parametrize("t", [64, 40])  # a multiple of the chunk, and not
-@pytest.mark.parametrize("decay", [2.0, 0.01])
+# T a multiple of the chunk and not; a state that forgets within a chunk
+# and one that carries over all of them; (B, key heads, value heads): a key
+# head to each value head, one key head read by two, a grid step of two key
+# heads' four value heads, and two sequences
+@pytest.mark.parametrize("t, decay, B, Hk, H", [
+    *((t, decay, 1, Hk, 2) for Hk in (2, 1) for decay in (2.0, 0.01)
+      for t in (64, 40)),
+    (64, 0.01, 1, 2, 4), (40, 0.01, 2, 1, 2),
+])
 def test_kernel_pair_matches_recurrence_and_scan(kernel_path, monkeypatch,
-                                                 t, decay):
-    args = _rule_inputs(t, decay=decay)
+                                                 t, decay, B, Hk, H):
+    args = _rule_inputs(t, decay=decay, B=B, Hk=Hk, H=H)
     rule = lambda *a: gated_delta_rule(*a, chunk=16, precision="highest")
     dgot = jax.grad(_loss(rule), _ALL)(*args)
-    dwant = jax.grad(_loss(gated_delta_recurrence), _ALL)(*args)
+    dwant = jax.grad(_loss(_recurrence), _ALL)(*args)
     out = rule(*args)
-    np.testing.assert_allclose(out, gated_delta_recurrence(*args), atol=2e-6)
+    np.testing.assert_allclose(out, _recurrence(*args), atol=2e-6)
     for g, w in zip(dgot, dwant):
         assert _rel(g, w) < 1e-5
     # the scan is the same algorithm: the two agree closer than either
@@ -141,8 +158,9 @@ def test_kernel_pair_matches_recurrence_and_scan(kernel_path, monkeypatch,
 
 def test_kernel_pair_under_vmap_and_remat(kernel_path):
     """As the trainer runs it: an agent axis vmapped over the whole
-    forward-and-gradient, the rule inside a rematerialised flax block."""
-    args = _rule_inputs(32, seed=2, lead=(2,))
+    forward-and-gradient, the rule inside a rematerialised flax block, a
+    key head read by two value heads."""
+    args = _rule_inputs(32, seed=2, lead=(2,), Hk=1)
 
     class Block(nn.Module):
         @nn.compact
@@ -158,8 +176,7 @@ def test_kernel_pair_under_vmap_and_remat(kernel_path):
             argnums=(1, 2, 3, 4, 5))(params, *rule_args)
 
     got = jax.vmap(lambda *a: grads(nn.remat(Block)(), a))(*args)
-    want = jax.vmap(lambda *a: jax.grad(
-        _loss(gated_delta_recurrence), _ALL)(*a))(*args)
+    want = jax.vmap(lambda *a: jax.grad(_loss(_recurrence), _ALL)(*a))(*args)
     for g, w in zip(got, want):
         assert g.shape == w.shape and _rel(g, w) < 1e-5
 
@@ -192,7 +209,7 @@ def test_dispatch_by_shape_and_backend(monkeypatch, C, Dk, Dv, runs):
 
 def test_refused_shapes_take_the_scan(monkeypatch):
     monkeypatch.setattr(gd, "_on_tpu", lambda: True)
-    monkeypatch.setattr(gd, "_chunk_scan", lambda *a: pytest.fail(
+    monkeypatch.setattr(gd, "_rule_kernels", lambda *a: pytest.fail(
         "the kernel pair was reached at a shape it refuses"))
     args = _rule_inputs(32, Dk=16, Dv=32)
     np.testing.assert_allclose(
@@ -204,7 +221,7 @@ def test_refused_shapes_take_the_scan(monkeypatch):
 # what tracing and lowering cost: nothing may grow with T                #
 # ---------------------------------------------------------------------- #
 #: one chunk's arithmetic and its ref reads and writes, per kernel body
-KERNEL_BODY_CEILING = 80
+KERNEL_BODY_CEILING = 400
 #: equations of the product-form solve (10 products at the chunk of 64)
 SOLVE_CEILING = 100
 
@@ -270,16 +287,17 @@ def test_set_up_guard_kernels_and_trace_do_not_grow_with_t(monkeypatch):
 
 def test_set_up_guard_fails_an_unrolled_variant(monkeypatch):
     """The seeded fault: the same kernels reached once a chunk from a
-    Python loop, the state handed on through HBM."""
-    compiled = gd._chunk_scan
+    Python loop."""
+    compiled = gd._rule_kernels
 
-    def unrolled(u, w, qk, q_in, k_out, g_end, precision, interpret):
+    def unrolled(q, k, v, gc, beta, precision, interpret):
+        C = gc.shape[-1]
         return jnp.concatenate([
-            compiled(*(x[n:n + 1] for x in (u, w, qk, q_in, k_out, g_end)),
-                     precision, interpret)
-            for n in range(u.shape[0])])
+            compiled(*(x[:, n * C:(n + 1) * C] for x in (q, k, v)),
+                     gc[:, n:n + 1], beta[:, n:n + 1], precision, interpret)
+            for n in range(gc.shape[1])], axis=1)
 
-    monkeypatch.setattr(gd, "_chunk_scan", unrolled)
+    monkeypatch.setattr(gd, "_rule_kernels", unrolled)
     with pytest.raises(AssertionError, match="depends on T"):
         _guard(gated_delta_rule, monkeypatch)
 
